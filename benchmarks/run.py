@@ -14,7 +14,8 @@ figure's headline quantity).
   fig11_exec_increase   slowdown at the optimal clock
   fig13_16_ief          efficiency increase vs boost & base clocks
   table4_pipeline       pulsar pipeline w/ per-stage clock locking
-  kernels               Pallas kernels (interpret) vs jnp oracle wall time
+  kernels               Pallas kernels vs jnp oracle wall time (compiled on
+                        TPU, interpret mode on CPU: an emulator, not a clock)
   fft                   mixed-radix engine: stages, R2C vs C2C wall time,
                         J/transform model -> persists BENCH_fft.json
   fft2                  N-D plan graph: HBM passes vs the per-axis chain,
@@ -260,16 +261,15 @@ def kernels():
     x = (jax.random.normal(jax.random.PRNGKey(0), (16, 2048))
          + 1j * jax.random.normal(jax.random.PRNGKey(1), (16, 2048))
          ).astype(jnp.complex64)
-    us = _timeit(lambda v: fft_kernel_c2c(v, interpret=True), x, n=3)
+    mode = f"backend={jax.default_backend()}"
+    us = _timeit(fft_kernel_c2c, x, n=3)
     ref = _timeit(jax.jit(jnp.fft.fft), x, n=3)
-    _row("kernel_fft_2048x16_interp", us, f"jnp_ref_us={ref:.1f}")
+    _row("kernel_fft_2048x16", us, f"jnp_ref_us={ref:.1f} {mode}")
     p = jnp.abs(x) ** 2
-    us = _timeit(lambda v: harmonic_sum_kernel(v, 32, interpret=True), p,
-                 n=3)
-    _row("kernel_harmonic_sum_32", us, "levels=6")
-    us = _timeit(lambda v: power_spectrum_stats_kernel(v, interpret=True),
-                 x, n=3)
-    _row("kernel_spectrum_stats", us, "fused=power+mean+var")
+    us = _timeit(lambda v: harmonic_sum_kernel(v, 32), p, n=3)
+    _row("kernel_harmonic_sum_32", us, f"levels=6 {mode}")
+    us = _timeit(power_spectrum_stats_kernel, x, n=3)
+    _row("kernel_spectrum_stats", us, f"fused=power+mean+var {mode}")
 
 
 def roofline():
@@ -1902,6 +1902,8 @@ BENCHES = [fig4_exec_time, fig6_time_vs_freq, fig7_energy_u_shape,
 
 
 def main(argv: list[str] | None = None) -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     args = sys.argv[1:] if argv is None else argv
     by_name = {b.__name__: b for b in BENCHES}
     if args:

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.dvfs import sweep
 from repro.core.hardware import TESLA_V100
 from repro.core.scheduler import DVFSScheduler
@@ -24,6 +25,7 @@ from repro.search import TemplateBank, fdas_search
 
 
 def main():
+    enable_compile_cache()
     # --- run the pipeline on real voltages with an injected pulsar -------
     n, batch = 4096, 4
     t = jnp.arange(n, dtype=jnp.float32)

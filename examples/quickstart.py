@@ -8,11 +8,13 @@ Run:  PYTHONPATH=src python examples/quickstart.py
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (TESLA_V100, TPU_V5E, FFTCase, fft_workload,
                         mean_optimal, roofline_workload, sweep)
 
 
 def main():
+    enable_compile_cache()
     # --- 1. the paper's measurement, analytically -----------------------
     print("=== FFT DVFS sweep on the V100 (paper Secs. 4-5) ===")
     sweeps = []

@@ -7,11 +7,13 @@ Run:  PYTHONPATH=src python examples/serve_fft.py
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.hardware import TPU_V5E
 from repro.serving import FFTService
 
 
 def main():
+    enable_compile_cache()
     rng = np.random.default_rng(0)
     svc = FFTService(TPU_V5E, time_budget=0.10)
 

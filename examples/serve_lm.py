@@ -7,10 +7,12 @@ Run:  PYTHONPATH=src python examples/serve_lm.py [--arch qwen2-0.5b]
 """
 import argparse
 
+from repro.compile_cache import enable_compile_cache
 from repro.launch import serve as serve_launch
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     args = ap.parse_args()

@@ -11,10 +11,12 @@ Run:  PYTHONPATH=src python examples/train_lm.py [--arch qwen2-0.5b]
 import argparse
 import sys
 
+from repro.compile_cache import enable_compile_cache
 from repro.launch import train as train_launch
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--steps", type=int, default=200)

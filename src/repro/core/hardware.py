@@ -181,3 +181,24 @@ def get_device(name: str) -> DeviceSpec:
         return DEVICES[name]
     except KeyError as e:
         raise KeyError(f"unknown device {name!r}; have {sorted(DEVICES)}") from e
+
+
+#: ``device_kind`` strings JAX reports for the TPU chips this repository
+#: has a spec for (a v5e reports "TPU v5 lite").
+TPU_KINDS: dict[str, DeviceSpec] = {
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def spec_for_device_kind(kind: str) -> DeviceSpec:
+    """The :class:`DeviceSpec` of an attached TPU, from its ``device_kind``.
+
+    An unknown kind raises: pricing a different chip with the v5e model
+    would give every modelled number the wrong device.
+    """
+    try:
+        return TPU_KINDS[kind]
+    except KeyError as e:
+        raise ValueError(
+            f"no DeviceSpec for TPU device_kind {kind!r}; known kinds: "
+            f"{sorted(TPU_KINDS)}") from e

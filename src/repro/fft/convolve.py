@@ -298,14 +298,18 @@ def overlap_save_conv(x: jax.Array, filters, *, nfft: int | None = None,
     else:
         spectra = _bank_spectra(filters_np, nfft)
 
-    # Segment the (taps-1)-front-padded signal into overlapping windows.
+    # Segment the (taps-1)-front-padded signal into overlapping windows:
+    # window s is blocks s..s+r-1 of ``step`` samples, cut to nfft.  Built
+    # from reshapes and slices, not a gather, which the TPU compiler took
+    # minutes over at FDAS sizes (a 2^20-sample search).
     pad_front = taps - 1
-    total = (nseg - 1) * step + nfft
+    r = -(-nfft // step)                             # blocks per window
+    total = (nseg + r - 1) * step
     xp = jnp.pad(x, [(0, 0)] * (x.ndim - 1)
                  + [(pad_front, total - pad_front - n)])
-    idx = (np.arange(nseg)[:, None] * step
-           + np.arange(nfft)[None, :])               # (nseg, nfft) windows
-    segs = xp[..., idx]                              # (..., nseg, nfft)
+    blocks = xp.reshape(*x.shape[:-1], nseg + r - 1, step)
+    segs = jnp.concatenate([blocks[..., i:i + nseg, :] for i in range(r)],
+                           axis=-1)[..., :nfft]      # (..., nseg, nfft)
 
     # Forward FFT + fused bank multiply: one pass, T product planes.
     prod = _plan_mod.fft_mul(segs, spectra)          # (..., nseg, T, nfft)

@@ -2,6 +2,10 @@
 
 Two parallel regimes, matching how the paper's workload scales out:
 
+Pallas kernels run inside ``shard_map`` bodies, whose outputs carry no
+varying-axis annotation, so every ``shard_map`` here passes
+``check_vma=False``.
+
 * **Batch parallel** (:func:`batch_parallel_fft`) — the paper's own setting:
   many independent transforms, sharded over the ``data`` axis.  No
   communication at all; this is why the paper can say "FFTs which fit into
@@ -26,8 +30,8 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 def pad_rows(x: jax.Array, rows: int) -> jax.Array:
@@ -62,13 +66,18 @@ def batch_parallel_fft(x: jax.Array, mesh: Mesh, *, axis: str = "data",
             fft_fn = plan_for_length(x.shape[-1], kind)
     d = mesh.shape[axis]
     b = x.shape[0]
-    x = pad_rows(x, b + (-b) % d)
     spec = P(axis, *([None] * (x.ndim - 1)))
-    fn = shard_map(
-        lambda v: fft_fn(v), mesh=mesh, in_specs=(spec,), out_specs=spec
-    )
+    x = jax.device_put(pad_rows(x, b + (-b) % d), NamedSharding(mesh, spec))
+    fn = shard_map(lambda v: fft_fn(v), mesh=mesh, in_specs=(spec,),
+                   out_specs=spec, check_vma=False)
     out = fn(x)
-    return out[:b] if out.shape[0] != b else out
+    if out.shape[0] == b:
+        return out
+    if AxisType.Explicit in mesh.axis_types:
+        # Rows sharded explicitly cannot be cut to a count the axis does
+        # not divide; replicate them first (only padded batches get here).
+        out = jax.sharding.reshard(out, NamedSharding(mesh, P()))
+    return out[:b]
 
 
 @functools.partial(jax.jit, static_argnames=("n1", "n2", "axis", "mesh"))
@@ -99,7 +108,8 @@ def _pencil_body(x, *, n1, n2, axis, mesh):
         return v
 
     spec = P(None, axis, None)
-    return shard_map(local, mesh=mesh, in_specs=(spec,), out_specs=spec)(x)
+    return shard_map(local, mesh=mesh, in_specs=(spec,), out_specs=spec,
+                     check_vma=False)(x)
 
 
 @functools.partial(jax.jit, static_argnames=("n1", "n2p", "axis", "mesh"))
@@ -143,7 +153,8 @@ def _pencil_split_body(z, *, n1, n2p, axis, mesh):
         return jnp.concatenate([x, col], axis=-1)
 
     spec = P(None, axis, None)
-    return shard_map(local, mesh=mesh, in_specs=(spec,), out_specs=spec)(z)
+    return shard_map(local, mesh=mesh, in_specs=(spec,), out_specs=spec,
+                     check_vma=False)(z)
 
 
 def pencil_fft(x: jax.Array, mesh: Mesh, *, n1: int, n2: int,

@@ -14,11 +14,13 @@ into an N/2 complex transform with a fused Hermitian split/merge — ~2x
 FLOP and HBM savings for real telescope voltages.
 
 **Routing**: every plan's power-of-two passes execute the fused Pallas
-kernel (``repro.kernels.fft``) via :func:`pow2_fft`, falling back to the
-pure-JAX Stockham engine when Pallas is unavailable (import failure, a
-lowering error, or ``REPRO_FFT_DISABLE_PALLAS=1``).  Tests monkeypatch
-the module-level ``_kernel_fft``/``_kernel_rfft``/``_kernel_irfft`` hooks
-to count kernel invocations or force the fallback.
+kernels (``repro.kernels.fft``) via :func:`pow2_fft`.  A kernel that
+fails to lower or run raises; nothing falls back behind the caller's
+back.  The pure-JAX Stockham engine runs only where it is asked for:
+``REPRO_FFT_DISABLE_PALLAS=1`` or :func:`pallas_disabled`, which the
+serving layer's bottom degradation rung uses and names in its receipts.
+Tests monkeypatch the module-level ``_kernel_*`` hooks to count kernel
+invocations.
 
 **Tuning**: plan construction consults the active
 :class:`repro.tune.TuningContext` (exactly once per (device, shape, kind)
@@ -62,27 +64,20 @@ MAX_SINGLE_PASS = 2**13
 # Pallas kernel routing (monkeypatchable hooks + env kill-switch)
 # ---------------------------------------------------------------------------
 
-try:
-    from repro.kernels.fft.ops import (MAX_KERNEL_N, fft_kernel_c2c,
-                                       fft_kernel_c2c_axis1,
-                                       fft_kernel_c2c_mul,
-                                       fft_kernel_c2c_t, fft_kernel_c2r,
-                                       fft_kernel_r2c, fft_kernel_r2c_t,
-                                       transpose_kernel)
-    _kernel_fft: Callable | None = fft_kernel_c2c
-    _kernel_rfft: Callable | None = fft_kernel_r2c
-    _kernel_irfft: Callable | None = fft_kernel_c2r
-    _kernel_fft_t: Callable | None = fft_kernel_c2c_t
-    _kernel_fft_axis1: Callable | None = fft_kernel_c2c_axis1
-    _kernel_rfft_t: Callable | None = fft_kernel_r2c_t
-    _kernel_transpose: Callable | None = transpose_kernel
-    _kernel_fft_mul: Callable | None = fft_kernel_c2c_mul
-except Exception:                                     # pragma: no cover
-    MAX_KERNEL_N = MAX_SINGLE_PASS
-    _kernel_fft = _kernel_rfft = _kernel_irfft = None
-    _kernel_fft_t = _kernel_fft_axis1 = None
-    _kernel_rfft_t = _kernel_transpose = None
-    _kernel_fft_mul = None
+from repro.kernels.fft.ops import (MAX_KERNEL_N, fft_kernel_c2c,
+                                   fft_kernel_c2c_axis1, fft_kernel_c2c_mul,
+                                   fft_kernel_c2c_t, fft_kernel_c2r,
+                                   fft_kernel_r2c, fft_kernel_r2c_t,
+                                   transpose_kernel)
+
+_kernel_fft: Callable = fft_kernel_c2c
+_kernel_rfft: Callable = fft_kernel_r2c
+_kernel_irfft: Callable = fft_kernel_c2r
+_kernel_fft_t: Callable = fft_kernel_c2c_t
+_kernel_fft_axis1: Callable = fft_kernel_c2c_axis1
+_kernel_rfft_t: Callable = fft_kernel_r2c_t
+_kernel_transpose: Callable = transpose_kernel
+_kernel_fft_mul: Callable = fft_kernel_c2c_mul
 
 
 def _pallas_enabled() -> bool:
@@ -138,7 +133,7 @@ def pow2_fft(x: jax.Array, *, inverse: bool = False,
     """C2C FFT of a pow2 length, routed through the Pallas kernel.
 
     Single-kernel lengths run the fused mixed-radix kernel (pure-JAX
-    Stockham on fallback); longer lengths recurse through the four-step
+    Stockham with Pallas disabled); longer lengths recurse through the four-step
     decomposition so *every* pow2 pass of every plan lands on the kernel.
     ``config`` (a tuned :class:`repro.tune.KernelConfig`) overrides the
     batch tile / radix schedule / four-step split heuristics.
@@ -149,12 +144,8 @@ def pow2_fft(x: jax.Array, *, inverse: bool = False,
             return jnp.conj(pow2_fft(jnp.conj(x), config=config)) / n
         n1, n2 = _resolve_split(n, config)
         return four_step_fft(x, n1, n2, config=config)
-    kern = _kernel_fft
-    if kern is not None and n <= MAX_KERNEL_N and _pallas_enabled():
-        try:
-            return kern(x, inverse=inverse, **_kernel_overrides(config))
-        except Exception:                             # graceful fallback
-            pass
+    if n <= MAX_KERNEL_N and _pallas_enabled():
+        return _kernel_fft(x, inverse=inverse, **_kernel_overrides(config))
     return _stockham_pow2(x, inverse=inverse)
 
 
@@ -170,19 +161,14 @@ def fft_mul(x: jax.Array, bank,
     The overlap-save convolution engine's forward pass: the bank multiply
     rides the FFT kernel as an in-VMEM epilogue (``fft_kernel_c2c_mul``),
     so a T-template matched-filter plane costs forward + T inverse passes
-    with zero standalone multiply passes.  The fallback (Pallas missing
-    or disabled) pays the routed FFT plus ONE XLA broadcast multiply —
+    with zero standalone multiply passes.  With Pallas disabled (the
+    pure-JAX rung) it pays the routed FFT plus ONE XLA broadcast multiply —
     numerically identical, one extra HBM round trip of the plane.
     """
     x = _as_complex(x)
     n = x.shape[-1]
-    kern = _kernel_fft_mul
-    if (kern is not None and _is_pow2(n) and 1 < n <= MAX_KERNEL_N
-            and _pallas_enabled()):
-        try:
-            return kern(x, bank, **_kernel_overrides(config))
-        except Exception:                             # graceful fallback
-            pass
+    if _is_pow2(n) and 1 < n <= MAX_KERNEL_N and _pallas_enabled():
+        return _kernel_fft_mul(x, bank, **_kernel_overrides(config))
     y = pow2_fft(x, config=config)
     return y[..., None, :] * jnp.asarray(bank).astype(y.dtype)
 
@@ -197,20 +183,15 @@ def fft_transposed(x: jax.Array, *, twiddle=None, inverse: bool = False,
 
     One fused kernel pass: (..., R, C) -> (..., C, R).  ``twiddle`` (an
     (R, C) complex table) rides along as a kernel epilogue — the four-step
-    inter-pass multiply costs zero extra HBM passes.  Falls back to
-    routed-FFT + XLA multiply + XLA transpose when Pallas is unavailable
+    inter-pass multiply costs zero extra HBM passes.  With Pallas
+    disabled it runs routed-FFT + XLA multiply + XLA transpose
     (numerically identical, just more memory passes).
     """
     x = _as_complex(x)
     n = x.shape[-1]
-    kern = _kernel_fft_t
-    if (kern is not None and _is_pow2(n) and n <= MAX_KERNEL_N
-            and n > 1 and _pallas_enabled()):
-        try:
-            return kern(x, twiddle=twiddle, inverse=inverse,
-                        **_kernel_overrides(config))
-        except Exception:                             # graceful fallback
-            pass
+    if _is_pow2(n) and 1 < n <= MAX_KERNEL_N and _pallas_enabled():
+        return _kernel_fft_t(x, twiddle=twiddle, inverse=inverse,
+                             **_kernel_overrides(config))
     y = _routed_1d(x, n, inverse, config)
     if twiddle is not None:
         y = y * jnp.asarray(twiddle).astype(y.dtype)
@@ -236,19 +217,14 @@ def fft_column(x: jax.Array, *, twiddle=None, inverse: bool = False,
     One fused kernel pass (transpose-read + FFT + optional twiddle
     epilogue + transpose-write, all in VMEM) — the column pass of the
     four-step algorithm.  ``twiddle`` is a (C, R) table multiplying output
-    ``[..., k, j]`` by ``twiddle[j, k]``.  Falls back to XLA transpose +
-    routed FFT + multiply when Pallas is unavailable.
+    ``[..., k, j]`` by ``twiddle[j, k]``.  With Pallas disabled it
+    runs XLA transpose + routed FFT + multiply.
     """
     x = _as_complex(x)
     r = x.shape[-2]
-    kern = _kernel_fft_axis1
-    if (kern is not None and _is_pow2(r) and 1 < r <= MAX_KERNEL_N
-            and _pallas_enabled()):
-        try:
-            return kern(x, twiddle=twiddle, inverse=inverse,
-                        **_kernel_overrides(config))
-        except Exception:                             # graceful fallback
-            pass
+    if _is_pow2(r) and 1 < r <= MAX_KERNEL_N and _pallas_enabled():
+        return _kernel_fft_axis1(x, twiddle=twiddle, inverse=inverse,
+                                 **_kernel_overrides(config))
     y = _routed_1d(jnp.swapaxes(x, -1, -2), r, inverse, config)
     if twiddle is not None:
         y = y * jnp.asarray(twiddle).astype(y.dtype)
@@ -264,25 +240,17 @@ def rfft_transposed(x: jax.Array,
     if jnp.issubdtype(x.dtype, jnp.complexfloating):
         x = x.real
     n = x.shape[-1]
-    kern = _kernel_rfft_t
-    if (kern is not None and _is_pow2(n) and 4 <= n
-            and n // 2 <= MAX_KERNEL_N and _pallas_enabled()):
-        try:
-            return kern(x, **_kernel_overrides(config))
-        except Exception:
-            pass
+    if (_is_pow2(n) and 4 <= n and n // 2 <= MAX_KERNEL_N
+            and _pallas_enabled()):
+        return _kernel_rfft_t(x, **_kernel_overrides(config))
     return jnp.swapaxes(plan_with_config(n, "r2c", config)(x), -1, -2)
 
 
 def tiled_transpose(x: jax.Array) -> jax.Array:
     """Swap the last two axes in one tiled kernel pass (read row tiles,
-    write column tiles); XLA transpose on fallback."""
-    kern = _kernel_transpose
-    if kern is not None and _pallas_enabled():
-        try:
-            return kern(x)
-        except Exception:
-            pass
+    write column tiles); an XLA transpose with Pallas disabled."""
+    if _pallas_enabled():
+        return _kernel_transpose(x)
     return jnp.swapaxes(x, -1, -2)
 
 
@@ -334,8 +302,8 @@ def four_step_fft(x: jax.Array, n1: int, n2: int,
     The unfused formulation costs kernel + XLA-twiddle + three XLA
     transposes (five HBM round trips of the batch); the fused pair costs
     exactly two.  Both passes route through the Pallas kernels
-    (:func:`fft_column`, :func:`fft_transposed`), falling back to routed
-    :func:`pow2_fft` + XLA ops when Pallas is unavailable.  The
+    (:func:`fft_column`, :func:`fft_transposed`); with Pallas disabled
+    they run routed :func:`pow2_fft` + XLA ops.  The
     distributed version (repro.fft.distributed) turns the transpose into
     an all_to_all across the mesh — cuFFT's multi-kernel plan, TPU-style.
     """
@@ -367,13 +335,8 @@ def _r2c_fn(x: jax.Array, n: int,
     if jnp.issubdtype(x.dtype, jnp.complexfloating):
         x = x.real
     m = n // 2
-    kern = _kernel_rfft
-    if (kern is not None and 4 <= n and m <= MAX_KERNEL_N
-            and _pallas_enabled()):
-        try:
-            return kern(x, **_kernel_overrides(config))
-        except Exception:
-            pass
+    if 4 <= n and m <= MAX_KERNEL_N and _pallas_enabled():
+        return _kernel_rfft(x, **_kernel_overrides(config))
     if m < 1:
         return _as_complex(x)
     return _rfft_split(
@@ -385,13 +348,8 @@ def _c2r_fn(x: jax.Array, n: int,
     """Routed C2R inverse of :func:`_r2c_fn` (1/N normalised)."""
     x = _as_complex(x)
     m = n // 2
-    kern = _kernel_irfft
-    if (kern is not None and 4 <= n and m <= MAX_KERNEL_N
-            and _pallas_enabled()):
-        try:
-            return kern(x, **_kernel_overrides(config))
-        except Exception:
-            pass
+    if 4 <= n and m <= MAX_KERNEL_N and _pallas_enabled():
+        return _kernel_irfft(x, **_kernel_overrides(config))
     return _unpack_real(
         pow2_fft(_irfft_merge(x, n), inverse=True, config=config))
 

@@ -128,30 +128,6 @@ def stage_twiddles(n: int, radices: tuple[int, ...] = DEFAULT_RADICES,
 
 
 @functools.lru_cache(maxsize=None)
-def packed_stage_twiddles(n: int,
-                          radices: tuple[int, ...] = DEFAULT_RADICES
-                          ) -> tuple[np.ndarray, np.ndarray]:
-    """Forward twiddles packed for the Pallas kernel: (rows, n) re/im f32.
-
-    Row layout: stages in execution order, branches k = 1..r-1 within a
-    stage; each row holds its h = M/r twiddles left-aligned, zero-padded
-    to n.  The kernel slices ``[row, :h]`` at statically known offsets.
-    Inverse transforms conjugate in-kernel (negate the im plane).
-    """
-    tables = stage_twiddles(n, radices, False)
-    rows = sum(t.shape[0] for t in tables)
-    re = np.zeros((max(rows, 1), n), np.float32)
-    im = np.zeros((max(rows, 1), n), np.float32)
-    row = 0
-    for t in tables:
-        k, h = t.shape
-        re[row:row + k, :h] = t.real
-        im[row:row + k, :h] = t.imag
-        row += k
-    return re, im
-
-
-@functools.lru_cache(maxsize=None)
 def rfft_split_twiddles(n: int) -> np.ndarray:
     """W[k] = exp(-2*pi*i*k/n), k = 0..n/2 — the R2C split / C2R merge
     factors (complex128; cast to the working dtype at trace time)."""

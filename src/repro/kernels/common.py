@@ -1,38 +1,75 @@
-"""Shared kernel plumbing: interpret-mode detection and tiling helpers."""
+"""Shared kernel plumbing: backend mode, TPU tiling constants, tile sizing."""
 from __future__ import annotations
 
 import jax
 
+#: Vector register shape of a 32-bit value on the TPU: (sublanes, lanes).
+SUBLANES = 8
+LANES = 128
+
+#: Mosaic's default scoped-VMEM limit per kernel (v5e has 128 MiB of VMEM
+#: per core; a kernel that needs more must ask for it).
+SCOPED_VMEM_BYTES = 16 * 2**20
+
+#: Bytes of double-buffered in/out blocks a kernel's tile may fill; the
+#: rest of the scoped limit holds tables, scratch and temporaries.
+BLOCK_BUDGET_BYTES = 6 * 2**20
+
 
 def use_interpret() -> bool:
-    """Pallas TPU kernels run in interpret mode off-TPU (this container)."""
-    return jax.default_backend() != "tpu"
+    """Pallas mode for the attached backend.
+
+    ``cpu`` (the test path) runs kernels in interpret mode; ``tpu``
+    compiles them.  Any other backend has no Pallas TPU lowering and no
+    reason to emulate one, so it is an error rather than a silent
+    interpret-mode run.
+    """
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"Pallas kernels run compiled on 'tpu' or in interpret mode on "
+        f"'cpu'; the default backend is {backend!r}")
 
 
 def round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
-def batch_tile(n: int, elem_bytes: int, *, vmem_budget: int = 8 * 2**20,
-               buffers: int = 4, lane: int = 8,
-               override: int | None = None) -> int:
-    """Largest batch tile keeping ``buffers`` copies of (tile, n) in VMEM.
+def batch_tile(n: int, elem_bytes: int, *,
+               vmem_budget: int = BLOCK_BUDGET_BYTES, buffers: int = 4,
+               align: int = SUBLANES, override: int | None = None) -> int:
+    """Rows per grid step keeping ``buffers`` (tile, n) planes in VMEM.
 
-    VMEM on v5e is ~128 MiB but we budget a small slice so several kernels
-    and double-buffered DMA windows coexist; ``lane`` aligns the sublane
-    dimension.
+    ``buffers`` counts every pipelined in/out plane of the kernel with
+    its double buffer (a C2C kernel's re/im in and out are 8).  The tile
+    is ``align`` times a power of two, so the service's power-of-two
+    bucketed batches never pad, and at least one aligned block.
 
     ``override`` short-circuits the heuristic with an explicit tile (the
-    autotuner's tuned choice, ``repro.tune``) — validated positive but
-    otherwise trusted: the tuner measured it on this device.
+    autotuner's tuned choice, ``repro.tune``), rounded up to ``align``.
     """
     if override is not None:
         if override < 1:
             raise ValueError(f"batch tile override must be >= 1, "
                              f"got {override}")
-        return override
-    per_row = n * elem_bytes * buffers
-    tile = max(vmem_budget // per_row, 1)
-    if tile >= lane:
-        tile = tile // lane * lane
-    return tile
+        return round_up(override, align)
+    per_row = max(n, 1) * elem_bytes * buffers
+    blocks = max(vmem_budget // per_row // align, 1)
+    return (1 << (blocks.bit_length() - 1)) * align
+
+
+def rows_tile(rows: int, tile: int, align: int = SUBLANES) -> tuple[int, int]:
+    """(tile, padded rows) for a batch of ``rows``.
+
+    A batch no larger than one aligned block is its own (full-extent)
+    block and is never padded; otherwise the tile is an ``align``
+    multiple no larger than the aligned batch, and the batch pads up to
+    a tile multiple.
+    """
+    if rows <= align:
+        return rows, rows
+    tile = min(tile, round_up(rows, align))
+    return tile, round_up(rows, tile)

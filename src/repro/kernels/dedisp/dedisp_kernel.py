@@ -3,15 +3,18 @@
 GPU dedispersion engines walk a (DM, channel) delay table with global
 gathers; TPU has no efficient gather, so we ADAPT the algorithm the same
 way the harmonic-sum kernel does (DESIGN.md: rethink for the TPU memory
-hierarchy): every delay is a *static* integer known at trace time, so
+hierarchy): every delay is a whole number of samples, so
 
   x[c, t + d]  over t = 0..N-1-d  ==  the affine ``lax.slice`` x[c, d:]
 
-zero-padded back to length N.  The kernel unrolls the (DM, delay) table
-statically, grouping channels that share a delay so each distinct shift
-is materialised once per DM trial; the (TILE_B, C, N) filterbank block
-is loaded from HBM exactly once and every one of the D * C accumulations
-reads it from VMEM.
+zero-padded back to length N.  On the chip that is a lane rotation
+(``pltpu.roll``) by N - d with the wrapped tail masked to zero.  The
+(D, C) delay table rides in SMEM as scalar-prefetch data and the kernel
+loops over trials and channels, so the program stays the same size for
+any table (an unrolled D x C table of unaligned slices took the compiler
+minutes at 16 x 64); the (TILE_B, C, N) filterbank block is loaded from
+HBM exactly once and every one of the D * C accumulations reads it from
+VMEM.
 
 Grid: 1-D over batch tiles (whole channels and the whole time axis stay
 resident — a time-tiled variant would need halo reads of max-delay
@@ -24,32 +27,29 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _shift(x: jax.Array, d: int) -> jax.Array:
-    """x[:, d:] zero-padded back to (B, N): the time-shift by ``d``."""
-    if d == 0:
-        return x
-    b, n = x.shape
-    return jnp.pad(jax.lax.slice(x, (0, d), (b, n)), ((0, 0), (0, d)))
+def _dedisp_body(delays_ref, fb_ref, out_ref):
+    tb, nchan, n = fb_ref.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (tb, n), 1)
 
+    def trial(d, carry):
+        def channel(c, acc):
+            x = fb_ref[:, pl.ds(c, 1), :].reshape(tb, n)
+            s = delays_ref[d, c]
+            # y[t] = x[t + s] for t < n - s, else 0 (zero-padded shift).
+            y = pltpu.roll(x, jnp.where(s == 0, 0, n - s), 1)
+            return acc + jnp.where(lane < n - s, y, 0.0)
 
-def _dedisp_body(fb_ref, out_ref, *, delays: tuple[tuple[int, ...], ...]):
-    fb = fb_ref[...]                                 # (B, C, N)
-    for trial, row in enumerate(delays):
-        # Channels sharing a delay are summed first, then shifted once.
-        groups: dict[int, list[int]] = {}
-        for ch, d in enumerate(row):
-            groups.setdefault(d, []).append(ch)
-        acc = None
-        for d, chans in sorted(groups.items()):
-            g = fb[:, chans[0], :]
-            for ch in chans[1:]:
-                g = g + fb[:, ch, :]
-            g = _shift(g, d)
-            acc = g if acc is None else acc + g
-        out_ref[:, trial, :] = acc
+        acc = jax.lax.fori_loop(0, nchan, channel,
+                                jnp.zeros((tb, n), jnp.float32))
+        out_ref[:, pl.ds(d, 1), :] = acc.reshape(tb, 1, n)
+        return carry
+
+    jax.lax.fori_loop(0, out_ref.shape[1], trial, 0)
 
 
 @functools.partial(jax.jit,
@@ -78,11 +78,15 @@ def dedisperse_pallas(fb: jax.Array,
                     f"delay {d} of trial {trial} outside [0, ntime={n}); "
                     f"clip the DM grid to the block length")
     fn = pl.pallas_call(
-        functools.partial(_dedisp_body, delays=delays),
-        grid=(b // tile_b,),
-        in_specs=[pl.BlockSpec((tile_b, nchan, n), lambda i: (i, 0, 0))],
-        out_specs=pl.BlockSpec((tile_b, ndm, n), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, ndm, n), fb.dtype),
+        _dedisp_body,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b // tile_b,),
+            in_specs=[pl.BlockSpec((tile_b, nchan, n),
+                                   lambda i, _: (i, 0, 0))],
+            out_specs=pl.BlockSpec((tile_b, ndm, n),
+                                   lambda i, _: (i, 0, 0))),
+        out_shape=jax.ShapeDtypeStruct((b, ndm, n), jnp.float32),
         interpret=interpret,
     )
-    return fn(fb)
+    return fn(jnp.asarray(np.asarray(delays, np.int32)), fb)

@@ -29,8 +29,8 @@ def dedisperse_kernel(fb: jax.Array, delays, *,
     """(..., C, N) filterbanks -> (..., D, N) dedispersed time series.
 
     ``delays`` is a (D, C) integer-sample table (rows = DM trials); it is
-    host-side and static — the kernel unrolls it at trace time, which is
-    what makes the shift-and-sum gather-free on TPU.
+    host-side and static (checked against the block length here, then
+    handed to the kernel as scalar-prefetch data).
     """
     if interpret is None:
         interpret = use_interpret()
@@ -61,8 +61,10 @@ def dedisperse_kernel(fb: jax.Array, delays, *,
     for d in lead:
         b *= d
     x = fb.reshape(b, nchan, n)
-    # VMEM holds the (tile, C, N) block plus the (tile, D, N) output.
-    tile = min(batch_tile(n, 4, buffers=nchan + len(static)), b)
+    # VMEM holds the (tile, C, N) block plus the (tile, D, N) output, both
+    # double-buffered; the tile is a leading block dimension (any size).
+    tile = min(batch_tile(n, 4, buffers=2 * (nchan + len(static)),
+                          align=1), b)
     pad = (-b) % tile
     if pad:
         x = jnp.pad(x, ((0, pad), (0, 0), (0, 0)))

@@ -1,11 +1,12 @@
-"""Public jit'd wrappers for the fused mixed-radix Stockham FFT kernel."""
+"""Public wrappers for the FFT Pallas kernels (split re/im planes, tiles)."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.fft.radix import DEFAULT_RADICES
-from repro.kernels.common import batch_tile, use_interpret
+from repro.kernels.common import (BLOCK_BUDGET_BYTES, LANES, SUBLANES,
+                                  batch_tile, round_up, rows_tile,
+                                  use_interpret)
 from repro.obs.ledger import record_launch
 from repro.kernels.fft.fft_kernel import (fft_axis1_pallas,
                                           fft_axis1_twiddle_pallas,
@@ -17,6 +18,11 @@ from repro.kernels.fft.fft_kernel import (fft_axis1_pallas,
 
 # One fused pass handles transforms that fit VMEM alongside work buffers.
 MAX_KERNEL_N = 2**13
+
+# Every wrapper takes ``radices`` for signature parity with the pure-JAX
+# Stockham engine (``repro.fft.stockham``, the bottom degradation rung)
+# and the autotuner's configs; the MXU kernels have one fixed
+# factorisation and ignore it.
 
 
 def _check_kernel_length(n: int) -> None:
@@ -44,18 +50,18 @@ def _tile_and_pad(planes: list[jax.Array], b: int, n: int,
     A tile-multiple batch (the common case after the serving layer's
     coalescer) skips the pad-then-slice HBM round trip entirely.
     ``tile_b`` is an explicit override (the autotuner's tuned choice,
-    clamped to the batch) — when None the VMEM-budget heuristic decides.
+    rounded to the sublane height) — when None the VMEM budget decides.
     """
-    tile = min(batch_tile(n, elem_bytes, buffers=8, override=tile_b), b)
-    pad = (-b) % tile
-    if pad:
-        planes = [jnp.pad(p, ((0, pad), (0, 0))) for p in planes]
+    tile, padded = rows_tile(b, batch_tile(n, elem_bytes, buffers=8,
+                                           override=tile_b))
+    if padded > b:
+        planes = [jnp.pad(p, ((0, padded - b), (0, 0))) for p in planes]
     return planes, tile
 
 
 def fft_kernel_c2c(x: jax.Array, *, inverse: bool = False,
                    interpret: bool | None = None,
-                   radices: tuple[int, ...] = DEFAULT_RADICES,
+                   radices: tuple[int, ...] | None = None,
                    tile_b: int | None = None) -> jax.Array:
     """Batched pow2 C2C FFT (..., N) via the Pallas kernel.
 
@@ -81,7 +87,7 @@ def fft_kernel_c2c(x: jax.Array, *, inverse: bool = False,
     im = flat.imag.astype(jnp.float32)
     (re, im), tile = _tile_and_pad([re, im], b, n, tile_b=tile_b)
     out_re, out_im = fft_pallas(re, im, tile_b=tile, inverse=inverse,
-                                interpret=interpret, radices=radices)
+                                interpret=interpret)
     padded = b + (-b) % tile
     record_launch("fft-c2c", grid=(padded // tile,), tile=(tile, n),
                   bytes_moved=16 * padded * n, shape=(b, n))
@@ -92,7 +98,7 @@ def fft_kernel_c2c(x: jax.Array, *, inverse: bool = False,
 
 def fft_kernel_c2c_mul(x: jax.Array, bank, *, inverse: bool = False,
                        interpret: bool | None = None,
-                       radices: tuple[int, ...] = DEFAULT_RADICES,
+                       radices: tuple[int, ...] | None = None,
                        tile_b: int | None = None) -> jax.Array:
     """Fused pow2 C2C FFT + (T, N) filter-bank multiply epilogue.
 
@@ -120,14 +126,18 @@ def fft_kernel_c2c_mul(x: jax.Array, bank, *, inverse: bool = False,
     flat, lead, b = _flatten(x)
     re = flat.real.astype(jnp.float32)
     im = flat.imag.astype(jnp.float32)
-    # The output plane is T x the input tile; scale the VMEM budget so
-    # input, bank and product planes coexist.
-    (re, im), tile = _tile_and_pad([re, im], b, n * (4 + 2 * t) // 8,
-                                   tile_b=tile_b)
+    # Rows travel as (1, n) blocks (a (1, n) block pads to 8 sublanes in
+    # VMEM), next to a T-row product plane each, double-buffered; the
+    # pinned bank takes its share of the budget first.
+    per_row = 2 * 4 * (2 * SUBLANES * n + 2 * t * n)
+    room = BLOCK_BUDGET_BYTES - 2 * 4 * 2 * t * n
+    tile = tile_b if tile_b is not None else max(room // per_row, 1)
+    tile = min(tile, b)
+    padded = round_up(b, tile)
+    if padded > b:
+        re, im = (jnp.pad(p, ((0, padded - b), (0, 0))) for p in (re, im))
     out_re, out_im = fft_mul_pallas(re, im, fbr, fbi, tile_b=tile,
-                                    inverse=inverse, interpret=interpret,
-                                    radices=radices)
-    padded = b + (-b) % tile
+                                    inverse=inverse, interpret=interpret)
     record_launch("fft-c2c-mul", grid=(padded // tile,), tile=(tile, n),
                   bytes_moved=8 * n * (padded + t + padded * t),
                   shape=(b, t, n))
@@ -136,20 +146,23 @@ def fft_kernel_c2c_mul(x: jax.Array, bank, *, inverse: bool = False,
     return (out_re + 1j * out_im).reshape(*lead, t, n)
 
 
-def _row_tile(r: int, c: int, elem_bytes: int = 4, buffers: int = 10,
-              override: int | None = None) -> int:
-    """Largest row tile that divides ``r`` and fits the VMEM budget.
+def _lane_tile(r: int, c: int, planes: int = 12,
+               override: int | None = None) -> int:
+    """Tile along an axis that becomes the lane (minor) dimension of a
+    transposed block: a multiple of 128 dividing ``r``, or ``r`` itself
+    when it is not a multiple of 128.
 
-    A divisor search (not pow2 halving): ``batch_tile`` returns
-    lane-aligned but often non-pow2 budgets, and halving those would
-    collapse to tile=1 for the pow2 row counts the fused passes serve.
-    An explicit ``override`` (the autotuner's tile) is snapped down to
-    the nearest divisor of ``r`` the same way.
+    ``planes`` counts the (tile, c) planes the kernel keeps in VMEM
+    (double-buffered blocks plus scratch).  An explicit ``override`` (the
+    autotuner's tile) is snapped down to the nearest such divisor.
     """
-    tile = max(min(batch_tile(c, elem_bytes, buffers=buffers,
-                              override=override), r), 1)
-    while tile > 1 and r % tile:
-        tile -= 1
+    if r % LANES or r <= LANES:
+        return r
+    want = (override if override is not None
+            else BLOCK_BUDGET_BYTES // (planes * c * 4))
+    tile = max(want // LANES, 1) * LANES
+    while r % tile:
+        tile -= LANES
     return tile
 
 
@@ -164,7 +177,7 @@ def _flatten3(x: jax.Array) -> tuple[jax.Array, tuple[int, ...]]:
 
 def fft_kernel_c2c_t(x: jax.Array, *, twiddle=None, inverse: bool = False,
                      interpret: bool | None = None,
-                     radices: tuple[int, ...] = DEFAULT_RADICES,
+                     radices: tuple[int, ...] | None = None,
                      tile_b: int | None = None) -> jax.Array:
     """Fused C2C FFT + transposed write: (..., R, C) -> (..., C, R).
 
@@ -186,17 +199,17 @@ def fft_kernel_c2c_t(x: jax.Array, *, twiddle=None, inverse: bool = False,
     flat, lead = _flatten3(x)
     re = flat.real.astype(jnp.float32)
     im = flat.imag.astype(jnp.float32)
-    tile = _row_tile(r, c, override=tile_b)
+    tile = _lane_tile(r, c, override=tile_b)
     if twiddle is not None:
         tw = jnp.asarray(twiddle)
         ftwr = tw.real.astype(jnp.float32)
         ftwi = tw.imag.astype(jnp.float32)
         out_re, out_im = fft_t_twiddle_pallas(
             re, im, ftwr, ftwi, tile_r=tile, inverse=inverse,
-            interpret=interpret, radices=radices)
+            interpret=interpret)
     else:
         out_re, out_im = fft_t_pallas(re, im, tile_r=tile, inverse=inverse,
-                                      interpret=interpret, radices=radices)
+                                      interpret=interpret)
     record_launch("fft-c2c-t", grid=(flat.shape[0], r // tile),
                   tile=(tile, c), bytes_moved=16 * flat.shape[0] * r * c,
                   shape=(flat.shape[0], r, c))
@@ -206,7 +219,7 @@ def fft_kernel_c2c_t(x: jax.Array, *, twiddle=None, inverse: bool = False,
 def fft_kernel_c2c_axis1(x: jax.Array, *, twiddle=None,
                          inverse: bool = False,
                          interpret: bool | None = None,
-                         radices: tuple[int, ...] = DEFAULT_RADICES,
+                         radices: tuple[int, ...] | None = None,
                          tile_b: int | None = None) -> jax.Array:
     """C2C FFT over axis -2, layout preserved: (..., R, C) -> (..., R, C).
 
@@ -225,19 +238,18 @@ def fft_kernel_c2c_axis1(x: jax.Array, *, twiddle=None,
     flat, lead = _flatten3(x)
     re = flat.real.astype(jnp.float32)
     im = flat.imag.astype(jnp.float32)
-    tile = _row_tile(c, r, override=tile_b)
+    tile = _lane_tile(c, r, override=tile_b)
     if twiddle is not None:
         tw = jnp.asarray(twiddle)
         ftwr = tw.real.astype(jnp.float32)
         ftwi = tw.imag.astype(jnp.float32)
         out_re, out_im = fft_axis1_twiddle_pallas(
             re, im, ftwr, ftwi, tile_c=tile, inverse=inverse,
-            interpret=interpret, radices=radices)
+            interpret=interpret)
     else:
         out_re, out_im = fft_axis1_pallas(re, im, tile_c=tile,
                                           inverse=inverse,
-                                          interpret=interpret,
-                                          radices=radices)
+                                          interpret=interpret)
     record_launch("fft-c2c-axis1", grid=(flat.shape[0], c // tile),
                   tile=(r, tile), bytes_moved=16 * flat.shape[0] * r * c,
                   shape=(flat.shape[0], r, c))
@@ -245,7 +257,7 @@ def fft_kernel_c2c_axis1(x: jax.Array, *, twiddle=None,
 
 
 def fft_kernel_r2c_t(x: jax.Array, *, interpret: bool | None = None,
-                     radices: tuple[int, ...] = DEFAULT_RADICES,
+                     radices: tuple[int, ...] | None = None,
                      tile_b: int | None = None) -> jax.Array:
     """Fused R2C + transposed write: (..., R, C) real -> (..., C/2+1, R)."""
     if interpret is None:
@@ -258,9 +270,8 @@ def fft_kernel_r2c_t(x: jax.Array, *, interpret: bool | None = None,
     if c < 4:
         raise ValueError(f"fused R2C needs C >= 4, got {c}")
     flat, lead = _flatten3(x.astype(jnp.float32))
-    tile = _row_tile(r, c, override=tile_b)
-    out_re, out_im = rfft_t_pallas(flat, tile_r=tile, interpret=interpret,
-                                   radices=radices)
+    tile = _lane_tile(r, c, planes=8, override=tile_b)
+    out_re, out_im = rfft_t_pallas(flat, tile_r=tile, interpret=interpret)
     record_launch(
         "fft-r2c-t", grid=(flat.shape[0], r // tile), tile=(tile, c),
         bytes_moved=4 * flat.shape[0] * r * (c + 2 * (c // 2 + 1)),
@@ -280,8 +291,8 @@ def transpose_kernel(x: jax.Array, *,
     x = jnp.asarray(x)
     r, c = x.shape[-2:]
     flat, lead = _flatten3(x)
-    tr = _row_tile(r, max(c, 1), buffers=4)
-    tc = _row_tile(c, max(r, 1), buffers=4)
+    tc = _lane_tile(c, LANES, planes=8)
+    tr = _lane_tile(r, tc, planes=8)
     if jnp.issubdtype(x.dtype, jnp.complexfloating):
         re, im = transpose_pallas(flat.real, flat.imag, tile_r=tr, tile_c=tc,
                                   interpret=interpret)
@@ -299,7 +310,7 @@ def transpose_kernel(x: jax.Array, *,
 
 
 def fft_kernel_r2c(x: jax.Array, *, interpret: bool | None = None,
-                   radices: tuple[int, ...] = DEFAULT_RADICES,
+                   radices: tuple[int, ...] | None = None,
                    tile_b: int | None = None) -> jax.Array:
     """Batched pow2 R2C FFT: (..., N) real -> (..., N/2+1) complex.
 
@@ -318,8 +329,7 @@ def fft_kernel_r2c(x: jax.Array, *, interpret: bool | None = None,
         return rfft(x)
     flat, lead, b = _flatten(x.astype(jnp.float32))
     (flat,), tile = _tile_and_pad([flat], b, n, tile_b=tile_b)
-    out_re, out_im = rfft_pallas(flat, tile_b=tile, interpret=interpret,
-                                 radices=radices)
+    out_re, out_im = rfft_pallas(flat, tile_b=tile, interpret=interpret)
     padded = b + (-b) % tile
     record_launch("fft-r2c", grid=(padded // tile,), tile=(tile, n),
                   bytes_moved=4 * padded * (n + 2 * (n // 2 + 1)),
@@ -330,7 +340,7 @@ def fft_kernel_r2c(x: jax.Array, *, interpret: bool | None = None,
 
 
 def fft_kernel_c2r(x: jax.Array, *, interpret: bool | None = None,
-                   radices: tuple[int, ...] = DEFAULT_RADICES,
+                   radices: tuple[int, ...] | None = None,
                    tile_b: int | None = None) -> jax.Array:
     """Batched pow2 C2R inverse: (..., N/2+1) half-spectrum -> (..., N) real.
 
@@ -352,8 +362,7 @@ def fft_kernel_c2r(x: jax.Array, *, interpret: bool | None = None,
     re = flat.real.astype(jnp.float32)
     im = flat.imag.astype(jnp.float32)
     (re, im), tile = _tile_and_pad([re, im], b, n, tile_b=tile_b)
-    out = irfft_pallas(re, im, tile_b=tile, interpret=interpret,
-                       radices=radices)
+    out = irfft_pallas(re, im, tile_b=tile, interpret=interpret)
     padded = b + (-b) % tile
     record_launch("fft-c2r", grid=(padded // tile,), tile=(tile, n),
                   bytes_moved=4 * padded * (2 * (m + 1) + n),

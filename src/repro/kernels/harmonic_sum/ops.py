@@ -23,10 +23,13 @@ Edge cases (tested in tests/test_kernels.py):
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import batch_tile, use_interpret
+from repro.kernels.common import (LANES, batch_tile, round_up, rows_tile,
+                                  use_interpret)
 from repro.kernels.harmonic_sum.harmonic_sum_kernel import (
     harmonic_sum_pallas, harmonic_sum_plane_pallas)
 from repro.obs.ledger import record_launch
@@ -53,18 +56,19 @@ def _checked_power(power, n_harmonics: int, fn_name: str) -> jax.Array:
     return power.astype(jnp.float32)
 
 
-def _tiled(power: jax.Array) -> tuple[jax.Array, int, int, tuple[int, ...]]:
-    """Flatten lead dims and pad the batch to a VMEM-sized tile multiple."""
+def _tiled(power: jax.Array, n_harmonics: int, out_planes: int
+           ) -> tuple[jax.Array, int, int, tuple[int, ...]]:
+    """Flatten lead dims, pad the batch to a VMEM-sized tile multiple and
+    each row to 128-aligned bins plus the kernel's 128 * H zero bins."""
     lead = power.shape[:-1]
     n = power.shape[-1]
     b = 1
     for d in lead:
         b *= d
-    p2 = power.reshape(b, n)
-    tile = min(batch_tile(n, 4, buffers=8), b)
-    pad = (-b) % tile
-    if pad:
-        p2 = jnp.pad(p2, ((0, pad), (0, 0)))
+    width = round_up(n, LANES) + LANES * n_harmonics
+    tile, rows = rows_tile(b, batch_tile(width, 4,
+                                         buffers=2 * (1 + out_planes)))
+    p2 = jnp.pad(power.reshape(b, n), ((0, rows - b), (0, width - n)))
     return p2, b, tile, lead
 
 
@@ -74,10 +78,11 @@ def harmonic_sum_kernel(power: jax.Array, n_harmonics: int = 32, *,
     if interpret is None:
         interpret = use_interpret()
     power = _checked_power(power, n_harmonics, "harmonic_sum_kernel")
-    p2, b, tile, lead = _tiled(power)
-    out = harmonic_sum_pallas(p2, n_harmonics, tile_b=tile,
-                              interpret=interpret)[:b]
+    levels = int(math.log2(n_harmonics)) + 1
+    p2, b, tile, lead = _tiled(power, n_harmonics, levels)
     n = power.shape[-1]
+    out = harmonic_sum_pallas(p2, n_harmonics, tile_b=tile,
+                              interpret=interpret)[:b, :, :n]
     record_launch("harmonic-sum", grid=(p2.shape[0] // tile,),
                   tile=(tile, n),
                   bytes_moved=4 * p2.shape[0] * n * (1 + out.shape[-2]),
@@ -98,11 +103,12 @@ def harmonic_sum_plane(power: jax.Array, n_harmonics: int = 8, *,
     if interpret is None:
         interpret = use_interpret()
     power = _checked_power(power, n_harmonics, "harmonic_sum_plane")
-    p2, b, tile, lead = _tiled(power)
+    p2, b, tile, lead = _tiled(power, n_harmonics, 2)
     stat, lev = harmonic_sum_plane_pallas(p2, n_harmonics, tile_b=tile,
                                           interpret=interpret)
     n = power.shape[-1]
     record_launch("harmonic-sum-plane", grid=(p2.shape[0] // tile,),
                   tile=(tile, n), bytes_moved=12 * p2.shape[0] * n,
                   shape=(b, n))
-    return stat[:b].reshape(*lead, n), lev[:b].reshape(*lead, n)
+    return (stat[:b, :n].reshape(*lead, n),
+            lev[:b, :n].reshape(*lead, n))

@@ -4,7 +4,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import batch_tile, use_interpret
+from repro.kernels.common import batch_tile, rows_tile, use_interpret
 from repro.kernels.spectrum.spectrum_kernel import power_spectrum_stats_pallas
 from repro.obs.ledger import record_launch
 
@@ -26,11 +26,10 @@ def power_spectrum_stats_kernel(x: jax.Array, *,
         b *= d
     re = x.real.reshape(b, n).astype(jnp.float32)
     im = x.imag.reshape(b, n).astype(jnp.float32)
-    tile = min(batch_tile(n, 4, buffers=5), b)
-    pad = (-b) % tile
-    if pad:
-        re = jnp.pad(re, ((0, pad), (0, 0)))
-        im = jnp.pad(im, ((0, pad), (0, 0)))
+    tile, rows = rows_tile(b, batch_tile(n, 4, buffers=6))
+    if rows > b:
+        re = jnp.pad(re, ((0, rows - b), (0, 0)))
+        im = jnp.pad(im, ((0, rows - b), (0, 0)))
     p, mean, var = power_spectrum_stats_pallas(re, im, tile_b=tile,
                                                interpret=interpret)
     record_launch("power-spectrum-stats", grid=(re.shape[0] // tile,),
@@ -38,5 +37,5 @@ def power_spectrum_stats_kernel(x: jax.Array, *,
                   bytes_moved=4 * re.shape[0] * (3 * n + 2),
                   shape=(b, n))
     std = jnp.sqrt(jnp.maximum(var, 0.0))
-    return (p[:b].reshape(*lead, n), mean[:b].reshape(lead),
-            std[:b].reshape(lead))
+    return (p[:b].reshape(*lead, n), mean[:b, 0].reshape(lead),
+            std[:b, 0].reshape(lead))

@@ -6,7 +6,8 @@ traffic of the non-FFT pipeline (a beyond-paper optimisation recorded in
 EXPERIMENTS.md Sec. Perf).  One pass: read (re, im), emit power, and reduce
 sum / sum-of-squares for the row statistics.
 
-Grid: 1-D over batch tiles; (TILE_B, N) resident in VMEM.
+Grid: 1-D over batch tiles; (TILE_B, N) resident in VMEM.  The row
+mean and variance come out as (TILE_B, 1) columns.
 """
 from __future__ import annotations
 
@@ -23,9 +24,9 @@ def _spectrum_body(re_ref, im_ref, p_ref, mean_ref, var_ref):
     n = re.shape[-1]
     p = (re * re + im * im) / n
     p_ref[...] = p
-    mean = jnp.mean(p, axis=-1)
+    mean = jnp.mean(p, axis=-1, keepdims=True)
     mean_ref[...] = mean
-    var_ref[...] = jnp.mean(p * p, axis=-1) - mean * mean
+    var_ref[...] = jnp.mean(p * p, axis=-1, keepdims=True) - mean * mean
 
 
 @functools.partial(jax.jit, static_argnames=("tile_b", "interpret"))
@@ -40,7 +41,9 @@ def power_spectrum_stats_pallas(re: jax.Array, im: jax.Array, *,
             f"layer (repro.kernels.spectrum.ops) pads batches to tile "
             f"multiples — route through it or pass a dividing tile")
     row = pl.BlockSpec((tile_b, n), lambda i: (i, 0))
-    vec = pl.BlockSpec((tile_b,), lambda i: (i,))
+    # Row statistics leave as (tile_b, 1) columns: a rank-1 block of
+    # tile_b would have to be a multiple of 128 on the chip.
+    vec = pl.BlockSpec((tile_b, 1), lambda i: (i, 0))
     fn = pl.pallas_call(
         _spectrum_body,
         grid=(b // tile_b,),
@@ -48,8 +51,8 @@ def power_spectrum_stats_pallas(re: jax.Array, im: jax.Array, *,
         out_specs=[row, vec, vec],
         out_shape=[
             jax.ShapeDtypeStruct((b, n), jnp.float32),
-            jax.ShapeDtypeStruct((b,), jnp.float32),
-            jax.ShapeDtypeStruct((b,), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1), jnp.float32),
         ],
         interpret=interpret,
     )

@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
-                           + os.environ.get("XLA_FLAGS", ""))
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this produces a JSON artifact with:
@@ -16,6 +12,7 @@ Usage:
   python -m repro.launch.dryrun --all          # every cell, both meshes
 """
 import argparse
+import os
 import gzip
 import json
 import subprocess
@@ -30,7 +27,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.analysis.hlo import analyze_hlo
 from repro.analysis.roofline import model_flops_for
 from repro.configs import ARCHS, get_arch, get_shape, shapes_for
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import force_host_devices, make_production_mesh
 from repro.launch.specs import fix_tree, input_specs
 from repro.models.api import build_model
 from repro.obs.log import get_logger
@@ -183,9 +180,6 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    # jax < 0.4.35 returned [dict]; newer versions return the dict directly.
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     hlo_text = compiled.as_text()
     hlo = analyze_hlo(hlo_text)
 
@@ -285,6 +279,7 @@ def run_all(out_dir: str, multi_pod_only: bool = False):
 
 
 def main():
+    force_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ARCHS))
     ap.add_argument("--shape")

@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
-                           + os.environ.get("XLA_FLAGS", ""))
-
 """Dry-run of the paper's OWN workload on the production mesh: the
 distributed pencil FFT (batch x 32M-point transforms, n1 sharded over the
 model axis) lowered + compiled on 16x16 and 2x16x16, with the same
@@ -10,6 +6,7 @@ roofline artifact as the LM cells.
   PYTHONPATH=src python -m repro.launch.fft_dryrun [--multi-pod]
 """
 import argparse
+import os
 import gzip
 import json
 import math
@@ -22,13 +19,14 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.analysis.hlo import analyze_hlo
 from repro.configs.fft_bench import CONFIG
 from repro.fft.distributed import pencil_collective_bytes, pencil_fft
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import force_host_devices, make_production_mesh
 
 ART = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                    "artifacts", "dryrun")
 
 
 def main():
+    force_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--out", default=os.path.abspath(ART))

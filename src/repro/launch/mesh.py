@@ -5,7 +5,17 @@ touches jax device state — the dry-run sets XLA_FLAGS before first init.
 """
 from __future__ import annotations
 
+import os
+
 import jax
+
+
+def force_host_devices() -> None:
+    """512 host devices for the production mesh.  The dry-run entry
+    points call it in ``main`` before the first backend call, so importing
+    them changes nothing."""
+    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
+                               + os.environ.get("XLA_FLAGS", ""))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -21,7 +31,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def batch_axes(mesh) -> tuple[str, ...]:

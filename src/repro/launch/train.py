@@ -58,7 +58,8 @@ def main(argv=None):
     model = build_model(cfg)
 
     d, m = (int(x) for x in args.mesh.split("x"))
-    mesh = jax.make_mesh((d, m), ("data", "model"))
+    mesh = jax.make_mesh((d, m), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
     state = init_train_state(model, jax.random.PRNGKey(0))
     specs = train_state_specs(model)

@@ -34,13 +34,14 @@ CACHE_ENV = "REPRO_TUNE_CACHE"
 
 
 def default_device_name() -> str:
-    """A filesystem-safe identifier of the local accelerator."""
-    try:
-        import jax
-        kind = jax.devices()[0].device_kind
-    except Exception:                                  # pragma: no cover
-        kind = "cpu"
-    return re.sub(r"[^A-Za-z0-9_.-]+", "-", str(kind)).strip("-") or "cpu"
+    """A filesystem-safe identifier of the local accelerator.
+
+    A device that cannot be queried raises: naming it "cpu" would file a
+    chip's tuning results under the wrong device.
+    """
+    import jax
+    kind = jax.devices()[0].device_kind
+    return re.sub(r"[^A-Za-z0-9_.-]+", "-", str(kind)).strip("-")
 
 
 def cache_path(device: str | None = None) -> str:

@@ -187,10 +187,11 @@ def test_conv_falls_back_without_pallas(monkeypatch):
     for hook in ("_kernel_fft", "_kernel_rfft", "_kernel_irfft",
                  "_kernel_fft_mul", "_kernel_fft_t", "_kernel_fft_axis1",
                  "_kernel_rfft_t", "_kernel_transpose"):
-        monkeypatch.setattr(plan_mod, hook, None)
+        monkeypatch.setattr(plan_mod, hook, _no_kernel)
     x = rand_complex((2, 333), key=jax.random.PRNGKey(23))
     h = np.asarray(rand_complex((3, 17), key=jax.random.PRNGKey(24)))
-    assert_close(overlap_save_conv(x, h), oracle(x, h))
+    with plan_mod.pallas_disabled():
+        assert_close(overlap_save_conv(x, h), oracle(x, h))
 
 
 def test_fft_mul_kernel_parity():
@@ -219,3 +220,7 @@ def test_conv_plan_unfused_beyond_kernel_limit():
     assert plan.inverse_passes > plan.templates      # four-step inverses
     fused = conv_plan(2**15, 33, templates=2)
     assert fused.fused and fused.forward_passes == 1
+
+
+def _no_kernel(*args, **kwargs):
+    raise AssertionError("a Pallas kernel ran with Pallas disabled")

@@ -143,3 +143,17 @@ def test_tpu_device_roofline_constants():
     assert TPU_V5E.peak_flops == pytest.approx(197e12)
     assert TPU_V5E.hbm_bandwidth == pytest.approx(819e9)
     assert TPU_V5E.link_bandwidth == pytest.approx(50e9)
+
+
+def test_spec_for_device_kind_knows_v5e():
+    """The kind string a v5e reports to JAX."""
+    from repro.core.hardware import spec_for_device_kind
+    assert spec_for_device_kind("TPU v5 lite") is TPU_V5E
+
+
+@pytest.mark.parametrize("kind", ["TPU v4", "TPU v5", "TPU v6 lite"])
+def test_spec_for_device_kind_rejects_other_tpus(kind):
+    """Another chip priced with the v5e model would be silently wrong."""
+    from repro.core.hardware import spec_for_device_kind
+    with pytest.raises(ValueError, match=kind):
+        spec_for_device_kind(kind)

@@ -36,7 +36,8 @@ def test_pencil_fft_matches_reference():
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
         from repro.fft.distributed import pencil_fft, untranspose_ref
 
-        mesh = jax.make_mesh((8,), ("model",))
+        mesh = jax.make_mesh((8,), ("model",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         n1, n2, batch = 64, 128, 2
         key = jax.random.PRNGKey(0)
         x = (jax.random.normal(key, (batch, n1, n2)) +
@@ -58,7 +59,8 @@ def test_batch_parallel_fft():
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
         from repro.fft.distributed import batch_parallel_fft
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         x = (jax.random.normal(jax.random.PRNGKey(0), (16, 512)) +
              1j * jax.random.normal(jax.random.PRNGKey(1), (16, 512))
              ).astype(jnp.complex64)
@@ -79,7 +81,8 @@ def test_pencil_rfft_matches_reference():
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
         from repro.fft.distributed import assemble_rfft_pencil, pencil_fft
 
-        mesh = jax.make_mesh((8,), ("model",))
+        mesh = jax.make_mesh((8,), ("model",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         n1, n2, batch = 32, 64, 2
         x = jax.random.normal(jax.random.PRNGKey(0), (batch, n1, n2),
                               jnp.float32)
@@ -101,7 +104,8 @@ def test_batch_parallel_fft_r2c_kind():
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
         from repro.fft.distributed import batch_parallel_fft
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         x = jax.random.normal(jax.random.PRNGKey(0), (16, 512), jnp.float32)
         xs = jax.device_put(x, NamedSharding(mesh, P("data", None)))
         y = batch_parallel_fft(xs, mesh, kind="r2c")
@@ -121,7 +125,8 @@ def test_batch_parallel_fft_2d_plan_graph():
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
         from repro.fft.distributed import batch_parallel_fft
 
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = jax.make_mesh((4,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         x = (jax.random.normal(jax.random.PRNGKey(0), (8, 16, 32)) +
              1j * jax.random.normal(jax.random.PRNGKey(1), (8, 16, 32))
              ).astype(jnp.complex64)

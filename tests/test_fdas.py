@@ -141,10 +141,11 @@ def test_fdas_falls_back_without_pallas(monkeypatch):
     for hook in ("_kernel_fft", "_kernel_rfft", "_kernel_irfft",
                  "_kernel_fft_mul", "_kernel_fft_t", "_kernel_fft_axis1",
                  "_kernel_rfft_t", "_kernel_transpose"):
-        monkeypatch.setattr(plan_mod, hook, None)
+        monkeypatch.setattr(plan_mod, hook, _no_kernel)
     bank = TemplateBank.linear(zmax=2, n_templates=5)
     spec = rand_complex((1, 700), key=jax.random.PRNGKey(43))
-    got = np.asarray(matched_filter_plane(spec, bank))
+    with plan_mod.pallas_disabled():
+        got = np.asarray(matched_filter_plane(spec, bank))
     want = direct_plane(spec, bank)
     assert np.abs(got - want).max() / np.abs(want).max() <= 1e-4
 
@@ -285,3 +286,7 @@ def test_fdas_request_validation():
     assert a.shape_key("d") != b.shape_key("d")
     c = FFTRequest(x=jnp.zeros((2, 64)), templates=5)
     assert c.shape_key("d").templates == 0
+
+
+def _no_kernel(*args, **kwargs):
+    raise AssertionError("a Pallas kernel ran with Pallas disabled")

@@ -78,7 +78,7 @@ def test_fft_axis_argument():
 
 
 def test_float64_precision_path():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         x = rand_complex((2, 512), dtype=jnp.complex128)
         np.testing.assert_allclose(fft(x), jnp.fft.fft(x), rtol=1e-10)
 
@@ -136,7 +136,7 @@ def test_irfft_inverts_rfft(n):
 
 
 def test_rfft_float64_precision_path():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         x = jax.random.normal(KEY, (2, 512), dtype=jnp.float64)
         np.testing.assert_allclose(rfft(x), jnp.fft.rfft(x), rtol=1e-10)
         np.testing.assert_allclose(irfft(rfft(x)), x, rtol=1e-10)
@@ -243,14 +243,20 @@ def test_r2c_plan_routes_through_pallas_kernel(monkeypatch):
 
 @pytest.mark.parametrize("n", [2**9, 2**14, 45])
 def test_plans_fall_back_without_pallas(monkeypatch, n):
-    """With the kernel unavailable every plan stays correct (pure JAX)."""
+    """With Pallas disabled (the serving ladder's pure-JAX rung) every
+    plan stays correct and reaches no kernel hook."""
     for hook in ("_kernel_fft", "_kernel_rfft", "_kernel_irfft",
                  "_kernel_fft_t", "_kernel_fft_axis1", "_kernel_rfft_t",
                  "_kernel_transpose"):
-        monkeypatch.setattr(plan_mod, hook, None)
+        monkeypatch.setattr(plan_mod, hook, _no_kernel)
     x = rand_complex((5, n))
-    np.testing.assert_allclose(plan_for_length(n)(x), jnp.fft.fft(x),
-                               rtol=3e-3, atol=3e-3)
+    with plan_mod.pallas_disabled():
+        np.testing.assert_allclose(plan_for_length(n)(x), jnp.fft.fft(x),
+                                   rtol=3e-3, atol=3e-3)
+
+
+def _no_kernel(*args, **kwargs):
+    raise AssertionError("a Pallas kernel ran with Pallas disabled")
 
 
 def test_pallas_disable_env_skips_kernel(monkeypatch):
@@ -264,12 +270,18 @@ def test_pallas_disable_env_skips_kernel(monkeypatch):
 
 
 def test_broken_kernel_falls_back_gracefully(monkeypatch):
+    """A failing kernel raises to the caller: no silent retreat to the
+    pure-JAX engine, which runs only when asked for (the serving
+    ladder's bottom rung, via ``pallas_disabled``)."""
     def boom(*args, **kwargs):
         raise RuntimeError("no Pallas backend")
     monkeypatch.setattr(plan_mod, "_kernel_fft", boom)
     x = rand_complex((4, 2**9))
-    np.testing.assert_allclose(plan_mod.pow2_fft(x), jnp.fft.fft(x),
-                               rtol=3e-4, atol=3e-4)
+    with pytest.raises(RuntimeError, match="no Pallas backend"):
+        plan_mod.pow2_fft(x)
+    with plan_mod.pallas_disabled():
+        np.testing.assert_allclose(plan_mod.pow2_fft(x), jnp.fft.fft(x),
+                                   rtol=3e-4, atol=3e-4)
 
 
 # ---------------------------------------------------------------------------

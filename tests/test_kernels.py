@@ -325,3 +325,21 @@ class TestKernelInputValidation:
         re = jnp.ones((10, 64))
         with pytest.raises(ValueError, match=r"batch=10.*\(4\)"):
             power_spectrum_stats_pallas(re, re, tile_b=4, interpret=True)
+
+
+class TestBackendMode:
+    """Kernels compile on the TPU, run interpreted on the CPU (the test
+    path), and refuse any other backend rather than emulate it."""
+
+    @pytest.mark.parametrize("backend,interpret", [("cpu", True),
+                                                   ("tpu", False)])
+    def test_known_backends(self, monkeypatch, backend, interpret):
+        from repro.kernels import common
+        monkeypatch.setattr(common.jax, "default_backend", lambda: backend)
+        assert common.use_interpret() is interpret
+
+    def test_unknown_backend_raises(self, monkeypatch):
+        from repro.kernels import common
+        monkeypatch.setattr(common.jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            common.use_interpret()

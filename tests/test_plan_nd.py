@@ -220,11 +220,16 @@ def test_nd_falls_back_without_pallas(monkeypatch):
     for hook in ("_kernel_fft", "_kernel_rfft", "_kernel_irfft",
                  "_kernel_fft_t", "_kernel_fft_axis1", "_kernel_rfft_t",
                  "_kernel_transpose"):
-        monkeypatch.setattr(plan_mod, hook, None)
+        monkeypatch.setattr(plan_mod, hook, _no_kernel)
     x = rand_complex((6, 16, 32))
-    assert_close(fft2(x), jnp.fft.fft2(x))
     xr = jax.random.normal(KEY, (6, 16, 32))
-    assert_close(rfft2(xr), jnp.fft.rfft2(xr))
+    with plan_mod.pallas_disabled():
+        assert_close(fft2(x), jnp.fft.fft2(x))
+        assert_close(rfft2(xr), jnp.fft.rfft2(xr))
+
+
+def _no_kernel(*args, **kwargs):
+    raise AssertionError("a Pallas kernel ran with Pallas disabled")
 
 
 # ---------------------------------------------------------------------------

@@ -348,7 +348,8 @@ def test_sharded_service_matches_single_device_oracle():
         from repro.core.hardware import TPU_V5E
         from repro.serving import FFTService
 
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = jax.make_mesh((4,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         svc = FFTService(TPU_V5E, mesh=mesh)
         key = jax.random.PRNGKey(0)
         # 5 transforms: not divisible by 4 devices -> exercises padding
