@@ -359,10 +359,11 @@ def fft_pencil_roofline():
 
 
 def fft():
-    """Mixed-radix FFT engine microbench — persists BENCH_fft.json.
+    """FFT plan microbench — persists BENCH_fft.json.
 
-    Per length 2^10..2^22: plan route, HBM passes, butterfly stage count
-    (radix-2 vs mixed-radix), modelled J/transform at the optimal clock
+    Per length 2^10..2^22: plan route, HBM passes, the kernels' DFT-matrix
+    products, the pure-JAX engine's butterfly stage count (radix-2 vs
+    mixed-radix), modelled J/transform at the optimal clock
     (C2C vs R2C), and measured wall time (C2C vs R2C) through the routed
     plans (Pallas kernel in interpret mode off-TPU).  Long lengths are
     wall-timed only up to REPRO_FFT_BENCH_MAX_LOG2_WALL (default 13) —
@@ -373,7 +374,7 @@ def fft():
     from repro.core.hardware import TESLA_V100
     from repro.core.workloads import FFTCase, fft_workload
     from repro.fft.plan import _four_step_split, plan_for_length
-    from repro.fft.radix import stage_count
+    from repro.fft.radix import DEFAULT_RADICES, stage_count
 
     wall_max = int(os.environ.get("REPRO_FFT_BENCH_MAX_LOG2_WALL", "13"))
     dev = TESLA_V100
@@ -382,21 +383,23 @@ def fft():
         n = 2**logn
         plan_c = plan_for_length(n)
         plan_r = plan_for_length(n, "r2c")
-        # Like-for-like: sum stages over the plan's pow2 passes for both
-        # engines (a radix-2 four-step would run log2(n1)+log2(n2) stages).
-        if plan_c.algorithm == "four-step":
-            n1, n2 = _four_step_split(n)
-            stages_r2 = stage_count(n1, (2,)) + stage_count(n2, (2,))
-        else:
-            stages_r2 = stage_count(n, (2,))
+        # Butterfly stages of the pure-JAX Stockham engine (the bottom
+        # degradation rung; the Pallas kernels run DFT-matrix products,
+        # ``plan.stages``), summed over the plan's pow2 passes for both
+        # radix sets (a radix-2 four-step runs log2(n1)+log2(n2) stages).
+        parts = (_four_step_split(n) if plan_c.algorithm == "four-step"
+                 else (n,))
+        stages_r2 = sum(stage_count(p, (2,)) for p in parts)
+        stages_mixed = sum(stage_count(p, DEFAULT_RADICES) for p in parts)
         row = {
             "n": n,
             "algorithm": plan_c.algorithm,
             "passes_c2c": plan_c.passes,
             "passes_r2c": plan_r.passes,
+            "dft_products": plan_c.stages,
             "stages_radix2": stages_r2,
-            "stages_mixed": plan_c.stages,
-            "stage_ratio": stages_r2 / max(plan_c.stages, 1),
+            "stages_mixed": stages_mixed,
+            "stage_ratio": stages_r2 / max(stages_mixed, 1),
         }
         for transform, plan in (("c2c", plan_c), ("r2c", plan_r)):
             case = FFTCase(n=n, transform=transform, radices=(4, 2))

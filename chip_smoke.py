@@ -12,7 +12,7 @@ a plain reference that does not use the code under test:
   bluestein  4 x (1024, 3000) complex64     non-pow2 route
   fft2       8 x (4096, 4096) complex64     2-D plan graph
   fdas       2 x 2^20-sample series         overlap-save + fused bank mul
-  pulsar     2 x (64 ch, 2^14) filterbanks  dedisp, R2C, FDAS, hsum, sift
+  pulsar     2 x (1024 ch, 2^17) filterbanks  dedisp, R2C, FDAS, hsum, sift
 
 Every receipt must be served at rung 0 (the Pallas path; the pure-JAX
 rung is never acceptable here) and the service's launch ledger must show
@@ -57,8 +57,10 @@ PENCIL_BOUND = 1e-5
 DEDISP_BOUND = 1e-5
 
 # (requests, rows per request, length) at full size and for the CPU
-# rehearsal.  The pulsar and fdas phases keep their full length in the
-# rehearsal: their injected-signal recovery is what needs rehearsing.
+# rehearsal; for pulsar, (filterbanks, channels, samples).  The fdas
+# phase keeps its full length in the rehearsal, and the pulsar phase a
+# size that still takes the channel-tiled dedispersion and the packed
+# four-step R2C: their injected-signal recovery is what needs rehearsing.
 FULL = {
     "c2c": (4, 8192, 4096),
     "c2c_long": (2, 1024, 65536),
@@ -66,7 +68,7 @@ FULL = {
     "bluestein": (4, 1024, 3000),
     "fft2": (8, 4096, 4096),
     "fdas": (2, 1, 2**20),
-    "pulsar": (2, 64, 2**14),
+    "pulsar": (2, 1024, 2**17),
 }
 REHEARSAL = {
     "c2c": (4, 16, 4096),
@@ -75,12 +77,30 @@ REHEARSAL = {
     "bluestein": (4, 8, 3000),
     "fft2": (2, 256, 256),
     "fdas": (2, 1, 2**20),
-    "pulsar": (2, 64, 2**14),
+    "pulsar": (2, 128, 2**15),
 }
 FDAS_TEMPLATES = 16
-PULSAR = {"dm_trials": 16, "templates": 8, "n_harmonics": 8, "amp": 0.03}
-# (DM trial, template, bin) injected per filterbank.
+# The injected amplitude is 0.03 at 64 channels x 2^14 samples and scales
+# as 1/sqrt(channels x samples), which holds each pulsar's expected
+# normalised power (and so the recovery margin) fixed at every size.
+PULSAR = {"dm_trials": 16, "templates": 8, "n_harmonics": 8}
+PULSAR_AMP_AT_64x2_14 = 0.03
+# Why the full-size pulsar phase stops where it does (tests/
+# test_tpu_compile.py compiles both sides of each limit).
+PULSAR_SIZE_NOTE = (
+    "2^17 samples: the harmonic-sum plane kernel keeps whole padded "
+    "spectrum rows in VMEM and exceeds the 16 MiB scoped limit at 2^18 "
+    "(24.09 MiB); 1024 channels: a common survey channel count, not a "
+    "kernel limit (the channel-tiled dedispersion kernel and the whole "
+    "search also compile at 4096 channels), kept so the host-side "
+    "reference check stays about a minute")
+# (DM trial, template, bin at 2^14 samples) injected per filterbank.  The
+# bin scales with the block length: adjacent DM trials differ by ~4
+# samples of delay across the band, a phase spread of 2 pi bin 4 / ntime
+# at the pulsar's frequency, so a fixed bin would let neighbouring trials
+# stay coherent (and noise pick between them) at longer blocks.
 PULSAR_INJECTED = [[(4, 6, 1500), (11, 1, 3900)], [(8, 4, 2400)]]
+PULSAR_INJECTED_NTIME = 2**14
 FDAS_INJECTED = [(11, 200_000), (3, 345_678)]          # (template, bin)
 
 
@@ -223,7 +243,7 @@ def phase_fft(jax, svc, sizes, rng) -> list[dict]:
 
 def phase_fdas(jax, svc, sizes, rng) -> dict:
     import jax.numpy as jnp
-    from repro.search.fdas import fdas_search
+    from repro.search.fdas import fdas_search, serving_candidates
     from repro.search.templates import TemplateBank
 
     k, _, n = sizes["fdas"]
@@ -246,16 +266,25 @@ def phase_fdas(jax, svc, sizes, rng) -> dict:
         if int(top[0]) != t or abs(int(top[1]) - k0) > 1:
             raise SmokeError(f"fdas: top candidate {top[:2]} is not the "
                              f"injected (template {t}, bin {k0})")
-    del recs
-    recs, steady_s = _serve(jax, svc, xs, kind="fdas",
-                            templates=FDAS_TEMPLATES)
-    _check_receipts("fdas", recs, {"fft-c2c-mul", "fft-c2c"})
-    del recs
+    recs2, steady_s = _serve(jax, svc, xs, kind="fdas",
+                             templates=FDAS_TEMPLATES)
+    _check_receipts("fdas", recs2, {"fft-c2c-mul", "fft-c2c"})
+    del recs2
 
     # The power plane behind those candidates, against a numpy matched
-    # filter (float64 FFT convolution) over the same bank.
+    # filter (float64 FFT convolution) over the same bank.  The receipts
+    # carry candidates only, so the plane comes from a direct call of
+    # the function the service compiled, on the batch it drained; its
+    # candidates must equal the served ones.
     x = np.concatenate(xs)
-    plane = np.asarray(fdas_search(jnp.asarray(x), bank).power)
+    direct = fdas_search(jnp.asarray(x), bank)
+    plane = np.asarray(direct.power)
+    served = np.concatenate([np.asarray(r.result) for r in recs])
+    if not np.array_equal(np.asarray(serving_candidates(direct))[..., :2],
+                          served[..., :2]):
+        raise SmokeError("fdas: the checked plane's candidates differ from "
+                         "the served candidates")
+    del recs
     xc = x.astype(np.float64) - x.mean(axis=-1, keepdims=True)
     spec = np.fft.rfft(xc, axis=-1)
     nbins = spec.shape[-1]
@@ -270,6 +299,8 @@ def phase_fdas(jax, svc, sizes, rng) -> dict:
         "phase": "fdas", "requests": k, "series_samples": n,
         "templates": FDAS_TEMPLATES, "plane_shape": list(plane.shape),
         "rows_checked": "all", "rel_l2_err": err, "bound": BOUND,
+        "plane_source": "direct fdas_search on the drained batch; its "
+                        "candidates equal the served ones",
         "injected": [list(i) for i in FDAS_INJECTED[:k]],
         "recovered": found, "launches": launches, "rungs": [0],
         "first_call_s_informational": first_s,
@@ -286,31 +317,39 @@ def phase_pulsar(jax, svc, sizes, seed: int) -> dict:
     from repro.data.synthetic import (FilterbankSpec, InjectedPulsar,
                                       synthetic_filterbank)
     from repro.kernels.dedisp.ops import dedisperse_kernel
+    from repro.fft.plan import MAX_KERNEL_N
     from repro.kernels.dedisp.ref import dedisperse_ref
     from repro.search.pipeline import DispersionPlan
     from repro.search.templates import TemplateBank
 
     k, nchan, ntime = sizes["pulsar"]
+    amp = PULSAR_AMP_AT_64x2_14 * np.sqrt(64 * 2**14 / (nchan * ntime))
     spec = FilterbankSpec(nchan=nchan, ntime=ntime)
     dplan = DispersionPlan.from_spec(spec, n_trials=PULSAR["dm_trials"])
     bank = TemplateBank.linear(zmax=(PULSAR["templates"] - 1) / 2.0,
                                n_templates=PULSAR["templates"])
+    injected = [[(d, t, b * ntime // PULSAR_INJECTED_NTIME)
+                 for d, t, b in inj] for inj in PULSAR_INJECTED[:k]]
     fbs = []
-    for i, inj in enumerate(PULSAR_INJECTED[:k]):
+    for i, inj in enumerate(injected):
         pulsars = tuple(InjectedPulsar(dm=dplan.dms[d], k0=b,
-                                       z=bank.drifts[t], amp=PULSAR["amp"])
+                                       z=bank.drifts[t], amp=amp)
                         for d, t, b in inj)
         fbs.append(synthetic_filterbank(spec, pulsars, noise=1.0,
                                         seed=seed + 100 + i))
     kw = dict(kind="pulsar", dm_trials=PULSAR["dm_trials"],
               templates=PULSAR["templates"],
               n_harmonics=PULSAR["n_harmonics"])
-    families = {"dedisperse", "fft-r2c", "fft-c2c-mul", "fft-c2c",
-                "harmonic-sum-plane"}
+    # The series' R2C is one fused kernel up to 2 * MAX_KERNEL_N samples,
+    # a packed four-step (column + row pass) beyond.
+    r2c = ({"fft-r2c"} if ntime // 2 <= MAX_KERNEL_N
+           else {"fft-c2c-axis1", "fft-c2c-t"})
+    families = {"dedisperse", "fft-c2c-mul", "fft-c2c",
+                "harmonic-sum-plane"} | r2c
     recs, first_s = _serve(jax, svc, fbs, **kw)
     launches = _check_receipts("pulsar", recs, families)
     found = []
-    for inj, r in zip(PULSAR_INJECTED, recs):
+    for inj, r in zip(injected, recs):
         cands = np.asarray(r.result)[0]           # (k, 5): dm, t, bin, ...
         got = sorted((int(c[0]), int(c[1]), int(c[2]))
                      for c in cands if c[0] >= 0)
@@ -323,19 +362,27 @@ def phase_pulsar(jax, svc, sizes, seed: int) -> dict:
     _check_receipts("pulsar", recs, families)
     del recs
 
+    # The receipts carry candidates only: the series comes from a direct
+    # call of the kernel the pipeline runs first, on the drained batch.
     fb = np.stack(fbs)
+    del fbs
     series = np.asarray(dedisperse_kernel(jnp.asarray(fb), dplan.delays))
+    # ref.py gathers a (D, C, N) block per filterbank; the sum over
+    # channels is split into slabs of 64 to bound host memory.
+    delays = np.asarray(dplan.delays)
+    want = np.zeros(series.shape, np.float64)
     with jax.default_device(jax.devices("cpu")[0]):
-        want = np.asarray(dedisperse_ref(jnp.asarray(fb), dplan.delays))
+        for c in range(0, nchan, 64):
+            want += np.asarray(dedisperse_ref(jnp.asarray(fb[:, c:c + 64]),
+                                              delays[:, c:c + 64]))
     err = float(np.max(_rel_l2(series.astype(np.float64), want, -1)))
     line = {
         "phase": "pulsar", "filterbanks": k, "nchan": nchan,
         "ntime": ntime, **{key: PULSAR[key] for key in
                            ("dm_trials", "templates", "n_harmonics")},
-        "size_note": ("64 channels x 2^14 samples: the largest filterbank "
-                      "whose double-buffered (C, N) dedispersion block "
-                      "fits the 16 MiB scoped-VMEM limit at 2^14 samples"),
-        "injected": [sorted(i) for i in PULSAR_INJECTED[:k]],
+        "injected_amp": float(amp), "size_note": PULSAR_SIZE_NOTE,
+        "series_source": "direct dedisperse_kernel on the drained batch",
+        "injected": [sorted(i) for i in injected],
         "recovered": found, "false_positives": 0,
         "dedisp_rel_l2_err": err, "dedisp_bound": DEDISP_BOUND,
         "launches": launches, "rungs": [0],
@@ -378,16 +425,25 @@ def phase_four_chips(jax, spec, sizes, rng) -> list[dict]:
         for r, s in zip(recs, single))
     err_ref = max(_worst_rel_err(np.asarray(r.result), x, np.fft.fft)
                   for r, x in zip(recs, xs))
-    del recs, sharded, single
+    del recs, single
+    # Two rows do not divide over four devices: batch_parallel_fft pads
+    # them to four, transforms, replicates the result and cuts it back.
+    odd = [_complex(rng, (2, n))]
+    recs, _ = _serve(jax, sharded, odd)
+    _check_receipts("sharded_padded", recs, {"fft-c2c"})
+    err_pad = _worst_rel_err(np.asarray(recs[0].result), odd[0], np.fft.fft)
+    del recs, sharded
     lines = [{
         "phase": "sharded_service", "devices": 4, "mesh": {"data": 4},
         "requests": k, "request_shape": [rows, n],
         "rel_l2_err_vs_single_chip": err_vs_single,
         "bound_vs_single_chip": SHARDED_BOUND,
         "rel_l2_err_vs_numpy": err_ref, "bound": BOUND,
+        "padded_batch_rows": 2, "padded_rel_l2_err_vs_numpy": err_pad,
         "launches": launches, "rungs": [0],
         "first_call_s_informational": first_s}]
-    if not (err_vs_single <= SHARDED_BOUND and err_ref <= BOUND):
+    if not (err_vs_single <= SHARDED_BOUND and err_ref <= BOUND
+            and err_pad <= BOUND):
         _emit(lines[-1])
         raise SmokeError("sharded service disagrees with one chip")
 

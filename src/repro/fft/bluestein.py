@@ -56,8 +56,8 @@ def bluestein_fft(x: jax.Array, *, inverse: bool = False,
     """C2C DFT of arbitrary length along the last axis via chirp-z.
 
     ``config`` (a hashable :class:`repro.tune.KernelConfig`, static) rides
-    into the two inner pow2 FFTs so tuned tiles/radices actually execute
-    for Bluestein lengths too.
+    into the two inner pow2 FFTs so tuned tiles actually execute for
+    Bluestein lengths too.
     """
     from repro.fft.plan import pow2_fft          # lazy: avoids import cycle
 

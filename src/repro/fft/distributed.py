@@ -31,7 +31,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 from jax import shard_map
-from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 def pad_rows(x: jax.Array, rows: int) -> jax.Array:
@@ -50,6 +50,10 @@ def batch_parallel_fft(x: jax.Array, mesh: Mesh, *, axis: str = "data",
     Batches that do not divide the axis size are zero-padded to the next
     multiple, transformed, and sliced back — the serving layer coalesces
     requests into arbitrary batch sizes, so divisibility cannot be assumed.
+    A divided batch comes back sharded over ``axis``; a padded one comes
+    back replicated, because its row count cannot be split evenly over
+    the devices (an all-gather of the batch).  Meshes with ``Auto`` and
+    with ``Explicit`` axes take the same path.
 
     ``kind="r2c"`` routes real-input batches through the R2C plan (half
     the FLOPs and HBM traffic per shard) instead of silently casting to
@@ -73,11 +77,10 @@ def batch_parallel_fft(x: jax.Array, mesh: Mesh, *, axis: str = "data",
     out = fn(x)
     if out.shape[0] == b:
         return out
-    if AxisType.Explicit in mesh.axis_types:
-        # Rows sharded explicitly cannot be cut to a count the axis does
-        # not divide; replicate them first (only padded batches get here).
-        out = jax.sharding.reshard(out, NamedSharding(mesh, P()))
-    return out[:b]
+    # Replicate, then cut: XLA picks the same replicated result for an
+    # Auto mesh, and on Explicit axes a sharded array cannot be sliced to
+    # a row count the axis does not divide.
+    return jax.device_put(out, NamedSharding(mesh, P()))[:b]
 
 
 @functools.partial(jax.jit, static_argnames=("n1", "n2", "axis", "mesh"))

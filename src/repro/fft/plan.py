@@ -4,7 +4,8 @@ The paper leans on cuFFT's dispatch (Cooley-Tukey for smooth lengths,
 Bluestein otherwise, multi-kernel plans for long transforms).  Our planner
 mirrors it:
 
-  pow2, fits one kernel   -> single fused Stockham pass (Pallas kernel)
+  pow2, fits one kernel   -> single fused pass (Pallas kernel: DFT-matrix
+                             products on the MXU)
   pow2, long              -> four-step decomposition (two kernel passes
                              + cached twiddle)
   non-pow2                -> Bluestein (pow2 FFTs, cached chirp/filter)
@@ -25,7 +26,7 @@ invocations.
 **Tuning**: plan construction consults the active
 :class:`repro.tune.TuningContext` (exactly once per (device, shape, kind)
 — the context memoises) for a tuned :class:`repro.tune.KernelConfig`
-overriding the batch-tile / radix-schedule / four-step-split heuristics;
+overriding the batch-tile / four-step-split heuristics;
 ``REPRO_FFT_DISABLE_TUNING=1`` or the absence of a context restores the
 heuristic plans bit-for-bit (they are the same memoised objects).
 
@@ -50,9 +51,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.fft.bluestein import bluestein_fft
-from repro.fft.radix import DEFAULT_RADICES, radix_schedule, stage_count
 from repro.fft.stockham import (_as_complex, _irfft_merge, _pack_real,
                                 _rfft_split, _stockham_pow2, _unpack_real)
+from repro.kernels.common import LANES
 from repro.tune.config import KernelConfig
 from repro.tune.context import plan_config as _tuned_plan_config
 
@@ -111,12 +112,7 @@ def _kernel_overrides(config: KernelConfig | None) -> dict:
     """
     if config is None:
         return {}
-    kw = {}
-    if config.tile_b:
-        kw["tile_b"] = config.tile_b
-    if config.radices:
-        kw["radices"] = config.radices
-    return kw
+    return {"tile_b": config.tile_b} if config.tile_b else {}
 
 
 def _resolve_split(n: int, config: KernelConfig | None) -> tuple[int, int]:
@@ -132,11 +128,11 @@ def pow2_fft(x: jax.Array, *, inverse: bool = False,
              config: KernelConfig | None = None) -> jax.Array:
     """C2C FFT of a pow2 length, routed through the Pallas kernel.
 
-    Single-kernel lengths run the fused mixed-radix kernel (pure-JAX
+    Single-kernel lengths run the fused DFT-matrix kernel (pure-JAX
     Stockham with Pallas disabled); longer lengths recurse through the four-step
     decomposition so *every* pow2 pass of every plan lands on the kernel.
     ``config`` (a tuned :class:`repro.tune.KernelConfig`) overrides the
-    batch tile / radix schedule / four-step split heuristics.
+    batch tile / four-step split heuristics.
     """
     n = x.shape[-1]
     if n > MAX_SINGLE_PASS:
@@ -259,15 +255,21 @@ def _four_step_split(n: int) -> tuple[int, int]:
     return n1, n // n1
 
 
+def _dft_products(n: int) -> int:
+    """DFT-matrix products one fused kernel pass spends on a length-``n``
+    transform: a single (n, n) product up to 128 points, else the two of
+    its in-VMEM N1 x 128 four-step (``repro.kernels.fft.fft_kernel``)."""
+    return 1 if n <= LANES else 2
+
+
 @dataclasses.dataclass(frozen=True)
 class FFTPlan:
     n: int
-    algorithm: str              # "stockham" | "four-step" | "bluestein"
+    algorithm: str              # "single-pass" | "four-step" | "bluestein"
     passes: int                 # HBM read+write passes (DVFS model input)
     fn: Callable[[jax.Array], jax.Array]
     kind: str = "c2c"           # "c2c" | "r2c" | "c2r"
-    stages: int = 0             # butterfly stages per fused pass
-    radices: tuple[int, ...] = ()
+    stages: int = 0             # kernel DFT-matrix products per transform
 
     def __call__(self, x: jax.Array) -> jax.Array:
         return self.fn(x)
@@ -387,23 +389,19 @@ def _plan_for_length(n: int, kind: str,
                      config: KernelConfig | None) -> FFTPlan:
     if kind not in ("c2c", "r2c", "c2r"):
         raise ValueError(f"unknown transform kind {kind!r}")
-    radices = (config.radices if config is not None and config.radices
-               else DEFAULT_RADICES)
     if kind != "c2c":
         return _real_plan(n, kind, config)
     if _is_pow2(n):
-        schedule = radix_schedule(min(n, MAX_SINGLE_PASS), radices)
         if n <= MAX_SINGLE_PASS:
-            return FFTPlan(n, "stockham", 1,
+            return FFTPlan(n, "single-pass", 1,
                            functools.partial(_c2c_fn, config=config),
-                           stages=len(schedule), radices=schedule)
+                           stages=_dft_products(n))
         n1, n2 = _resolve_split(n, config)
         return FFTPlan(
             n, "four-step", 2,
             lambda x, n1=n1, n2=n2, c=config: four_step_fft(
                 _as_complex(x), n1, n2, config=c),
-            stages=stage_count(n1, radices) + stage_count(n2, radices),
-            radices=radix_schedule(n1, radices),
+            stages=_dft_products(n1) + _dft_products(n2),
         )
     # Bluestein: the filter-spectrum FFT is precomputed and cached per
     # length (repro.fft.bluestein), so only 2 pow2 FFTs of length
@@ -415,7 +413,7 @@ def _plan_for_length(n: int, kind: str,
     fn = (bluestein_fft if config is None
           else functools.partial(bluestein_fft, config=config))
     return FFTPlan(n, "bluestein", 2 * inner.passes + 1, fn,
-                   stages=inner.stages, radices=inner.radices)
+                   stages=inner.stages)
 
 
 def _real_plan(n: int, kind: str, config: KernelConfig | None) -> FFTPlan:
@@ -428,14 +426,15 @@ def _real_plan(n: int, kind: str, config: KernelConfig | None) -> FFTPlan:
         return FFTPlan(
             n, inner.algorithm, inner.passes,
             lambda x: inner.fn(_as_complex(x))[..., :n // 2 + 1],
-            kind="r2c", stages=inner.stages, radices=inner.radices)
+            kind="r2c", stages=inner.stages)
     m = max(n // 2, 1)
     inner = _plan_for_length(m, "c2c", config) if m > 1 else None
     passes = inner.passes if inner else 1
-    stages = inner.stages if inner else 0
-    radices = inner.radices if inner else ()
-    alg = inner.algorithm if inner else "stockham"
+    alg = inner.algorithm if inner else "single-pass"
+    # Up to 2 * MAX_KERNEL_N points the real kernels transform the n
+    # reals directly; longer rows pack into the m-point complex plan.
+    stages = (_dft_products(n) if 4 <= n and m <= MAX_KERNEL_N
+              else inner.stages if inner else 0)
     fn = (functools.partial(_r2c_fn, n=n, config=config) if kind == "r2c"
           else functools.partial(_c2r_fn, n=n, config=config))
-    return FFTPlan(n, alg, passes, fn, kind=kind, stages=stages,
-                   radices=radices)
+    return FFTPlan(n, alg, passes, fn, kind=kind, stages=stages)

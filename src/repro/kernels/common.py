@@ -38,6 +38,23 @@ def round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
+def vmem_compiler_params(block_bytes: int):
+    """Raise the scoped-VMEM limit for one kernel when its blocks (double
+    buffered) plus scratch and temporaries cannot fit the default.
+
+    Only kernels whose smallest legal block outgrows the default get
+    here (a transposed write needs 128 rows for a lane-dense output; a
+    dedispersion block keeps every DM trial's whole time axis).  v5e has
+    128 MiB of VMEM per core; the limit is sized from the blocks, not set
+    blindly.  None keeps the default.
+    """
+    from jax.experimental.pallas import tpu as pltpu
+    need = 3 * block_bytes // 2 + 4 * 2**20
+    if need <= SCOPED_VMEM_BYTES:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=min(need, 100 * 2**20))
+
+
 def batch_tile(n: int, elem_bytes: int, *,
                vmem_budget: int = BLOCK_BUDGET_BYTES, buffers: int = 4,
                align: int = SUBLANES, override: int | None = None) -> int:
@@ -48,17 +65,20 @@ def batch_tile(n: int, elem_bytes: int, *,
     is ``align`` times a power of two, so the service's power-of-two
     bucketed batches never pad, and at least one aligned block.
 
-    ``override`` short-circuits the heuristic with an explicit tile (the
-    autotuner's tuned choice, ``repro.tune``), rounded up to ``align``.
+    That tile is also the largest the budget admits: ``override`` (the
+    autotuner's tuned choice, ``repro.tune``) is rounded up to ``align``
+    and clamped to it, so no tuning-cache entry can hand the compiler a
+    tile that overflows VMEM.
     """
-    if override is not None:
-        if override < 1:
-            raise ValueError(f"batch tile override must be >= 1, "
-                             f"got {override}")
-        return round_up(override, align)
     per_row = max(n, 1) * elem_bytes * buffers
     blocks = max(vmem_budget // per_row // align, 1)
-    return (1 << (blocks.bit_length() - 1)) * align
+    budget_tile = (1 << (blocks.bit_length() - 1)) * align
+    if override is None:
+        return budget_tile
+    if override < 1:
+        raise ValueError(f"batch tile override must be >= 1, "
+                         f"got {override}")
+    return min(round_up(override, align), budget_tile)
 
 
 def rows_tile(rows: int, tile: int, align: int = SUBLANES) -> tuple[int, int]:

@@ -1,7 +1,8 @@
 """Brute-force incoherent dedispersion (many-DM shift-and-sum).
 
-  dedisp_kernel  pl.pallas_call body: statically unrolled per-(DM, delay
-                 group) ``lax.slice`` shifts over a VMEM-resident block
+  dedisp_kernel  pl.pallas_call body: lane-rotation shifts driven by an
+                 SMEM delay table, summed over channel slabs into a
+                 VMEM-resident (D, N) output block
   ops            public wrapper (guards, batch tiling, lead-dim plumbing)
   ref            gather-based pure-jnp oracle the tests assert against
 """

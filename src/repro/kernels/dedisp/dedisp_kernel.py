@@ -9,17 +9,22 @@ hierarchy): every delay is a whole number of samples, so
 
 zero-padded back to length N.  On the chip that is a lane rotation
 (``pltpu.roll``) by N - d with the wrapped tail masked to zero.  The
-(D, C) delay table rides in SMEM as scalar-prefetch data and the kernel
-loops over trials and channels, so the program stays the same size for
-any table (an unrolled D x C table of unaligned slices took the compiler
-minutes at 16 x 64); the (TILE_B, C, N) filterbank block is loaded from
-HBM exactly once and every one of the D * C accumulations reads it from
-VMEM.
+(D, C) delay table rides in SMEM as scalar-prefetch data (flattened, so
+SMEM pads no minor dimension) and the kernel loops over trials and
+channels, so the program stays the same size for any table (an unrolled
+D x C table of unaligned slices took the compiler minutes at 16 x 64).
 
-Grid: 1-D over batch tiles (whole channels and the whole time axis stay
-resident — a time-tiled variant would need halo reads of max-delay
-samples per tile, the overhead-access t_o term the paper's Sec. 5
-discussion prices).
+Grid: (batch tiles, channel tiles).  The channel axis is a reduction:
+each step reads one (TILE_B, TILE_C, N) slab of the filterbank once and
+adds its D * TILE_C shifted rows into the (TILE_B, D, N) output block,
+which stays resident in VMEM across the channel steps (its block index
+does not change with the channel), so the filterbank is read from HBM
+exactly once and the channel count is bounded by nothing in VMEM.  The
+sum still runs over channels 0..C-1 in order, as the untiled kernel did.
+Each trial keeps its whole time axis resident (a time-tiled variant would
+need halo reads of max-delay samples per tile, the overhead-access t_o
+term the paper's Sec. 5 discussion prices), so N is what VMEM bounds:
+2 * (D + TILE_C) * N * 4 bytes of blocks per filterbank row.
 """
 from __future__ import annotations
 
@@ -31,21 +36,28 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import vmem_compiler_params
 
-def _dedisp_body(delays_ref, fb_ref, out_ref):
-    tb, nchan, n = fb_ref.shape
+
+def _dedisp_body(delays_ref, fb_ref, out_ref, *, nchan: int):
+    tb, tc, n = fb_ref.shape
+    c0 = pl.program_id(1) * tc                 # first channel of the slab
     lane = jax.lax.broadcasted_iota(jnp.int32, (tb, n), 1)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
 
     def trial(d, carry):
         def channel(c, acc):
             x = fb_ref[:, pl.ds(c, 1), :].reshape(tb, n)
-            s = delays_ref[d, c]
+            s = delays_ref[d * nchan + c0 + c]
             # y[t] = x[t + s] for t < n - s, else 0 (zero-padded shift).
             y = pltpu.roll(x, jnp.where(s == 0, 0, n - s), 1)
             return acc + jnp.where(lane < n - s, y, 0.0)
 
-        acc = jax.lax.fori_loop(0, nchan, channel,
-                                jnp.zeros((tb, n), jnp.float32))
+        acc = jax.lax.fori_loop(0, tc, channel,
+                                out_ref[:, pl.ds(d, 1), :].reshape(tb, n))
         out_ref[:, pl.ds(d, 1), :] = acc.reshape(tb, 1, n)
         return carry
 
@@ -53,12 +65,18 @@ def _dedisp_body(delays_ref, fb_ref, out_ref):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("delays", "tile_b", "interpret"))
+                   static_argnames=("delays", "tile_b", "tile_c", "interpret"))
 def dedisperse_pallas(fb: jax.Array,
                       delays: tuple[tuple[int, ...], ...], *,
-                      tile_b: int = 1, interpret: bool = False):
-    """(b, C, N) filterbanks + static (D, C) delay table -> (b, D, N)."""
+                      tile_b: int = 1, tile_c: int | None = None,
+                      interpret: bool = False):
+    """(b, C, N) filterbanks + static (D, C) delay table -> (b, D, N).
+
+    ``tile_c`` channels are summed per grid step (None: all of them); it
+    divides C and is a multiple of 8 or C itself.
+    """
     b, nchan, n = fb.shape
+    tile_c = nchan if tile_c is None else tile_c
     # A ValueError, not an assert: asserts vanish under ``python -O`` and
     # a non-dividing tile would silently corrupt the grid partition.
     if tile_b < 1 or b % tile_b:
@@ -66,6 +84,10 @@ def dedisperse_pallas(fb: jax.Array,
             f"batch={b} is not a multiple of its tile ({tile_b}); the ops "
             f"layer (repro.kernels.dedisp.ops) pads batches to tile "
             f"multiples — route through it or pass a dividing tile")
+    if tile_c < 1 or nchan % tile_c or (tile_c != nchan and tile_c % 8):
+        raise ValueError(
+            f"channel tile {tile_c} must divide nchan={nchan} and be a "
+            f"multiple of 8 (or nchan itself)")
     ndm = len(delays)
     for trial, row in enumerate(delays):
         if len(row) != nchan:
@@ -78,15 +100,17 @@ def dedisperse_pallas(fb: jax.Array,
                     f"delay {d} of trial {trial} outside [0, ntime={n}); "
                     f"clip the DM grid to the block length")
     fn = pl.pallas_call(
-        _dedisp_body,
+        functools.partial(_dedisp_body, nchan=nchan),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(b // tile_b,),
-            in_specs=[pl.BlockSpec((tile_b, nchan, n),
-                                   lambda i, _: (i, 0, 0))],
+            grid=(b // tile_b, nchan // tile_c),
+            in_specs=[pl.BlockSpec((tile_b, tile_c, n),
+                                   lambda i, j, _: (i, j, 0))],
             out_specs=pl.BlockSpec((tile_b, ndm, n),
-                                   lambda i, _: (i, 0, 0))),
+                                   lambda i, j, _: (i, 0, 0))),
         out_shape=jax.ShapeDtypeStruct((b, ndm, n), jnp.float32),
+        compiler_params=vmem_compiler_params(
+            2 * (tile_c + ndm) * tile_b * n * 4),
         interpret=interpret,
     )
-    return fn(jnp.asarray(np.asarray(delays, np.int32)), fb)
+    return fn(jnp.asarray(np.asarray(delays, np.int32).reshape(-1)), fb)

@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.common import batch_tile, use_interpret
+from repro.kernels.common import SUBLANES, batch_tile, use_interpret
 from repro.kernels.dedisp.dedisp_kernel import dedisperse_pallas
 from repro.obs.ledger import record_launch
 
@@ -61,17 +61,21 @@ def dedisperse_kernel(fb: jax.Array, delays, *,
     for d in lead:
         b *= d
     x = fb.reshape(b, nchan, n)
-    # VMEM holds the (tile, C, N) block plus the (tile, D, N) output, both
-    # double-buffered; the tile is a leading block dimension (any size).
-    tile = min(batch_tile(n, 4, buffers=2 * (nchan + len(static)),
+    # VMEM holds a (tile, tile_c, N) channel slab plus the (tile, D, N)
+    # output, both double-buffered; the batch tile is a leading block
+    # dimension (any size).  One sublane group of channels per step keeps
+    # the slab small whatever the channel count.
+    tile_c = SUBLANES if nchan % SUBLANES == 0 else nchan
+    tile = min(batch_tile(n, 4, buffers=2 * (tile_c + len(static)),
                           align=1), b)
     pad = (-b) % tile
     if pad:
         x = jnp.pad(x, ((0, pad), (0, 0), (0, 0)))
-    out = dedisperse_pallas(x, static, tile_b=tile, interpret=interpret)[:b]
+    out = dedisperse_pallas(x, static, tile_b=tile, tile_c=tile_c,
+                            interpret=interpret)[:b]
     padded = b + pad
-    record_launch("dedisperse", grid=(padded // tile,),
-                  tile=(tile, nchan, n),
+    record_launch("dedisperse", grid=(padded // tile, nchan // tile_c),
+                  tile=(tile, tile_c, n),
                   bytes_moved=4 * padded * n * (nchan + len(static)),
                   shape=(b, nchan, n))
     return out.reshape(*lead, len(static), n)

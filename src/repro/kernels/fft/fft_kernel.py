@@ -31,7 +31,8 @@ Layout and arithmetic:
 VMEM: the ops layer (``repro.kernels.fft.ops``) sizes tiles against
 Mosaic's 16 MiB scoped limit.  The transposed-write kernels need 128-row
 tiles to keep their output lane-dense; where those blocks cannot fit the
-default limit, the kernel raises it for itself (``_compiler_params``).
+default limit, the kernel raises it for itself
+(``repro.kernels.common.vmem_compiler_params``).
 """
 from __future__ import annotations
 
@@ -44,7 +45,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import LANES, SUBLANES, SCOPED_VMEM_BYTES
+from repro.kernels.common import LANES, SUBLANES, vmem_compiler_params
 
 _HI = jax.lax.Precision.HIGHEST
 
@@ -242,21 +243,6 @@ def _for_row_blocks(rows: int, sub: int, fn) -> None:
 
 def _load(refs) -> tuple:
     return tuple(r[...] for r in refs)
-
-
-def _compiler_params(block_bytes: int):
-    """Raise the scoped-VMEM limit for one kernel when its blocks (double
-    buffered) plus scratch and temporaries cannot fit the default.
-
-    Only the transposed-write kernels get here: their output minor
-    dimension is the row tile, so the tile cannot drop below 128 rows,
-    and at long rows that floor outgrows 16 MiB.  v5e has 128 MiB of
-    VMEM per core; the limit is sized from the blocks, not set blindly.
-    """
-    need = 3 * block_bytes // 2 + 4 * 2**20
-    if need <= SCOPED_VMEM_BYTES:
-        return None
-    return pltpu.CompilerParams(vmem_limit_bytes=min(need, 100 * 2**20))
 
 
 # ---------------------------------------------------------------------------
@@ -554,7 +540,7 @@ def _fft_t_call(re, im, ftw, *, tile_r: int, inverse: bool,
         out_specs=[out_spec, out_spec],
         out_shape=[jax.ShapeDtypeStruct((b, c, r), jnp.float32)] * 2,
         scratch_shapes=[pltpu.VMEM((tile_r, c), jnp.float32)] * 2,
-        compiler_params=_compiler_params(
+        compiler_params=vmem_compiler_params(
             (2 * planes + 4) * tile_r * c * 4),
         interpret=interpret,
     )
@@ -615,7 +601,7 @@ def _fft_axis1_call(re, im, ftw, *, tile_c: int, inverse: bool,
         out_specs=[spec, spec],
         out_shape=[jax.ShapeDtypeStruct((b, r, c), jnp.float32)] * 2,
         scratch_shapes=[pltpu.VMEM((tile_c, r), jnp.float32)] * 2,
-        compiler_params=_compiler_params(
+        compiler_params=vmem_compiler_params(
             (2 * planes + 4) * tile_c * r * 4),
         interpret=interpret,
     )
@@ -670,7 +656,7 @@ def rfft_t_pallas(x: jax.Array, *, tile_r: int = 8, interpret: bool = False):
         out_specs=[out_spec, out_spec],
         out_shape=[jax.ShapeDtypeStruct((b, m + 1, r), jnp.float32)] * 2,
         scratch_shapes=[pltpu.VMEM((tile_r, m), jnp.float32)] * 2,
-        compiler_params=_compiler_params(8 * tile_r * c * 4),
+        compiler_params=vmem_compiler_params(8 * tile_r * c * 4),
         interpret=interpret,
     )
     return tuple(fn(x, *tabs))
@@ -700,7 +686,7 @@ def transpose_pallas(*planes: jax.Array, tile_r: int = 128,
         in_specs=[in_spec] * len(planes),
         out_specs=[out_spec] * len(planes),
         out_shape=out_shape,
-        compiler_params=_compiler_params(
+        compiler_params=vmem_compiler_params(
             4 * len(planes) * tile_r * tile_c * planes[0].dtype.itemsize),
         interpret=interpret,
     )

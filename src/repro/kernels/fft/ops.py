@@ -19,11 +19,6 @@ from repro.kernels.fft.fft_kernel import (fft_axis1_pallas,
 # One fused pass handles transforms that fit VMEM alongside work buffers.
 MAX_KERNEL_N = 2**13
 
-# Every wrapper takes ``radices`` for signature parity with the pure-JAX
-# Stockham engine (``repro.fft.stockham``, the bottom degradation rung)
-# and the autotuner's configs; the MXU kernels have one fixed
-# factorisation and ignore it.
-
 
 def _check_kernel_length(n: int) -> None:
     if n > MAX_KERNEL_N:
@@ -61,7 +56,6 @@ def _tile_and_pad(planes: list[jax.Array], b: int, n: int,
 
 def fft_kernel_c2c(x: jax.Array, *, inverse: bool = False,
                    interpret: bool | None = None,
-                   radices: tuple[int, ...] | None = None,
                    tile_b: int | None = None) -> jax.Array:
     """Batched pow2 C2C FFT (..., N) via the Pallas kernel.
 
@@ -98,7 +92,6 @@ def fft_kernel_c2c(x: jax.Array, *, inverse: bool = False,
 
 def fft_kernel_c2c_mul(x: jax.Array, bank, *, inverse: bool = False,
                        interpret: bool | None = None,
-                       radices: tuple[int, ...] | None = None,
                        tile_b: int | None = None) -> jax.Array:
     """Fused pow2 C2C FFT + (T, N) filter-bank multiply epilogue.
 
@@ -131,7 +124,9 @@ def fft_kernel_c2c_mul(x: jax.Array, bank, *, inverse: bool = False,
     # pinned bank takes its share of the budget first.
     per_row = 2 * 4 * (2 * SUBLANES * n + 2 * t * n)
     room = BLOCK_BUDGET_BYTES - 2 * 4 * 2 * t * n
-    tile = tile_b if tile_b is not None else max(room // per_row, 1)
+    tile = max(room // per_row, 1)
+    if tile_b is not None:          # a tuned tile, never past the budget
+        tile = min(tile_b, tile)
     tile = min(tile, b)
     padded = round_up(b, tile)
     if padded > b:
@@ -154,12 +149,14 @@ def _lane_tile(r: int, c: int, planes: int = 12,
 
     ``planes`` counts the (tile, c) planes the kernel keeps in VMEM
     (double-buffered blocks plus scratch).  An explicit ``override`` (the
-    autotuner's tile) is snapped down to the nearest such divisor.
+    autotuner's tile) is clamped to that budget and snapped down to the
+    nearest such divisor.
     """
     if r % LANES or r <= LANES:
         return r
-    want = (override if override is not None
-            else BLOCK_BUDGET_BYTES // (planes * c * 4))
+    want = BLOCK_BUDGET_BYTES // (planes * c * 4)
+    if override is not None:
+        want = min(override, want)
     tile = max(want // LANES, 1) * LANES
     while r % tile:
         tile -= LANES
@@ -177,7 +174,6 @@ def _flatten3(x: jax.Array) -> tuple[jax.Array, tuple[int, ...]]:
 
 def fft_kernel_c2c_t(x: jax.Array, *, twiddle=None, inverse: bool = False,
                      interpret: bool | None = None,
-                     radices: tuple[int, ...] | None = None,
                      tile_b: int | None = None) -> jax.Array:
     """Fused C2C FFT + transposed write: (..., R, C) -> (..., C, R).
 
@@ -219,7 +215,6 @@ def fft_kernel_c2c_t(x: jax.Array, *, twiddle=None, inverse: bool = False,
 def fft_kernel_c2c_axis1(x: jax.Array, *, twiddle=None,
                          inverse: bool = False,
                          interpret: bool | None = None,
-                         radices: tuple[int, ...] | None = None,
                          tile_b: int | None = None) -> jax.Array:
     """C2C FFT over axis -2, layout preserved: (..., R, C) -> (..., R, C).
 
@@ -257,7 +252,6 @@ def fft_kernel_c2c_axis1(x: jax.Array, *, twiddle=None,
 
 
 def fft_kernel_r2c_t(x: jax.Array, *, interpret: bool | None = None,
-                     radices: tuple[int, ...] | None = None,
                      tile_b: int | None = None) -> jax.Array:
     """Fused R2C + transposed write: (..., R, C) real -> (..., C/2+1, R)."""
     if interpret is None:
@@ -310,7 +304,6 @@ def transpose_kernel(x: jax.Array, *,
 
 
 def fft_kernel_r2c(x: jax.Array, *, interpret: bool | None = None,
-                   radices: tuple[int, ...] | None = None,
                    tile_b: int | None = None) -> jax.Array:
     """Batched pow2 R2C FFT: (..., N) real -> (..., N/2+1) complex.
 
@@ -340,7 +333,6 @@ def fft_kernel_r2c(x: jax.Array, *, interpret: bool | None = None,
 
 
 def fft_kernel_c2r(x: jax.Array, *, interpret: bool | None = None,
-                   radices: tuple[int, ...] | None = None,
                    tile_b: int | None = None) -> jax.Array:
     """Batched pow2 C2R inverse: (..., N/2+1) half-spectrum -> (..., N) real.
 

@@ -66,9 +66,10 @@ class DispersionPlan:
     """A DM trial grid with its static integer-sample delay table.
 
     Hashable (tuples only), so it is a static jit argument exactly like
-    :class:`~repro.search.templates.TemplateBank` — the kernel unrolls
-    the table at trace time.  Build with :meth:`from_spec` so injection
-    (``data.synthetic``) and dedispersion round the SAME delays.
+    :class:`~repro.search.templates.TemplateBank` — the kernel takes the
+    table as scalar-prefetch data fixed at trace time.  Build with
+    :meth:`from_spec` so injection (``data.synthetic``) and dedispersion
+    round the SAME delays.
     """
 
     dms: tuple[float, ...]                    # trial DMs, pc cm^-3
@@ -152,7 +153,7 @@ def pulsar_search(
     """Search filterbanks (batch, C, N) or (C, N) end to end.
 
     ``plan`` and ``bank`` are static (hashable) so the dedispersion
-    delay table and the template bank unroll at trace time; the whole
+    delay table and the template bank are fixed at trace time; the whole
     graph — dedispersion, R2C, matched filtering, harmonic summing,
     sifting — is one XLA computation.
     """

@@ -27,7 +27,8 @@ from typing import Any
 from repro.tune.config import ConfigKey, KernelConfig
 
 #: Bump when the on-disk schema changes; older files fall back to empty.
-CACHE_VERSION = 1
+#: 2: configs lost the radix-schedule axis (the MXU kernels have none).
+CACHE_VERSION = 2
 
 #: Environment override for the cache file path (tests, CI, containers).
 CACHE_ENV = "REPRO_TUNE_CACHE"

@@ -5,7 +5,6 @@ kernel launches away from the built-in heuristics:
 
   tile_b    batch tile of the 1-D batched kernels (``kernels.fft.ops``
             recomputes ``batch_tile`` when this is None)
-  radices   butterfly schedule of every fused pass (None = DEFAULT_RADICES)
   split     the four-step (n1, n2) factorisation for long transforms
             (None = the balanced ``_four_step_split`` heuristic)
   segment   overlap-save nfft for the convolution engine (0 = the
@@ -33,7 +32,6 @@ class KernelConfig:
     """One point of the kernel-configuration space (None = heuristic)."""
 
     tile_b: int | None = None
-    radices: tuple[int, ...] | None = None
     split: tuple[int, int] | None = None
     segment: int = 0
     source: str = SOURCE_HEURISTIC
@@ -41,13 +39,12 @@ class KernelConfig:
     @property
     def is_heuristic(self) -> bool:
         """True when every axis defers to the built-in heuristics."""
-        return (self.tile_b is None and self.radices is None
-                and self.split is None and self.segment == 0)
+        return (self.tile_b is None and self.split is None
+                and self.segment == 0)
 
     def to_dict(self) -> dict[str, Any]:
         return {
             "tile_b": self.tile_b,
-            "radices": list(self.radices) if self.radices else None,
             "split": list(self.split) if self.split else None,
             "segment": self.segment,
             "source": self.source,
@@ -55,11 +52,9 @@ class KernelConfig:
 
     @classmethod
     def from_dict(cls, d: dict[str, Any]) -> "KernelConfig":
-        radices = d.get("radices")
         split = d.get("split")
         return cls(
             tile_b=d.get("tile_b"),
-            radices=tuple(int(r) for r in radices) if radices else None,
             split=tuple(int(s) for s in split) if split else None,  # type: ignore[arg-type]
             segment=int(d.get("segment") or 0),
             source=str(d.get("source", SOURCE_TUNED)),

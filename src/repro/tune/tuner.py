@@ -3,18 +3,21 @@
 The paper finds each FFT length's best *clock* by measurement (sweep,
 then argmin J/transform under a latency bound); this module applies the
 same discipline to the *software* configuration axes the clock sweep
-holds fixed: batch tile, butterfly radix schedule, the four-step
-``(n1, n2)`` split, and the overlap-save segment length.
+holds fixed: batch tile, the four-step ``(n1, n2)`` split, and the
+overlap-save segment length.  (The MXU kernels have no radix schedule to
+tune: each fused pass is one or two DFT-matrix products.)
 
 The search is staged so measurement stays cheap:
 
   1. **Generate** every candidate :class:`KernelConfig` for the key
-     (schedules x splits/segments x batch tiles).
+     (splits/segments x batch tiles).
   2. **Prune with the cost model** (``core.workloads`` pass/traffic
      accounting + ``core.dvfs.sweep``): candidates are ranked by modelled
      boost-clock time (objective ``"time"``) or modelled J/transform at
      the DVFS-optimal clock (objective ``"energy"``) and only the top
-     few survive — nothing untimed is ever worse than unranked.
+     few survive — nothing untimed is ever worse than unranked.  The
+     model prices neither the tile nor the split, so today every FFT
+     candidate ties and the sort keeps generation order.
   3. **Measure survivors** with the shared warm-up/repeat methodology
      (:func:`repro.tune.timing.time_fn` — identical to the benchmark
      harness), always including the heuristic config.
@@ -42,15 +45,12 @@ from repro.core import dvfs
 from repro.core.hardware import TESLA_V100, DeviceSpec
 from repro.core.workloads import ConvCase, FFTCase, conv_workload, \
     fft_workload
-from repro.fft.radix import DEFAULT_RADICES, is_pow2, next_pow2
+from repro.fft.radix import is_pow2, next_pow2
 from repro.tune.cache import TuneRecord, TuningCache
 from repro.tune.config import (HEURISTIC, SOURCE_COMMON, SOURCE_TUNED,
                                ConfigKey, KernelConfig)
 from repro.tune.context import TuningContext, use_tuning
 from repro.tune.timing import time_fn
-
-#: Butterfly schedules the engine can execute (repro.fft.radix).
-RADIX_CANDIDATES = ((4, 2), (2,), (8, 4, 2))
 
 #: Batch tiles worth trying (f32 sublane is 8 on TPU; heuristic rides too).
 TILE_CANDIDATES = (8, 16, 32, 64)
@@ -119,18 +119,23 @@ def _split_candidates(n: int) -> list[tuple[int, int] | None]:
 
 
 def _tile_candidates(n: int, batch: int) -> list[int | None]:
-    """Batch tiles to try: the heuristic (None) plus explicit lane multiples
-    that fit the measurement batch and a conservative VMEM budget.
+    """Batch tiles to try: the heuristic (None) plus explicit sublane
+    multiples that fit the measurement batch and the kernels' VMEM block
+    budget.
 
-    The tile the heuristic would resolve to is excluded — an explicit copy
-    of it is functionally the heuristic and must never beat it on noise.
+    The budget is the one the kernels size their own tiles by
+    (``kernels.common.batch_tile``: eight pipelined planes in
+    ``BLOCK_BUDGET_BYTES``), and the heuristic tile is the largest it
+    admits, so every candidate is smaller.  The tile the heuristic would
+    resolve to is excluded — an explicit copy of it is functionally the
+    heuristic and must never beat it on noise.
     """
     from repro.kernels.common import batch_tile
-    heuristic_tile = min(batch_tile(n, 4, buffers=8), batch)
+    budget_tile = batch_tile(n, 4, buffers=8)
+    heuristic_tile = min(budget_tile, batch)
     tiles: list[int | None] = [None]
     for t in TILE_CANDIDATES:
-        if (t <= batch and t != heuristic_tile
-                and t * n * 4 * 8 <= 16 * 2**20 and t not in tiles):
+        if t < heuristic_tile and t not in tiles:
             tiles.append(t)
     return tiles
 
@@ -138,18 +143,12 @@ def _tile_candidates(n: int, batch: int) -> list[int | None]:
 def generate_candidates(n: int, kind: str, batch: int) -> list[KernelConfig]:
     """The full config space for one key (heuristic config first)."""
     configs: list[KernelConfig] = [HEURISTIC]
-    for radices in RADIX_CANDIDATES:
-        # The default schedule IS the heuristic radix choice — normalise
-        # it to None so a functionally-identical config can never "beat"
-        # the heuristic on timing noise.
-        rad = None if radices == DEFAULT_RADICES else radices
-        for split in _split_candidates(n):
-            for tile in _tile_candidates(n, batch):
-                cfg = KernelConfig(tile_b=tile, radices=rad, split=split,
-                                   source=SOURCE_TUNED)
-                if cfg.is_heuristic or cfg in configs:
-                    continue
-                configs.append(cfg)
+    for split in _split_candidates(n):
+        for tile in _tile_candidates(n, batch):
+            cfg = KernelConfig(tile_b=tile, split=split, source=SOURCE_TUNED)
+            if cfg.is_heuristic or cfg in configs:
+                continue
+            configs.append(cfg)
     return configs
 
 
@@ -181,8 +180,7 @@ def _segment_candidates(n: int, taps: int) -> list[int]:
 def _model_candidate(cfg: KernelConfig, n: int, kind: str,
                      model_device: DeviceSpec) -> Candidate:
     """Rank one config with the analytic pass/traffic model + DVFS sweep."""
-    case = FFTCase(n=n, transform=kind if kind in FFT_KINDS else "c2c",
-                   radices=cfg.radices or DEFAULT_RADICES)
+    case = FFTCase(n=n, transform=kind if kind in FFT_KINDS else "c2c")
     res = dvfs.sweep(fft_workload(case, model_device), model_device)
     per = dvfs.energy_per_transform(res, case.n_fft)
     return Candidate(config=cfg, model_time=res.boost.time,
@@ -365,8 +363,10 @@ def common_config(
     tuned FFT length — the software mirror of the paper's one-common-clock
     result (Sec. 4: one well-chosen setting recovers ~50% of the savings).
 
-    Only the length-portable axes (``tile_b``, ``radices``) generalise;
-    splits and segments stay per-length.  Returns ``(config, regret)``
+    Only the length-portable axis (``tile_b``) generalises; splits and
+    segments stay per-length.  The cost model does not price the tile, so
+    every portable config models alike and the heuristic (first in the
+    pool) wins with zero regret until the model learns tiles.  Returns ``(config, regret)``
     where ``regret`` is the mean relative J/transform excess over each
     length's own tuned optimum (0.0 = no loss anywhere).
     """
@@ -378,14 +378,12 @@ def common_config(
     for k in keys:
         rec = cache.get(k)
         portable = KernelConfig(tile_b=rec.config.tile_b,
-                                radices=rec.config.radices,
                                 source=SOURCE_COMMON)
         if portable not in pool:
             pool.append(portable)
 
     def model_j(cfg: KernelConfig, key: ConfigKey) -> float:
-        case = FFTCase(n=key.shape[0], transform=key.kind,
-                       radices=cfg.radices or DEFAULT_RADICES)
+        case = FFTCase(n=key.shape[0], transform=key.kind)
         res = dvfs.sweep(fft_workload(case, model_device), model_device)
         return dvfs.energy_per_transform(res, case.n_fft)["optimal_j"]
 

@@ -1,6 +1,6 @@
 """Dedispersion kernel tests: Pallas vs oracle parity, guards, properties.
 
-The kernel unrolls a static (DM, channel) delay table at trace time
+The kernel reads a static (DM, channel) delay table from SMEM
 (gather-free shift-and-sum, repro.kernels.dedisp); the oracle gathers
 with ``take_along_axis``.  Property tests draw random DM tables and
 non-divisible batch tiles; they skip cleanly when ``hypothesis`` is not
@@ -45,6 +45,23 @@ class TestDedisperseParity:
         want = dedisperse_ref(fb, delays)
         assert got.shape == (batch, ndm, n)
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("tile_c", [8, 16])
+    def test_channel_tiles_sum_like_one_block(self, tile_c):
+        """Channel slabs accumulate into the resident output block: the
+        result equals the untiled kernel bit for bit (same channel order)
+        and the oracle within float32 rounding."""
+        rng = np.random.default_rng(4)
+        fb = _rand_fb((2, 32, 256))
+        delays = tuple(tuple(int(d) for d in row)
+                       for row in _rand_delays(rng, 3, 32, 256))
+        got = dedisperse_pallas(fb, delays, tile_c=tile_c, interpret=True)
+        whole = dedisperse_pallas(fb, delays, interpret=True)
+        np.testing.assert_array_equal(got, whole)
+        np.testing.assert_allclose(got, dedisperse_ref(fb, delays),
+                                   rtol=1e-5, atol=1e-5)
+        with pytest.raises(ValueError, match="channel tile"):
+            dedisperse_pallas(fb, delays, tile_c=12, interpret=True)
 
     def test_multidim_lead_axes(self):
         rng = np.random.default_rng(1)

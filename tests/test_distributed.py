@@ -53,22 +53,29 @@ def test_pencil_fft_matches_reference():
 
 
 @pytest.mark.slow
-def test_batch_parallel_fft():
-    run_with_devices("""
+@pytest.mark.parametrize("axis_type", ["Auto", "Explicit"])
+def test_batch_parallel_fft(axis_type):
+    """A divided batch stays sharded; a padded one (13 rows on 8 devices)
+    is un-padded correctly on both kinds of mesh axis."""
+    run_with_devices(f"""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         from repro.fft.distributed import batch_parallel_fft
 
         mesh = jax.make_mesh((8,), ("data",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-        x = (jax.random.normal(jax.random.PRNGKey(0), (16, 512)) +
-             1j * jax.random.normal(jax.random.PRNGKey(1), (16, 512))
-             ).astype(jnp.complex64)
-        xs = jax.device_put(x, NamedSharding(mesh, P("data", None)))
-        y = batch_parallel_fft(xs, mesh)
-        np.testing.assert_allclose(jax.device_get(y),
-                                   np.fft.fft(np.asarray(x), axis=-1),
-                                   rtol=2e-3, atol=2e-3)
+                             axis_types=(AxisType.{axis_type},))
+        for rows in (16, 13):
+            x = (jax.random.normal(jax.random.PRNGKey(0), (rows, 512)) +
+                 1j * jax.random.normal(jax.random.PRNGKey(1), (rows, 512))
+                 ).astype(jnp.complex64)
+            xs = jax.device_put(x, NamedSharding(mesh, P()))
+            y = batch_parallel_fft(xs, mesh)
+            assert y.shape == (rows, 512), y.shape
+            if rows == 16:
+                assert y.sharding.spec[0] == "data", y.sharding
+            np.testing.assert_allclose(jax.device_get(y),
+                                       np.fft.fft(np.asarray(x), axis=-1),
+                                       rtol=2e-3, atol=2e-3)
         print("batch ok")
     """)
 
@@ -96,23 +103,26 @@ def test_pencil_rfft_matches_reference():
 
 
 @pytest.mark.slow
-def test_batch_parallel_fft_r2c_kind():
+@pytest.mark.parametrize("axis_type", ["Auto", "Explicit"])
+def test_batch_parallel_fft_r2c_kind(axis_type):
     """kind="r2c" shards real batches through the R2C plan (no complex
-    cast) and matches jnp.fft.rfft."""
-    run_with_devices("""
+    cast) and matches jnp.fft.rfft, divided or padded (13 rows)."""
+    run_with_devices(f"""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         from repro.fft.distributed import batch_parallel_fft
 
         mesh = jax.make_mesh((8,), ("data",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-        x = jax.random.normal(jax.random.PRNGKey(0), (16, 512), jnp.float32)
-        xs = jax.device_put(x, NamedSharding(mesh, P("data", None)))
-        y = batch_parallel_fft(xs, mesh, kind="r2c")
-        assert y.shape == (16, 257), y.shape
-        np.testing.assert_allclose(jax.device_get(y),
-                                   np.fft.rfft(np.asarray(x), axis=-1),
-                                   rtol=2e-3, atol=2e-3)
+                             axis_types=(AxisType.{axis_type},))
+        for rows in (16, 13):
+            x = jax.random.normal(jax.random.PRNGKey(0), (rows, 512),
+                                  jnp.float32)
+            xs = jax.device_put(x, NamedSharding(mesh, P()))
+            y = batch_parallel_fft(xs, mesh, kind="r2c")
+            assert y.shape == (rows, 257), y.shape
+            np.testing.assert_allclose(jax.device_get(y),
+                                       np.fft.rfft(np.asarray(x), axis=-1),
+                                       rtol=2e-3, atol=2e-3)
         print("batch r2c ok")
     """)
 

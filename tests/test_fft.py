@@ -58,7 +58,7 @@ def test_four_step_matches_reference(n1, n2):
 @pytest.mark.parametrize("n", [64, 8192, 2**15, 139, 100])
 def test_planner_dispatch_and_correctness(n):
     plan = plan_for_length(n)
-    expected = {True: "stockham" if n <= 2**13 else "four-step",
+    expected = {True: "single-pass" if n <= 2**13 else "four-step",
                 False: "bluestein"}[(n & (n - 1)) == 0]
     assert plan.algorithm == expected
     assert plan.passes >= 1
@@ -194,7 +194,7 @@ class _CountingKernel:
 
 
 @pytest.mark.parametrize("n,algorithm", [
-    (2**9, "stockham"),       # single fused pass
+    (2**9, "single-pass"),    # single fused pass
     (45, "bluestein"),        # two kernel passes at m=128
 ])
 def test_plans_route_through_pallas_kernel(monkeypatch, n, algorithm):
@@ -209,7 +209,7 @@ def test_plans_route_through_pallas_kernel(monkeypatch, n, algorithm):
     assert plan.algorithm == algorithm
     x = rand_complex((7, n))
     np.testing.assert_allclose(plan(x), jnp.fft.fft(x), rtol=3e-3, atol=3e-3)
-    assert counter.calls >= (2 if algorithm != "stockham" else 1)
+    assert counter.calls >= (2 if algorithm != "single-pass" else 1)
 
 
 def test_four_step_plan_runs_two_fused_kernel_passes(monkeypatch):

@@ -54,11 +54,12 @@ class TestFFTKernel:
         np.testing.assert_allclose(got, jnp.fft.fft(x.astype(jnp.complex64)),
                                    rtol=3e-4, atol=3e-4)
 
-    @pytest.mark.parametrize("radices", [(2,), (4, 2), (8, 4, 2)])
-    def test_radix_schedules_match_oracle(self, radices):
-        """The kernel's specialised r=2/r=4 and generic r=8 butterflies."""
-        x = rand_c((4, 1024))
-        got = fft_kernel_c2c(x, interpret=True, radices=radices)
+    @pytest.mark.parametrize("n", [128, 1024, MAX_KERNEL_N])
+    def test_radix_schedules_match_oracle(self, n):
+        """Each factorisation the kernel runs: one (n, n) DFT product at
+        n <= 128, else the in-VMEM N1 x 128 four-step (N1 = 8 and 64)."""
+        x = rand_c((4, n))
+        got = fft_kernel_c2c(x, interpret=True)
         np.testing.assert_allclose(got, jnp.fft.fft(x), rtol=3e-4, atol=3e-4)
 
     def test_too_long_raises_with_plan_pointer(self):
@@ -84,6 +85,15 @@ class TestFFTKernel:
         x = rand_c((12, 256))
         got = fft_kernel_c2c(x, interpret=True, tile_b=4)
         np.testing.assert_allclose(got, jnp.fft.fft(x), rtol=3e-4, atol=3e-4)
+
+    def test_tile_override_clamped_to_vmem_budget(self):
+        """A tuned tile past the VMEM block budget (a stale cache entry)
+        is cut to the budget tile instead of reaching the compiler."""
+        from repro.kernels.common import BLOCK_BUDGET_BYTES, batch_tile
+        budget = batch_tile(MAX_KERNEL_N, 4, buffers=8)
+        assert budget * MAX_KERNEL_N * 4 * 8 <= BLOCK_BUDGET_BYTES
+        assert batch_tile(MAX_KERNEL_N, 4, buffers=8, override=64) == budget
+        assert batch_tile(MAX_KERNEL_N, 4, buffers=8, override=3) == 8
 
     def test_tile_multiple_batch_skips_padding(self, monkeypatch):
         """A tile-multiple batch must not pay the pad-then-slice trip."""

@@ -66,6 +66,19 @@ def test_c2c(one_chip, n, rows):
              ((rows, n), C64))
 
 
+@pytest.mark.parametrize("which", ["largest_candidate", "stale_cache_tile"])
+def test_c2c_at_tuned_tile(one_chip, which):
+    """The longest fused pass at the largest tile the autotuner can
+    propose, and at a cached tile far past the VMEM budget (the ops layer
+    clamps it)."""
+    from repro.kernels.fft.ops import MAX_KERNEL_N, fft_kernel_c2c
+    from repro.tune.tuner import TILE_CANDIDATES, _tile_candidates
+    tiles = [t for t in _tile_candidates(MAX_KERNEL_N, batch=1024) if t]
+    tile = max(tiles) if which == "largest_candidate" else TILE_CANDIDATES[-1]
+    _compile(lambda x: fft_kernel_c2c(x, interpret=False, tile_b=tile),
+             one_chip, ((1024, MAX_KERNEL_N), C64))
+
+
 def test_c2c_with_bank(one_chip):
     """The FDAS forward pass with its (T, N) bank epilogue."""
     from repro.kernels.fft.ops import fft_kernel_c2c_mul
@@ -118,18 +131,29 @@ def test_transpose(one_chip):
 
 
 def test_dedisperse(one_chip):
-    """The pulsar phase's filterbanks: 64 channels x 2^14 samples, 16 DMs."""
+    """The pulsar phase's filterbanks: 1024 channels x 2^17 samples, 16
+    DMs (channel-tiled; the scoped-VMEM limit raised for the resident
+    (16, 2^17) output block)."""
     from repro.kernels.dedisp.ops import dedisperse_kernel
-    delays = np.arange(16)[:, None] * np.arange(64)[None, :] // 4
+    delays = np.arange(16)[:, None] * np.arange(1024)[None, :] // 64
     _compile(lambda fb: dedisperse_kernel(fb, delays, interpret=False),
-             one_chip, ((2, 64, 2**14), F32))
+             one_chip, ((2, 1024, 2**17), F32))
 
 
-def test_harmonic_sum_plane(one_chip):
-    """The pulsar phase's power plane: 2 x 16 DMs x 8 templates x 8193."""
+@pytest.mark.parametrize("ntime,fits", [(2**17, True), (2**18, False)])
+def test_harmonic_sum_plane(one_chip, ntime, fits):
+    """The pulsar phase's power plane (2 x 16 DMs x 8 templates x
+    ntime/2 + 1 bins) at its size, and the refusal one size up that caps
+    the phase: the kernel keeps whole padded spectrum rows in VMEM."""
     from repro.kernels.harmonic_sum.ops import harmonic_sum_plane
-    _compile(lambda p: harmonic_sum_plane(p, 8, interpret=False)[0],
-             one_chip, ((2, 16, 8, 8193), F32))
+    shape = ((2, 16, 8, ntime // 2 + 1), F32)
+    fn = lambda p: harmonic_sum_plane(p, 8, interpret=False)[0]  # noqa: E731
+    if fits:
+        _compile(fn, one_chip, shape)
+    else:
+        with pytest.raises(jax.errors.JaxRuntimeError,
+                           match="Ran out of memory in memory space vmem"):
+            _compile(fn, one_chip, shape)
 
 
 def test_power_spectrum_stats(one_chip):

@@ -40,6 +40,21 @@ def _tuned_cache(device="testdev", entries=()):
     return cache
 
 
+def _spy(monkeypatch, hook: str) -> list:
+    """Record (args, kwargs) of every call through a ``repro.fft.plan``
+    kernel hook."""
+    import repro.fft.plan as plan_mod
+    calls = []
+    orig = getattr(plan_mod, hook)
+
+    def spy(*args, **kw):
+        calls.append((args, kw))
+        return orig(*args, **kw)
+
+    monkeypatch.setattr(plan_mod, hook, spy)
+    return calls
+
+
 def rand_c(shape):
     kr, ki = jax.random.split(KEY)
     return (jax.random.normal(kr, shape) +
@@ -52,8 +67,8 @@ def rand_c(shape):
 
 class TestConfig:
     def test_json_round_trip(self):
-        cfg = KernelConfig(tile_b=16, radices=(8, 4, 2), split=(64, 128),
-                           segment=1024, source="tuned")
+        cfg = KernelConfig(tile_b=16, split=(64, 128), segment=1024,
+                           source="tuned")
         assert KernelConfig.from_dict(cfg.to_dict()) == cfg
         assert KernelConfig.from_dict(HEURISTIC.to_dict()) == HEURISTIC
 
@@ -76,7 +91,7 @@ class TestCachePersistence:
         path = str(tmp_path / "dev.json")
         cache = _tuned_cache(entries=[
             ((256,), "c2c", KernelConfig(tile_b=16, source="tuned")),
-            ((512,), "r2c", KernelConfig(radices=(2,), source="tuned")),
+            ((512,), "r2c", KernelConfig(tile_b=8, source="tuned")),
         ])
         rec = TuneRecord(config=KernelConfig(tile_b=16, source="tuned"),
                          objective="energy", score=1.5, heuristic_score=2.0,
@@ -155,9 +170,17 @@ class TestTuner:
         cands = generate_candidates(256, "c2c", batch=64)
         assert cands[0] is HEURISTIC
         assert len(cands) == len(set(cands))        # no duplicates
-        # the default radix schedule is normalised to None, so no candidate
-        # is a functional clone of the heuristic
+        # no candidate is a functional clone of the heuristic
         assert not any(c.is_heuristic for c in cands[1:])
+
+    @pytest.mark.parametrize("n", [256, 2048, 2**13])
+    def test_tile_candidates_fit_vmem_budget(self, n):
+        """Every proposed tile keeps the kernel's eight pipelined planes
+        inside the block budget the kernels size their own tiles by."""
+        from repro.kernels.common import BLOCK_BUDGET_BYTES
+        from repro.tune.tuner import _tile_candidates
+        tiles = [t for t in _tile_candidates(n, batch=1024) if t]
+        assert all(t * n * 4 * 8 <= BLOCK_BUDGET_BYTES for t in tiles)
 
     def test_prune_keeps_heuristic_and_respects_budget(self):
         cands = generate_candidates(256, "c2c", batch=64)
@@ -256,50 +279,44 @@ class TestPlanRouting:
                 plan_nd((64, 64))                  # N-D key, same context
             assert ctx.consults == 4
 
-    def test_tuned_plan_applies_config(self):
-        cfg = KernelConfig(radices=(2,), source="tuned")
+    def test_tuned_plan_applies_config(self, monkeypatch):
+        calls = _spy(monkeypatch, "_kernel_fft")
+        cfg = KernelConfig(tile_b=4, source="tuned")
         cache = _tuned_cache(entries=[((256,), "c2c", cfg)])
         with use_tuning(TuningContext(cache)):
             plan = plan_for_length(256)
-        assert plan.radices == (2,) * 8            # radix-2 schedule applied
         x = rand_c((5, 256))
         np.testing.assert_allclose(plan(x), jnp.fft.fft(x),
                                    rtol=3e-3, atol=3e-3)
+        assert [kw["tile_b"] for _, kw in calls] == [4]   # tuned tile used
 
-    def test_tuned_four_step_split_applies(self):
+    def test_tuned_four_step_split_applies(self, monkeypatch):
+        calls = _spy(monkeypatch, "_kernel_fft_axis1")
         n = 2**14
         cfg = KernelConfig(split=(2**5, 2**9), source="tuned")
         cache = _tuned_cache(entries=[((n,), "c2c", cfg)])
         with use_tuning(TuningContext(cache)):
             plan = plan_for_length(n)
         assert plan.algorithm == "four-step"
-        # the tuned (32, 512) cut, not the balanced (128, 128): the plan's
-        # recorded first-pass schedule covers n1 = 32 -> (4, 4, 2)
-        assert plan.radices == (2, 4, 4)
         x = rand_c((2, n))
+        plan(x)
+        # the tuned (32, 512) cut, not the balanced (128, 128): the column
+        # pass transforms n1 = 32 rows of n2 = 512 columns
+        assert [a[0].shape[-2:] for a, _ in calls] == [(32, 512)]
         np.testing.assert_allclose(plan(x), jnp.fft.fft(x),
                                    rtol=3e-3, atol=3e-3)
 
     def test_bluestein_plan_threads_config_into_inner_ffts(self, monkeypatch):
         """Non-pow2 (Bluestein) plans must actually execute their tuned
         config — otherwise the tuner times byte-identical executables."""
-        import repro.fft.plan as plan_mod
-        calls = []
-        orig = plan_mod.fft_kernel_c2c
-
-        def spy(x, **kw):
-            calls.append(kw)
-            return orig(x, **kw)
-
-        monkeypatch.setattr(plan_mod, "_kernel_fft", spy)
-        cfg = KernelConfig(radices=(2,), tile_b=4, source="tuned")
+        calls = _spy(monkeypatch, "_kernel_fft")
+        cfg = KernelConfig(tile_b=4, source="tuned")
         plan = plan_with_config(45, "c2c", cfg)
         assert plan.algorithm == "bluestein"
         x = rand_c((3, 45))
         np.testing.assert_allclose(plan(x), jnp.fft.fft(x),
                                    rtol=3e-3, atol=3e-3)
-        assert any(kw.get("radices") == (2,) and kw.get("tile_b") == 4
-                   for kw in calls)
+        assert any(kw.get("tile_b") == 4 for _, kw in calls)
 
     def test_no_heuristic_clone_candidates(self):
         """Explicit copies of the heuristic's resolved tile / balanced
@@ -326,8 +343,7 @@ class TestPlanRouting:
         the pre-tuner path built — not an equivalent copy."""
         heuristic = plan_with_config(256)
         cache = _tuned_cache(entries=[
-            ((256,), "c2c", KernelConfig(tile_b=4, radices=(2,),
-                                         source="tuned"))])
+            ((256,), "c2c", KernelConfig(tile_b=4, source="tuned"))])
         ctx = TuningContext(cache)
         with use_tuning(ctx):
             tuned = plan_for_length(256)
@@ -361,17 +377,18 @@ class TestPlanRouting:
         with use_tuning(TuningContext(cache)):
             assert conv_plan(n, taps, t).nfft == select_nfft(taps, n, t)
 
-    def test_common_default_serves_untuned_keys(self):
+    def test_common_default_serves_untuned_keys(self, monkeypatch):
+        calls = _spy(monkeypatch, "_kernel_fft")
         cache = _tuned_cache(entries=[
-            ((256,), "c2c", KernelConfig(radices=(8, 4, 2),
-                                         source="tuned"))])
+            ((256,), "c2c", KernelConfig(tile_b=16, source="tuned"))])
         ctx = TuningContext(cache)
-        ctx.common = KernelConfig(radices=(8, 4, 2), source="common")
+        ctx.common = KernelConfig(tile_b=8, source="common")
         with use_tuning(ctx):
             tuned = plan_for_length(256)           # its own entry
             untuned = plan_for_length(1024)        # falls back to common
-        assert tuned.radices == (4, 8, 8)          # residual radix first
-        assert untuned.radices == (2, 8, 8, 8)     # common schedule applied
+        tuned(rand_c((2, 256)))
+        untuned(rand_c((2, 1024)))
+        assert [kw["tile_b"] for _, kw in calls] == [16, 8]
 
 
 # ---------------------------------------------------------------------------
@@ -392,8 +409,7 @@ class TestCommonConfig:
 
     def test_portable_axes_only(self):
         cache = _tuned_cache(entries=[
-            ((2**14,), "c2c", KernelConfig(tile_b=16, radices=(8, 4, 2),
-                                           split=(32, 512),
+            ((2**14,), "c2c", KernelConfig(tile_b=16, split=(32, 512),
                                            source="tuned"))])
         cfg, regret = common_config(cache)
         assert cfg.split is None and cfg.segment == 0
@@ -419,12 +435,12 @@ class TestServingIntegration:
         key = self._key()
         e1 = cache.entry(key)
         assert cache.entry(key) is e1              # heuristic entry cached
-        tcache = _tuned_cache(entries=[
-            ((256,), "c2c", KernelConfig(radices=(2,), source="tuned"))])
+        cfg = KernelConfig(tile_b=4, source="tuned")
+        tcache = _tuned_cache(entries=[((256,), "c2c", cfg)])
         with use_tuning(TuningContext(tcache)):
             e2 = cache.entry(key)                  # tuned entry, new build
             assert e2 is not e1
-            assert e2.plan.radices == (2,) * 8
+            assert e2.plan is plan_with_config(256, "c2c", cfg)
             assert cache.entry(key) is e2          # ... and then cached
         assert cache.entry(key) is e1              # context gone -> heuristic
 
