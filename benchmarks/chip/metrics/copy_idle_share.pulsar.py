@@ -1,0 +1,6 @@
+"""``copy_idle_share.batch``'s reading, in the pulsar cell."""
+import runpy
+from pathlib import Path
+
+read = runpy.run_path(
+    str(Path(__file__).with_name("copy_idle_share.batch.py")))["read"]

@@ -1723,6 +1723,7 @@ def obs():
     model and stays silent under the calibrated one.
     """
     import dataclasses as _dc
+    import itertools
 
     from repro.core.hardware import TPU_V5E
     from repro.core.power_model import PowerModel
@@ -1732,6 +1733,7 @@ def obs():
     from repro.obs import trace as trace_mod
     from repro.power.telemetry import FleetTelemetry
     from repro.serving import FFTService
+    from repro.serving import request as request_mod
 
     class _FakeTimer:
         """Deterministic clock: advances dt per call."""
@@ -1813,6 +1815,9 @@ def obs():
 
     # --- 3/4. reproducible traces + drift detection -------------------
     def traced_run(power_model=None):
+        # Each run stands for a fresh process, in which request ids (a
+        # submit span's request_id) restart from zero.
+        request_mod._REQUEST_IDS = itertools.count()
         timer = _FakeTimer()
         tracer = Tracer(timer=timer)
         svc = FFTService(

@@ -111,6 +111,7 @@ def dedisperse_pallas(fb: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, ndm, n), jnp.float32),
         compiler_params=vmem_compiler_params(
             2 * (tile_c + ndm) * tile_b * n * 4),
+        name="dedisperse_pallas",
         interpret=interpret,
     )
     return fn(jnp.asarray(np.asarray(delays, np.int32).reshape(-1)), fb)

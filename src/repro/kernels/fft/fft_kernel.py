@@ -433,6 +433,7 @@ def fft_pallas(re: jax.Array, im: jax.Array, *, tile_b: int = 8,
         in_specs=[spec, spec] + tab_specs,
         out_specs=[spec, spec],
         out_shape=[jax.ShapeDtypeStruct((b, n), jnp.float32)] * 2,
+        name="fft_pallas",
         interpret=interpret,
     )
     return tuple(fn(re, im, *tabs))
@@ -467,6 +468,7 @@ def fft_mul_pallas(re: jax.Array, im: jax.Array, fbr: jax.Array,
         in_specs=[in_spec, in_spec] + tab_specs + [fb_spec, fb_spec],
         out_specs=[out_spec, out_spec],
         out_shape=[jax.ShapeDtypeStruct((b, t, n), jnp.float32)] * 2,
+        name="fft_mul_pallas",
         interpret=interpret,
     )
     return tuple(fn(re.reshape(b, 1, n), im.reshape(b, 1, n), *tabs,
@@ -489,6 +491,7 @@ def rfft_pallas(x: jax.Array, *, tile_b: int = 8, interpret: bool = False):
         in_specs=[pl.BlockSpec((tile_b, n), lambda i: (i, 0))] + tab_specs,
         out_specs=[pl.BlockSpec((tile_b, m + 1), lambda i: (i, 0))] * 2,
         out_shape=[jax.ShapeDtypeStruct((b, m + 1), jnp.float32)] * 2,
+        name="rfft_pallas",
         interpret=interpret,
     )
     return tuple(fn(x, *tabs))
@@ -512,13 +515,14 @@ def irfft_pallas(re: jax.Array, im: jax.Array, *, tile_b: int = 8,
         in_specs=[in_spec, in_spec] + tab_specs,
         out_specs=pl.BlockSpec((tile_b, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, n), jnp.float32),
+        name="irfft_pallas",
         interpret=interpret,
     )
     return fn(re, im, *tabs)
 
 
 def _fft_t_call(re, im, ftw, *, tile_r: int, inverse: bool,
-                interpret: bool):
+                interpret: bool, name: str):
     b, r, c = re.shape
     _require_pow2(c, "row length C")
     _require_tiled(r, tile_r, "rows R")
@@ -542,6 +546,7 @@ def _fft_t_call(re, im, ftw, *, tile_r: int, inverse: bool,
         scratch_shapes=[pltpu.VMEM((tile_r, c), jnp.float32)] * 2,
         compiler_params=vmem_compiler_params(
             (2 * planes + 4) * tile_r * c * 4),
+        name=name,
         interpret=interpret,
     )
     return tuple(fn(re, im, *tabs, *extra))
@@ -558,7 +563,7 @@ def fft_t_pallas(re: jax.Array, im: jax.Array, *, tile_r: int = 8,
     2-D / four-step transform costs zero extra HBM passes.
     """
     return _fft_t_call(re, im, None, tile_r=tile_r, inverse=inverse,
-                       interpret=interpret)
+                       interpret=interpret, name="fft_t_pallas")
 
 
 @functools.partial(jax.jit,
@@ -576,11 +581,12 @@ def fft_t_twiddle_pallas(re: jax.Array, im: jax.Array, ftwr: jax.Array,
         raise ValueError(
             f"twiddle planes must be ({r}, {c}), got {ftwr.shape}")
     return _fft_t_call(re, im, (ftwr, ftwi), tile_r=tile_r,
-                       inverse=inverse, interpret=interpret)
+                       inverse=inverse, interpret=interpret,
+                       name="fft_t_twiddle_pallas")
 
 
 def _fft_axis1_call(re, im, ftw, *, tile_c: int, inverse: bool,
-                    interpret: bool):
+                    interpret: bool, name: str):
     b, r, c = re.shape
     _require_pow2(r, "column length R")
     _require_tiled(c, tile_c, "columns C")
@@ -603,6 +609,7 @@ def _fft_axis1_call(re, im, ftw, *, tile_c: int, inverse: bool,
         scratch_shapes=[pltpu.VMEM((tile_c, r), jnp.float32)] * 2,
         compiler_params=vmem_compiler_params(
             (2 * planes + 4) * tile_c * r * 4),
+        name=name,
         interpret=interpret,
     )
     return tuple(fn(re, im, *tabs, *extra))
@@ -619,7 +626,7 @@ def fft_axis1_pallas(re: jax.Array, im: jax.Array, *, tile_c: int = 8,
     of a 2-D / four-step transform in one HBM round trip.
     """
     return _fft_axis1_call(re, im, None, tile_c=tile_c, inverse=inverse,
-                           interpret=interpret)
+                           interpret=interpret, name="fft_axis1_pallas")
 
 
 @functools.partial(jax.jit,
@@ -634,7 +641,8 @@ def fft_axis1_twiddle_pallas(re: jax.Array, im: jax.Array, ftwr: jax.Array,
         raise ValueError(
             f"twiddle planes must be ({c}, {r}), got {ftwr.shape}")
     return _fft_axis1_call(re, im, (ftwr, ftwi), tile_c=tile_c,
-                           inverse=inverse, interpret=interpret)
+                           inverse=inverse, interpret=interpret,
+                           name="fft_axis1_twiddle_pallas")
 
 
 @functools.partial(jax.jit,
@@ -657,6 +665,7 @@ def rfft_t_pallas(x: jax.Array, *, tile_r: int = 8, interpret: bool = False):
         out_shape=[jax.ShapeDtypeStruct((b, m + 1, r), jnp.float32)] * 2,
         scratch_shapes=[pltpu.VMEM((tile_r, m), jnp.float32)] * 2,
         compiler_params=vmem_compiler_params(8 * tile_r * c * 4),
+        name="rfft_t_pallas",
         interpret=interpret,
     )
     return tuple(fn(x, *tabs))
@@ -688,6 +697,7 @@ def transpose_pallas(*planes: jax.Array, tile_r: int = 128,
         out_shape=out_shape,
         compiler_params=vmem_compiler_params(
             4 * len(planes) * tile_r * tile_c * planes[0].dtype.itemsize),
+        name="transpose_pallas",
         interpret=interpret,
     )
     return tuple(fn(*planes))

@@ -136,6 +136,7 @@ def harmonic_sum_plane_pallas(power: jax.Array, n_harmonics: int, *,
         out_specs=[out_spec, out_spec],
         out_shape=[jax.ShapeDtypeStruct((b, n_out), jnp.float32),
                    jax.ShapeDtypeStruct((b, n_out), jnp.int32)],
+        name="harmonic_sum_plane_pallas",
         interpret=interpret,
     )
     return tuple(fn(power))
@@ -155,6 +156,7 @@ def harmonic_sum_pallas(power: jax.Array, n_harmonics: int, *,
         in_specs=[pl.BlockSpec((tile_b, width), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((tile_b, levels, n_out), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, levels, n_out), power.dtype),
+        name="harmonic_sum_pallas",
         interpret=interpret,
     )
     return fn(power)

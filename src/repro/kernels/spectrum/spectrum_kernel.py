@@ -54,6 +54,7 @@ def power_spectrum_stats_pallas(re: jax.Array, im: jax.Array, *,
             jax.ShapeDtypeStruct((b, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, 1), jnp.float32),
         ],
+        name="power_spectrum_stats_pallas",
         interpret=interpret,
     )
     return fn(re, im)

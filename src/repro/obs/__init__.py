@@ -4,8 +4,8 @@ Four cooperating pieces (docs/observability.md walks through the loop):
 
 * :mod:`repro.obs.trace` — deterministic nested-span tracing on an
   injectable clock, with a per-device flight recorder snapshotted on
-  every ``runtime.faults`` error, and Chrome-trace / JSONL / blake2b
-  exporters.
+  every ``runtime.faults`` error, per-span program-build counts,
+  optional profiler annotations, and JSONL / blake2b exporters.
 * :mod:`repro.obs.metrics` — a counters/gauges/fixed-bucket-histogram
   registry with one Prometheus-style text rendering, plus the shared
   guarded percentile helper.
@@ -24,8 +24,7 @@ from repro.obs.metrics import (DEFAULT_LATENCY_BUCKETS, Counter, Gauge,
                                Histogram, LatencySummary, MetricsRegistry,
                                latency_summary)
 from repro.obs.trace import (FlightRecorder, FlightSnapshot, Span, Tracer,
-                             digest, notify_fault, to_chrome_trace,
-                             to_jsonl)
+                             digest, notify_fault, to_jsonl)
 
 __all__ = [
     "DriftDetector", "DriftState",
@@ -34,5 +33,5 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "LatencySummary", "latency_summary", "DEFAULT_LATENCY_BUCKETS",
     "FlightRecorder", "FlightSnapshot", "Span", "Tracer",
-    "digest", "notify_fault", "to_chrome_trace", "to_jsonl",
+    "digest", "notify_fault", "to_jsonl",
 ]

@@ -14,8 +14,15 @@ feed a bounded per-device :class:`FlightRecorder` ring; when any
 its rings (plus the spans still open at the moment of failure) for
 postmortems — the crash-dump analogue of an aircraft flight recorder.
 
-Exporters: :func:`to_chrome_trace` (load the JSON in ``about:tracing``
-/ Perfetto), :func:`to_jsonl` (one span per line, canonical key order)
+The first :class:`Tracer` built registers one ``jax.monitoring``
+listener for XLA's backend-compile event; each event adds one to the
+``compiles`` and its seconds to the ``compile_s`` of the innermost span
+open in every live tracer, so a trace shows which step built a program.
+``Tracer(annotate=prefix)`` also opens a
+``jax.profiler.TraceAnnotation(prefix + name)`` around every span, which
+puts the spans in a profile on the device trace's clock.
+
+Exporters: :func:`to_jsonl` (one span per line, canonical key order)
 and :func:`digest` (blake2b of the JSONL).
 """
 from __future__ import annotations
@@ -30,7 +37,12 @@ import weakref
 from typing import Any
 
 __all__ = ["Span", "FlightSnapshot", "FlightRecorder", "Tracer",
-           "notify_fault", "to_chrome_trace", "to_jsonl", "digest"]
+           "notify_fault", "to_jsonl", "digest"]
+
+#: The ``jax.monitoring`` duration event of one XLA program build.  It
+#: also wraps a read from the persistent compilation cache, so a cache
+#: hit counts as a build.
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 @dataclasses.dataclass
@@ -43,6 +55,12 @@ class Span:
     depth: int = 0                      # nesting depth at open time
     parent: str | None = None           # enclosing span's name
     attrs: dict = dataclasses.field(default_factory=dict)
+    # Programs built while this was the innermost open span, and their
+    # seconds on the process clock.  Kept out of attrs (children do not
+    # inherit them) and out of to_dict (a fake-clock digest stays
+    # reproducible whether or not jit's caches were warm).
+    compiles: int = 0
+    compile_s: float = 0.0
 
     def to_dict(self) -> dict:
         return {"name": self.name, "t_start": self.t_start,
@@ -98,6 +116,29 @@ class FlightRecorder:
 _TRACERS: "weakref.WeakSet[Tracer]" = weakref.WeakSet()
 
 
+_compile_listener_registered = False
+
+
+def _on_duration(event: str, duration: float, **kwargs) -> None:
+    if event != COMPILE_EVENT:
+        return
+    for tracer in list(_TRACERS):
+        if tracer._stack:
+            span = tracer._stack[-1]
+            span.compiles += 1
+            span.compile_s += duration
+
+
+def _register_compile_listener() -> None:
+    """Listen for program builds, once per process."""
+    global _compile_listener_registered
+    if _compile_listener_registered:
+        return
+    import jax
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+    _compile_listener_registered = True
+
+
 def notify_fault(error: BaseException) -> None:
     """Snapshot every live tracer's flight recorder for ``error``.
 
@@ -109,33 +150,46 @@ def notify_fault(error: BaseException) -> None:
 
 
 class Tracer:
-    """Nested-span tracer on an injectable monotonic clock."""
+    """Nested-span tracer on an injectable monotonic clock.
+
+    ``annotate`` (a name prefix such as ``"service."``) also makes every
+    span a profiler annotation of that prefixed name.
+    """
 
     def __init__(self, timer=time.monotonic, *,
-                 recorder_capacity: int = 64):
+                 recorder_capacity: int = 64, annotate: str | None = None):
         self.timer = timer
+        self.annotate = annotate
         self.spans: list[Span] = []         # completed, in completion order
         self._stack: list[Span] = []
         self.flight = FlightRecorder(capacity=recorder_capacity)
+        _register_compile_listener()
         _TRACERS.add(self)
 
     @contextlib.contextmanager
     def span(self, name: str, **attrs):
         """Open a span; children inherit attrs (own keys win)."""
+        if self.annotate is None:
+            note = contextlib.nullcontext()
+        else:
+            import jax
+            note = jax.profiler.TraceAnnotation(self.annotate + name)
         parent = self._stack[-1] if self._stack else None
         merged = dict(parent.attrs) if parent is not None else {}
         merged.update(attrs)
-        s = Span(name=name, t_start=self.timer(), depth=len(self._stack),
-                 parent=parent.name if parent is not None else None,
-                 attrs=merged)
-        self._stack.append(s)
-        try:
-            yield s
-        finally:
-            self._stack.pop()
-            s.duration = self.timer() - s.t_start
-            self.spans.append(s)
-            self.flight.push(s)
+        with note:
+            s = Span(name=name, t_start=self.timer(),
+                     depth=len(self._stack),
+                     parent=parent.name if parent is not None else None,
+                     attrs=merged)
+            self._stack.append(s)
+            try:
+                yield s
+            finally:
+                self._stack.pop()
+                s.duration = self.timer() - s.t_start
+                self.spans.append(s)
+                self.flight.push(s)
 
 
 # ---------------------------------------------------------------------------
@@ -152,21 +206,3 @@ def digest(spans: list[Span]) -> str:
     """blake2b over the canonical JSONL — identical spans, identical hex."""
     return hashlib.blake2b(to_jsonl(spans).encode(),
                            digest_size=16).hexdigest()
-
-
-def to_chrome_trace(spans: list[Span]) -> dict:
-    """Chrome trace-event JSON (complete "X" events, microsecond times).
-
-    ``tid`` is the span's worker attribute so each device renders as its
-    own track in about:tracing / Perfetto.
-    """
-    events = []
-    for s in spans:
-        attrs = s.to_dict()["attrs"]
-        events.append({
-            "name": s.name, "ph": "X", "pid": 0,
-            "tid": int(attrs.get("worker", 0) or 0),
-            "ts": s.t_start * 1e6, "dur": s.duration * 1e6,
-            "args": attrs,
-        })
-    return {"traceEvents": events, "displayTimeUnit": "ms"}

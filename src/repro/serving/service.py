@@ -297,22 +297,28 @@ class FFTService:
         request's receipt becomes available after the next drain():
         ``service.receipt(request)``.
         """
-        req = FFTRequest(x=jnp.asarray(x), precision=precision, kind=kind,
-                         latency_budget=latency_budget,
-                         n_harmonics=n_harmonics, transform=transform,
-                         ndim=ndim, templates=templates, segment=segment,
-                         dm_trials=dm_trials)
-        req.t_enqueue = self._timer()
-        req.payload_ref = payload_ref
-        if self.journal is not None:
-            # Write-ahead: the admit record is durable (and its seq is the
-            # request's crash-stable identity) before the service takes
-            # the request.  ``payload_ref`` is the caller's token for
-            # re-resolving the payload after a crash — arrays themselves
-            # are not journaled.
-            from repro.serving.recovery import admit_record
-            req.jseq = self.journal.append(wal.ADMIT, admit_record(req))
-        self._pending.append(req)
+        with self._span("submit") as span:
+            on_host = not isinstance(x, jax.Array)
+            req = FFTRequest(x=jnp.asarray(x), precision=precision,
+                             kind=kind, latency_budget=latency_budget,
+                             n_harmonics=n_harmonics, transform=transform,
+                             ndim=ndim, templates=templates,
+                             segment=segment, dm_trials=dm_trials)
+            if span is not None:
+                span.attrs.update(request_id=req.request_id,
+                                  payload_bytes=req.x.nbytes,
+                                  h2d_bytes=req.x.nbytes if on_host else 0)
+            req.t_enqueue = self._timer()
+            req.payload_ref = payload_ref
+            if self.journal is not None:
+                # Write-ahead: the admit record is durable (and its seq is
+                # the request's crash-stable identity) before the service
+                # takes the request.  ``payload_ref`` is the caller's
+                # token for re-resolving the payload after a crash —
+                # arrays themselves are not journaled.
+                from repro.serving.recovery import admit_record
+                req.jseq = self.journal.append(wal.ADMIT, admit_record(req))
+            self._pending.append(req)
         return req
 
     def receipt(self, request: FFTRequest) -> RequestReceipt | None:
@@ -357,70 +363,78 @@ class FFTService:
         drain before the error propagates — one bad batch never drops
         the rest of the wave.
         """
-        pending, self._pending = self._pending, []
-        if not pending:
-            return []
-        deadline = (deadline_s if deadline_s is not None
-                    else self.drain_deadline_s)
-        serve = pending
-        if self.admission is not None:
-            serve = []
-            for d in self.admission.decide(pending, self.cache):
-                if d.action == SHED:
-                    self._store(RequestReceipt.make_shed(
-                        d.request, d.reason, self._timer()))
-                else:
-                    if d.rung > RUNG_TUNED_DVFS:
-                        self._forced[d.request.request_id] = (d.rung, d.reason)
-                    serve.append(d.request)
-        try:
-            if serve:
-                if self.coalesce_requests:
-                    batches = coalesce(serve,
-                                       device_name=self.device_spec.name,
-                                       batch_bytes=self.batch_bytes,
-                                       start_id=self._next_batch_id)
-                else:
-                    batches = [
-                        Batch(self._next_batch_id + i,
-                              r.shape_key(self.device_spec.name), [r])
-                        for i, r in enumerate(serve)
-                    ]
-                self._next_batch_id += len(batches)
-                if self.journal is not None:
+        with self._span("drain"):
+            pending, self._pending = self._pending, []
+            if not pending:
+                return []
+            deadline = (deadline_s if deadline_s is not None
+                        else self.drain_deadline_s)
+            serve = pending
+            if self.admission is not None:
+                serve = []
+                for d in self.admission.decide(pending, self.cache):
+                    if d.action == SHED:
+                        self._store(RequestReceipt.make_shed(
+                            d.request, d.reason, self._timer()))
+                    else:
+                        if d.rung > RUNG_TUNED_DVFS:
+                            self._forced[d.request.request_id] = (
+                                d.rung, d.reason)
+                        serve.append(d.request)
+            try:
+                if serve:
+                    if self.coalesce_requests:
+                        batches = coalesce(
+                            serve, device_name=self.device_spec.name,
+                            batch_bytes=self.batch_bytes,
+                            start_id=self._next_batch_id)
+                    else:
+                        batches = [
+                            Batch(self._next_batch_id + i,
+                                  r.shape_key(self.device_spec.name), [r])
+                            for i, r in enumerate(serve)
+                        ]
+                    self._next_batch_id += len(batches)
+                    if self.journal is not None:
+                        for batch in batches:
+                            self.journal.append(wal.ASSIGN, {
+                                "batch_id": batch.batch_id,
+                                "rseqs": [r.jseq for r in batch.requests]})
                     for batch in batches:
-                        self.journal.append(wal.ASSIGN, {
-                            "batch_id": batch.batch_id,
-                            "rseqs": [r.jseq for r in batch.requests]})
-                for batch in batches:
-                    self.dispatcher.submit(batch)
-                self.dispatcher.drain(self._execute, timer=self._timer,
-                                      deadline_s=deadline)
-        except BaseException:
-            self.dispatcher.clear()          # drop stale queued batches
-            unserved = [r for r in serve
-                        if r.request_id not in self._receipts]
-            self._pending = unserved + self._pending
-            raise
-        finally:
-            for r in serve:
-                self._forced.pop(r.request_id, None)
-        return [self._receipts[r.request_id] for r in pending
-                if r.request_id in self._receipts]   # cap may have evicted
+                        self.dispatcher.submit(batch)
+                    self.dispatcher.drain(self._execute,
+                                          timer=self._timer,
+                                          deadline_s=deadline)
+            except BaseException:
+                self.dispatcher.clear()          # drop stale queued batches
+                unserved = [r for r in serve
+                            if r.request_id not in self._receipts]
+                self._pending = unserved + self._pending
+                raise
+            finally:
+                for r in serve:
+                    self._forced.pop(r.request_id, None)
+            # The receipt cap may have evicted some.
+            return [self._receipts[r.request_id] for r in pending
+                    if r.request_id in self._receipts]
 
     def _stack(self, batch: Batch) -> np.ndarray:
         # Stacking happens on the host: an eager device-side concatenate
         # compiles one executable per distinct operand signature, and a
         # streaming service sees a new per-request row split nearly every
         # batch — host stacking costs one memcpy and compiles nothing.
-        # The executable itself still runs on-device (jit converts the
+        # The executable itself still runs on-device (``_run`` copies the
         # host array at its one bucketed input shape).
-        if batch.key.shape:
-            # N-D payloads: normalise every request to (rows, *shape).
-            rows = [np.asarray(r.x).reshape((-1, *batch.key.shape))
-                    for r in batch.requests]
-        else:
-            rows = [np.atleast_2d(np.asarray(r.x)) for r in batch.requests]
+        # Every payload lives on the device (submit copied it there).
+        d2h = sum(r.x.nbytes for r in batch.requests)
+        with self._span("pull", d2h_bytes=d2h):
+            if batch.key.shape:
+                # N-D payloads: normalise every request to (rows, *shape).
+                rows = [np.asarray(r.x).reshape((-1, *batch.key.shape))
+                        for r in batch.requests]
+            else:
+                rows = [np.atleast_2d(np.asarray(r.x))
+                        for r in batch.requests]
         x = np.concatenate(rows, axis=0) if len(rows) > 1 else rows[0]
         if batch.key.kind == KIND_FFT:
             if batch.key.transform == "r2c":
@@ -604,20 +618,6 @@ class FFTService:
             entry = self.cache.degraded_entry(batch.key)
         point = (entry.point_for(self._effective_budget(batch))
                  if rung == RUNG_TUNED_DVFS else entry.sweep.boost)
-        x = self._stack(batch)
-        rows = x.shape[0]
-        if self.bucket_batches:
-            # Shape bucketing: pad the row count to the next power of two so
-            # streaming drains reuse a handful of compiled shapes instead of
-            # recompiling for every coalesced batch size.  Padding stays on
-            # the host for the same reason stacking does — an eager pad
-            # compiles once per *unbucketed* input shape, defeating the
-            # bucketing it implements.
-            target = 1 << (rows - 1).bit_length()
-            if target > rows:
-                x = np.concatenate(
-                    [x, np.zeros((target - rows, *x.shape[1:]),
-                                 dtype=x.dtype)], axis=0)
         # Rung 0 locks at the sweep optimum; degraded rungs still lock, at
         # boost, to pin against governor drift — clock control is
         # independent of which compute path runs, so a lock failure is
@@ -634,16 +634,33 @@ class FFTService:
                 point = entry.sweep.boost
             reasons.append("fault:clock-lock-failed")
             lock_f = None
-        t_start = self._timer()
-        ctx = (self.clock.locked(lock_f) if lock_f is not None
-               else contextlib.nullcontext())
+        rows = sum(r.batch for r in batch.requests)
         # Span attributes (kind/shape/rung/clock) inherit to child spans;
         # the ledger capture rides the execution so a first-trace records
         # the shape's launch signature (repro.obs.ledger).
         with self._span("batch", batch_id=batch.batch_id, worker=worker,
                         kind=batch.key.kind,
                         shape=batch.key.shape or (batch.key.n,),
-                        rung=rung, clock_mhz=point.f):
+                        rung=rung, clock_mhz=point.f, rows=rows,
+                        requests=len(batch.requests)):
+            with self._span("stack"):
+                x = self._stack(batch)
+            if self.bucket_batches:
+                # Shape bucketing: pad the row count to the next power of
+                # two so streaming drains reuse a handful of compiled shapes
+                # instead of recompiling for every coalesced batch size.
+                # Padding stays on the host for the same reason stacking
+                # does — an eager pad compiles once per *unbucketed* input
+                # shape, defeating the bucketing it implements.
+                target = 1 << (rows - 1).bit_length()
+                with self._span("pad", pad_rows=target - rows):
+                    if target > rows:
+                        x = np.concatenate(
+                            [x, np.zeros((target - rows, *x.shape[1:]),
+                                         dtype=x.dtype)], axis=0)
+            t_start = self._timer()
+            ctx = (self.clock.locked(lock_f) if lock_f is not None
+                   else contextlib.nullcontext())
             with ctx:
                 # Injected kills fire mid-batch: after the lock and
                 # dispatch decisions, before results exist.  A host kill
@@ -664,27 +681,38 @@ class FFTService:
                     raise DeviceLostError(worker)
                 with self._span("execute"), \
                         self.ledger.capture(key=batch.key):
-                    if (self.mesh is not None
-                            and batch.key.kind == KIND_FFT
-                            and x.shape[0] > 1 and rung < RUNG_PURE_JAX):
-                        from repro.fft.distributed import batch_parallel_fft
-                        y = batch_parallel_fft(jnp.asarray(x), self.mesh,
-                                               fft_fn=entry.plan)
-                    else:
-                        if device is not None:
-                            x = jax.device_put(x, device)
-                        if (rung >= RUNG_PURE_JAX
-                                and batch.key.kind == KIND_FFT):
-                            from repro.fft.plan import pallas_disabled
-                            with pallas_disabled():
-                                y = self._rung2_fn(batch.key)(x)
-                        else:
-                            y = entry.fn(x)
-                    y = jax.block_until_ready(y)
-        y = y[:rows]
-        t_done = self._timer()
-        self._account(batch, worker, entry, point, y, t_start, t_done,
-                      rung=rung, reason="; ".join(reasons) or None)
+                    y = self._run(batch, entry, rung, x, device)
+            with self._span("slice"):
+                y = y[:rows]
+            t_done = self._timer()
+            with self._span("account"):
+                self._account(batch, worker, entry, point, y, t_start,
+                              t_done, rung=rung,
+                              reason="; ".join(reasons) or None)
+
+    def _run(self, batch: Batch, entry, rung: int, x: np.ndarray,
+             device: Any) -> jax.Array:
+        """Copy the stacked batch to the device (``h2d``) and run its
+        executable to completion (``compute``).  With a tracer attached
+        the copy is waited for inside its span, so the span times the
+        transfer rather than its enqueue."""
+        sharded = (self.mesh is not None and batch.key.kind == KIND_FFT
+                   and x.shape[0] > 1 and rung < RUNG_PURE_JAX)
+        with self._span("h2d", h2d_bytes=x.nbytes):
+            xd = jnp.asarray(x) if sharded else jax.device_put(x, device)
+            if self.tracer is not None:
+                jax.block_until_ready(xd)
+        with self._span("compute"):
+            if sharded:
+                from repro.fft.distributed import batch_parallel_fft
+                y = batch_parallel_fft(xd, self.mesh, fft_fn=entry.plan)
+            elif rung >= RUNG_PURE_JAX and batch.key.kind == KIND_FFT:
+                from repro.fft.plan import pallas_disabled
+                with pallas_disabled():
+                    y = self._rung2_fn(batch.key)(xd)
+            else:
+                y = entry.fn(xd)
+            return jax.block_until_ready(y)
 
     def _store(self, receipt: RequestReceipt, *, key=None) -> None:
         jseq = getattr(receipt.request, "jseq", None)

@@ -3,6 +3,7 @@ the metrics registry, the kernel launch ledger, drift detection, the
 structured logger, and their serving integration (docs/observability.md).
 """
 import io
+import itertools
 
 import numpy as np
 import pytest
@@ -14,7 +15,7 @@ from repro.core.hardware import TPU_V5E
 from repro.obs import (DriftDetector, FlightRecorder, LaunchLedger,
                        LaunchRecord, MetricsRegistry, Span, StructuredLogger,
                        Tracer, latency_summary, launches_digest,
-                       record_launch, to_chrome_trace, to_jsonl)
+                       record_launch, to_jsonl)
 from repro.obs import trace as trace_mod
 from repro.obs.metrics import Counter, Gauge, Histogram
 from repro.power.telemetry import FleetTelemetry
@@ -22,6 +23,7 @@ from repro.runtime.faults import (ClockLockError, DeviceLostError,
                                   DrainDeadlineError, PlanBuildError,
                                   WorkerStalledError)
 from repro.serving import FFTService
+from repro.serving import request as request_mod
 
 KEY = jax.random.PRNGKey(0)
 
@@ -97,16 +99,45 @@ class TestTracer:
         # one canonical JSON object per line
         assert len(to_jsonl(a).splitlines()) == 2
 
-    def test_chrome_trace_export(self):
+
+    def test_compile_counts_land_on_the_innermost_span(self):
         tr = Tracer(timer=FakeTimer(dt=1.0))
-        with tr.span("batch", worker=3, shape=(2, 8)):
+        fresh = jax.jit(lambda x: x * 2.5 + 0.75)
+        with tr.span("outer", shape=(7,)):
+            with tr.span("inner"):
+                fresh(jnp.ones(7)).block_until_ready()
+        inner, outer = tr.spans
+        assert inner.compiles >= 1 and inner.compile_s > 0
+        assert outer.compiles == 0 and outer.compile_s == 0
+        # counters are neither inherited attrs nor part of the digest
+        assert "compiles" not in inner.attrs
+        assert "compiles" not in inner.to_dict()
+
+    def test_annotate_opens_one_prefixed_annotation_per_span(
+            self, monkeypatch):
+        opened = []
+
+        class Annotation:
+            def __init__(self, name):
+                opened.append(name)
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+        tr = Tracer(timer=FakeTimer(dt=1.0), annotate="service.")
+        with tr.span("batch"):
+            with tr.span("execute"):
+                pass
+        assert opened == ["service.batch", "service.execute"]
+        plain = Tracer(timer=FakeTimer(dt=1.0))
+        with plain.span("batch"):
             pass
-        doc = to_chrome_trace(tr.spans)
-        (ev,) = doc["traceEvents"]
-        assert ev["ph"] == "X" and ev["tid"] == 3
-        assert ev["ts"] == pytest.approx(1e6)       # seconds -> microseconds
-        assert ev["dur"] == pytest.approx(1e6)
-        assert ev["args"]["shape"] == [2, 8]        # JSON-safe attrs
+        assert opened == ["service.batch", "service.execute"]
+        assert [s.name for s in tr.spans] == ["execute", "batch"]
 
 
 # ---------------------------------------------------------------------------
@@ -465,8 +496,12 @@ class TestServingIntegration:
         rep = svc.report()
         assert rep.drift is not None and rep.drift["observations"] > 0
 
-    def test_trace_digest_reproducible_across_runs(self):
+    def test_trace_digest_reproducible_across_runs(self, monkeypatch):
+        # Each run stands for a fresh process, in which request ids (a
+        # submit span's request_id) restart from zero.
+        monkeypatch.setattr(request_mod, "_REQUEST_IDS", itertools.count())
         s1, t1 = self._run()
+        monkeypatch.setattr(request_mod, "_REQUEST_IDS", itertools.count())
         s2, t2 = self._run()
         assert trace_mod.digest(t1.spans) == trace_mod.digest(t2.spans)
         # the second service reuses warm jit executables (its ledger
@@ -497,3 +532,96 @@ class TestServingIntegration:
         svc2, _ = self._run(power_model=hot)
         assert svc2.drift.observations >= 4
         assert svc2.drift.drift_alerts >= 1           # model disagrees
+
+
+class TestServicePath:
+    """The host path of one served batch as spans and byte counters."""
+
+    BATCH_CHILDREN = {"stack": "batch", "pull": "stack", "pad": "batch",
+                      "execute": "batch", "h2d": "execute",
+                      "compute": "execute", "slice": "batch",
+                      "account": "batch"}
+
+    def _serve(self, payloads, *, tracer, bucket_batches=True):
+        svc = FFTService(TPU_V5E, devices=jax.devices()[:1],
+                         tracer=tracer, bucket_batches=bucket_batches)
+        for p in payloads:
+            svc.submit(p)
+        svc.drain()
+        return svc
+
+    def test_served_batch_yields_the_span_tree(self):
+        tracer = Tracer(timer=FakeTimer(dt=1e-4))
+        # 3 + 2 rows pad to 8
+        payloads = [np.asarray(rand_complex((3, 64))),
+                    np.asarray(rand_complex((2, 64)))]
+        self._serve(payloads, tracer=tracer)
+        by_name = {}
+        for s in tracer.spans:
+            by_name.setdefault(s.name, []).append(s)
+        assert len(by_name["submit"]) == 2
+        assert all(s.parent is None for s in by_name["submit"])
+        (drain,) = by_name["drain"]
+        (batch,) = by_name["batch"]
+        assert batch.parent == "drain" and drain.parent is None
+        assert batch.attrs["rows"] == 5 and batch.attrs["requests"] == 2
+        for name, parent in self.BATCH_CHILDREN.items():
+            (span,) = by_name[name]
+            assert span.parent == parent, name
+            assert span.attrs["batch_id"] == batch.attrs["batch_id"], name
+        assert by_name["pad"][0].attrs["pad_rows"] == 3
+        assert set(by_name) == {"submit", "drain", "batch",
+                                *self.BATCH_CHILDREN}
+
+    @pytest.mark.parametrize("on_host,copies", [(True, 3), (False, 2)],
+                             ids=["host-array", "device-array"])
+    def test_byte_counters_total_the_copies(self, on_host, copies):
+        tracer = Tracer(timer=FakeTimer(dt=1e-4))
+        payloads = [rand_complex((4, 64), jax.random.PRNGKey(i))
+                    for i in range(2)]
+        if on_host:
+            payloads = [np.asarray(p) for p in payloads]
+        self._serve(payloads, tracer=tracer)
+
+        def total(name, key):
+            return sum(s.attrs[key] for s in tracer.spans if s.name == name)
+
+        payload = total("submit", "payload_bytes")
+        assert payload == 2 * 4 * 64 * 8
+        moved = (total("submit", "h2d_bytes") + total("h2d", "h2d_bytes")
+                 + total("pull", "d2h_bytes"))
+        assert moved == copies * payload
+
+    def test_no_tracer_opens_no_span_adds_no_sync_or_listener(
+            self, monkeypatch):
+        spans = []
+
+        class CountingSpan(trace_mod.Span):
+            def __init__(self, name, *args, **kwargs):
+                super().__init__(name, *args, **kwargs)
+                spans.append(name)
+
+        syncs = []
+        block = jax.block_until_ready
+
+        def counting_block(x):
+            syncs.append(1)
+            return block(x)
+
+        registered = []
+        monkeypatch.setattr(trace_mod, "Span", CountingSpan)
+        monkeypatch.setattr(jax, "block_until_ready", counting_block)
+        monkeypatch.setattr(trace_mod, "_compile_listener_registered", False)
+        monkeypatch.setattr(jax.monitoring,
+                            "register_event_duration_secs_listener",
+                            registered.append)
+        payloads = [np.asarray(rand_complex((4, 64)))]
+        self._serve(payloads, tracer=None)
+        assert spans == [] and registered == []
+        untraced = len(syncs)
+        assert untraced >= 1
+        syncs.clear()
+        self._serve(payloads, tracer=Tracer(timer=FakeTimer(dt=1e-4)))
+        assert registered == [trace_mod._on_duration]
+        assert "h2d" in spans
+        assert len(syncs) == untraced + 1      # the h2d span's wait
