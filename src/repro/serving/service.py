@@ -39,12 +39,14 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import time
 from typing import Any, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.core.energy import guarded_ratio
 from repro.core.hardware import TPU_V5E, DeviceSpec
@@ -63,7 +65,7 @@ from repro.serving.batcher import Batch, coalesce
 from repro.serving.cache import CacheStats, PlanSweepCache
 from repro.serving.dispatch import Dispatcher
 from repro.serving.request import (KIND_FFT, KIND_PULSAR, FFTRequest,
-                                   RequestReceipt, StageReceipt)
+                                   RequestReceipt, ShapeKey, StageReceipt)
 from repro.serving.slo import (RUNG_BOOST_HEURISTIC, RUNG_PURE_JAX,
                                RUNG_TUNED_DVFS, SHED, SLOPolicy,
                                AdmissionController, max_rung_for_kind)
@@ -74,6 +76,58 @@ _EXEC_DTYPE = {"fp16": jnp.complex64, "fp32": jnp.complex64,
 # double the device bytes and forfeit the R2C saving the receipts report.
 _REAL_EXEC_DTYPE = {"fp16": jnp.float32, "fp32": jnp.float32,
                     "fp64": jnp.float64}
+
+
+def _exec_dtype(key: ShapeKey) -> np.dtype:
+    """The dtype ``key``'s executable takes: complex for C2C, real for
+    R2C, float32 for the pulsar pipeline and the FDAS search (which
+    consume real time series); fp64 only where 64-bit is enabled."""
+    if key.kind != KIND_FFT:
+        dtype = jnp.float32
+    elif key.transform == "r2c":
+        dtype = _REAL_EXEC_DTYPE[key.precision]
+    else:
+        dtype = _EXEC_DTYPE[key.precision]
+    return jax.dtypes.canonicalize_dtype(dtype)
+
+
+@jax.jit
+def _complex_from_pairs(v: jax.Array) -> jax.Array:
+    """The complex array whose (real, imaginary) pairs ``v`` holds along
+    its last axis: a host complex array's real view, joined on the
+    device."""
+    return lax.complex(v[..., 0::2], v[..., 1::2])
+
+
+def _to_device(x: Any) -> jax.Array:
+    """``x`` on the device.  A host complex array crosses the link as its
+    real view (the same bytes) and is joined on the device: copied as
+    complex, it is transposed on the host in small chunks, and a profiler
+    records each of them."""
+    if isinstance(x, jax.Array):
+        return jnp.asarray(x)
+    xh = np.asarray(x)
+    if xh.ndim == 0 or not np.iscomplexobj(xh):
+        return jnp.asarray(xh)
+    pairs = np.ascontiguousarray(xh).view(np.finfo(xh.dtype).dtype)
+    return _complex_from_pairs(jnp.asarray(pairs))
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _write_rows(planes: tuple, x: jax.Array, offset) -> tuple:
+    """``planes`` with request ``x`` written in place from row ``offset``
+    on, as (rows, *shape): its real part into ``planes[0]`` and, for a
+    complex batch, its imaginary part into ``planes[1]``.  A complex
+    batch is built as two real planes because the TPU keeps complex
+    elements as 64-bit words that every update splits and joins again:
+    on planes, each payload is split once and the batch joined once.
+    The offset is traced, so the programs are one per (bucket, payload)
+    shape and dtype pair, whatever the batch's row split."""
+    x = x.reshape((-1, *planes[0].shape[1:]))
+    parts = (jnp.real(x), jnp.imag(x))
+    start = (offset,) + (0,) * (x.ndim - 1)
+    return tuple(lax.dynamic_update_slice(p, v.astype(p.dtype), start)
+                 for p, v in zip(planes, parts))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -299,7 +353,7 @@ class FFTService:
         """
         with self._span("submit") as span:
             on_host = not isinstance(x, jax.Array)
-            req = FFTRequest(x=jnp.asarray(x), precision=precision,
+            req = FFTRequest(x=_to_device(x), precision=precision,
                              kind=kind, latency_budget=latency_budget,
                              n_harmonics=n_harmonics, transform=transform,
                              ndim=ndim, templates=templates,
@@ -418,30 +472,43 @@ class FFTService:
             return [self._receipts[r.request_id] for r in pending
                     if r.request_id in self._receipts]
 
-    def _stack(self, batch: Batch) -> np.ndarray:
-        # Stacking happens on the host: an eager device-side concatenate
-        # compiles one executable per distinct operand signature, and a
-        # streaming service sees a new per-request row split nearly every
-        # batch — host stacking costs one memcpy and compiles nothing.
-        # The executable itself still runs on-device (``_run`` copies the
-        # host array at its one bucketed input shape).
-        # Every payload lives on the device (submit copied it there).
-        d2h = sum(r.x.nbytes for r in batch.requests)
-        with self._span("pull", d2h_bytes=d2h):
-            if batch.key.shape:
-                # N-D payloads: normalise every request to (rows, *shape).
-                rows = [np.asarray(r.x).reshape((-1, *batch.key.shape))
-                        for r in batch.requests]
-            else:
-                rows = [np.atleast_2d(np.asarray(r.x))
-                        for r in batch.requests]
-        x = np.concatenate(rows, axis=0) if len(rows) > 1 else rows[0]
-        if batch.key.kind == KIND_FFT:
-            if batch.key.transform == "r2c":
-                return x.real.astype(_REAL_EXEC_DTYPE[batch.key.precision])
-            return x.astype(_EXEC_DTYPE[batch.key.precision])
-        # The pulsar pipeline and the FDAS search consume real time series.
-        return x.real.astype(np.float32)
+    def _bucket(self, batch: Batch, target: int) -> tuple | None:
+        """The zero-filled (``target``, *shape) planes the batch is written
+        into (one for a real batch, real and imaginary for a complex
+        one), on the device holding its first payload (submit put it
+        there); their rows past the requests' are the bucketing pad.
+        None when the batch is one payload that already has the
+        executable's shape and dtype: it is handed through as it is.
+        """
+        shape = (target, *(batch.key.shape or (batch.key.n,)))
+        dtype = _exec_dtype(batch.key)
+        first = batch.requests[0].x
+        if (len(batch.requests) == 1 and first.shape == shape
+                and first.dtype == dtype):
+            return None
+        plane = jnp.zeros(shape, np.finfo(dtype).dtype,
+                          device=next(iter(first.devices())))
+        if not jnp.issubdtype(dtype, jnp.complexfloating):
+            return (plane,)
+        return plane, jnp.zeros_like(plane)
+
+    def _stack(self, batch: Batch, planes: tuple | None) -> jax.Array:
+        """Write every payload of the batch into ``planes`` on the device,
+        one ``_write_rows`` call per request at its row offset (a payload
+        on another device is moved to the planes' first), and join a
+        complex batch's planes; with no planes, the batch's one payload.
+        """
+        if planes is None:
+            return batch.requests[0].x
+        home = planes[0].sharding
+        offset = 0
+        for r in batch.requests:
+            x = r.x
+            if x.devices() != home.device_set:
+                x = jax.device_put(x, home)
+            planes = _write_rows(planes, x, offset)
+            offset += r.batch
+        return planes[0] if len(planes) == 1 else lax.complex(*planes)
 
     def _effective_budget(self, batch: Batch) -> float | None:
         """Strictest real-time budget across the batch's requests.
@@ -635,6 +702,8 @@ class FFTService:
             reasons.append("fault:clock-lock-failed")
             lock_f = None
         rows = sum(r.batch for r in batch.requests)
+        target = (1 << (rows - 1).bit_length() if self.bucket_batches
+                  else rows)
         # Span attributes (kind/shape/rung/clock) inherit to child spans;
         # the ledger capture rides the execution so a first-trace records
         # the shape's launch signature (repro.obs.ledger).
@@ -643,21 +712,19 @@ class FFTService:
                         shape=batch.key.shape or (batch.key.n,),
                         rung=rung, clock_mhz=point.f, rows=rows,
                         requests=len(batch.requests)):
-            with self._span("stack"):
-                x = self._stack(batch)
-            if self.bucket_batches:
-                # Shape bucketing: pad the row count to the next power of
-                # two so streaming drains reuse a handful of compiled shapes
-                # instead of recompiling for every coalesced batch size.
-                # Padding stays on the host for the same reason stacking
-                # does — an eager pad compiles once per *unbucketed* input
-                # shape, defeating the bucketing it implements.
-                target = 1 << (rows - 1).bit_length()
-                with self._span("pad", pad_rows=target - rows):
-                    if target > rows:
-                        x = np.concatenate(
-                            [x, np.zeros((target - rows, *x.shape[1:]),
-                                         dtype=x.dtype)], axis=0)
+            # The batch is built on the device from the payloads submit
+            # put there: zero-filled bucket planes, then one in-place write
+            # per request at a traced row offset.  The programs are one
+            # zero fill (and join) per bucket shape and one write per
+            # (bucket, payload) shape and dtype pair, so a stream of new
+            # row splits compiles nothing.  Shape bucketing pads the row
+            # count to the next power of two, so streaming drains reuse a
+            # handful of executables instead of compiling one per size.
+            with self._span("pad", pad_rows=target - rows):
+                planes = self._bucket(batch, target)
+            with self._span("stack", device_stacked=(
+                    0 if planes is None else len(batch.requests))):
+                x = self._stack(batch, planes)
             t_start = self._timer()
             ctx = (self.clock.locked(lock_f) if lock_f is not None
                    else contextlib.nullcontext())
@@ -682,6 +749,7 @@ class FFTService:
                 with self._span("execute"), \
                         self.ledger.capture(key=batch.key):
                     y = self._run(batch, entry, rung, x, device)
+                del x      # the stacked batch's memory, before the slices
             with self._span("slice"):
                 y = y[:rows]
             t_done = self._timer()
@@ -690,16 +758,20 @@ class FFTService:
                               t_done, rung=rung,
                               reason="; ".join(reasons) or None)
 
-    def _run(self, batch: Batch, entry, rung: int, x: np.ndarray,
+    def _run(self, batch: Batch, entry, rung: int, x: jax.Array,
              device: Any) -> jax.Array:
-        """Copy the stacked batch to the device (``h2d``) and run its
-        executable to completion (``compute``).  With a tracer attached
-        the copy is waited for inside its span, so the span times the
-        transfer rather than its enqueue."""
+        """Place the stacked batch on the worker's device (``h2d``) and run
+        its executable to completion (``compute``).  The batch is already
+        on a device, so no host bytes cross the link here: a worker on
+        another device gets one device-to-device copy.  With a tracer
+        attached the batch is waited for inside ``h2d``, so the span times
+        the submit copies and device writes it depends on rather than
+        leaving them to ``compute``."""
         sharded = (self.mesh is not None and batch.key.kind == KIND_FFT
                    and x.shape[0] > 1 and rung < RUNG_PURE_JAX)
-        with self._span("h2d", h2d_bytes=x.nbytes):
-            xd = jnp.asarray(x) if sharded else jax.device_put(x, device)
+        with self._span("h2d", h2d_bytes=0):
+            xd = (x if sharded or x.devices() == {device}
+                  else jax.device_put(x, device))
             if self.tracer is not None:
                 jax.block_until_ready(xd)
         with self._span("compute"):

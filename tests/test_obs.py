@@ -537,7 +537,7 @@ class TestServingIntegration:
 class TestServicePath:
     """The host path of one served batch as spans and byte counters."""
 
-    BATCH_CHILDREN = {"stack": "batch", "pull": "stack", "pad": "batch",
+    BATCH_CHILDREN = {"stack": "batch", "pad": "batch",
                       "execute": "batch", "h2d": "execute",
                       "compute": "execute", "slice": "batch",
                       "account": "batch"}
@@ -570,10 +570,11 @@ class TestServicePath:
             assert span.parent == parent, name
             assert span.attrs["batch_id"] == batch.attrs["batch_id"], name
         assert by_name["pad"][0].attrs["pad_rows"] == 3
+        assert by_name["stack"][0].attrs["device_stacked"] == 2
         assert set(by_name) == {"submit", "drain", "batch",
                                 *self.BATCH_CHILDREN}
 
-    @pytest.mark.parametrize("on_host,copies", [(True, 3), (False, 2)],
+    @pytest.mark.parametrize("on_host,copies", [(True, 1), (False, 0)],
                              ids=["host-array", "device-array"])
     def test_byte_counters_total_the_copies(self, on_host, copies):
         tracer = Tracer(timer=FakeTimer(dt=1e-4))
@@ -588,9 +589,9 @@ class TestServicePath:
 
         payload = total("submit", "payload_bytes")
         assert payload == 2 * 4 * 64 * 8
-        moved = (total("submit", "h2d_bytes") + total("h2d", "h2d_bytes")
-                 + total("pull", "d2h_bytes"))
+        moved = total("submit", "h2d_bytes") + total("h2d", "h2d_bytes")
         assert moved == copies * payload
+        assert not any(s.name == "pull" for s in tracer.spans)
 
     def test_no_tracer_opens_no_span_adds_no_sync_or_listener(
             self, monkeypatch):

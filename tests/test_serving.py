@@ -209,8 +209,9 @@ def test_r2c_batches_execute_real_and_pack_double():
     # and the executor stacks the r2c batch as a real array
     svc = FFTService(TPU_V5E)
     svc.submit(xr, transform="r2c")
-    stacked = svc._stack(coalesce(svc._pending, device_name=TPU_V5E.name,
-                                  batch_bytes=budget)[0])
+    (batch,) = coalesce(svc._pending, device_name=TPU_V5E.name,
+                        batch_bytes=budget)
+    stacked = svc._stack(batch, svc._bucket(batch, 4))
     assert stacked.dtype == jnp.float32
 
 
@@ -337,6 +338,115 @@ def test_receipt_retention_cap_evicts_oldest():
 
 
 # ---------------------------------------------------------------------------
+# batches stacked on the device from the submitted payloads
+# ---------------------------------------------------------------------------
+
+def host_stack(batch, target):
+    """A batch as the service stacked it on the host before it stacked on
+    the device: payloads pulled back, concatenated, cast, zero-padded."""
+    key = batch.key
+    rows = [np.asarray(r.x).reshape((-1, *(key.shape or (key.n,))))
+            for r in batch.requests]
+    x = np.concatenate(rows, axis=0)
+    if key.kind == "fft" and key.transform == "c2c":
+        x = x.astype(np.complex64)
+    else:
+        x = x.real.astype(np.float32)
+    pad = np.zeros((target - len(x), *x.shape[1:]), x.dtype)
+    return np.concatenate([x, pad], axis=0)
+
+
+def _real(shape, seed):
+    return np.asarray(jax.random.normal(jax.random.PRNGKey(seed), shape))
+
+
+PULSAR_KW = dict(kind="pulsar", n_harmonics=4, templates=5, dm_trials=4)
+
+
+@pytest.mark.parametrize("payloads,kw,target", [
+    ([rand_complex((4, 64)), rand_complex((4, 64), KEY + 1)], {}, 8),
+    ([np.asarray(rand_complex((4, 64))), rand_complex((2, 64), KEY + 1)],
+     dict(transform="r2c"), 8),
+    ([rand_complex((2, 16, 16)), rand_complex((16, 16), KEY + 1)],
+     dict(ndim=2), 4),
+    ([_real((8, 512), 0), _real((8, 512), 1)], PULSAR_KW, 2),
+    ([rand_complex((3, 64)), rand_complex((2, 64), KEY + 1)], {}, 8),
+    ([rand_complex((8, 64))], {}, 8),
+], ids=["c2c", "r2c-from-complex", "fft2", "pulsar-pair", "padded-split",
+        "pass-through"])
+def test_device_stacked_batch_matches_host_stacking(monkeypatch, payloads,
+                                                    kw, target):
+    """The executable gets the bits the host stacking gave it: the same
+    rows, cast and zero pad, built on the device from the payloads."""
+    svc = FFTService(TPU_V5E, devices=jax.devices()[:1])
+    reqs = [svc.submit(p, **kw) for p in payloads]
+    seen = []
+    run = svc._run
+
+    def capture(batch, entry, rung, x, device):
+        seen.append((batch, x, np.asarray(x)))
+        return run(batch, entry, rung, x, device)
+
+    monkeypatch.setattr(svc, "_run", capture)
+    svc.drain()
+    ((batch, x, got),) = seen
+    want = host_stack(batch, target)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert got.tobytes() == want.tobytes()
+    assert isinstance(x, jax.Array)
+    if len(reqs) == 1:
+        assert x is reqs[0].x                     # handed through
+    assert all(svc.receipt(r).status == "served" for r in reqs)
+
+
+@pytest.mark.parametrize("make", [
+    lambda: np.array(rand_complex((3, 64))),
+    lambda: np.array(rand_complex((2, 8, 16))),
+    lambda: np.array(rand_complex((64,))),
+    lambda: np.array(rand_complex((3, 64)), dtype=np.complex128),
+    lambda: np.array(rand_complex((64, 3))).T,
+], ids=["c64", "n-d", "1-d", "c128", "strided"])
+def test_host_complex_payload_is_copied_bit_for_bit(make):
+    """A host complex payload crosses the link as its real view and is
+    joined on the device into the array a complex copy gives."""
+    x = make()
+    svc = FFTService(TPU_V5E, devices=jax.devices()[:1])
+    want = np.asarray(jnp.asarray(x))
+    req = svc.submit(x, ndim=2 if x.ndim == 3 else 1)
+    got = np.asarray(req.x)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert got.tobytes() == want.tobytes()
+
+
+def test_stack_compiles_once_per_bucket_and_request_size():
+    """Shuffled row splits over two request sizes: once a (bucket,
+    request size) pair has been stacked, stacking it again at any row
+    offset builds no program."""
+    from repro.obs import Tracer
+    rng = np.random.default_rng(7)
+    tracer = Tracer(timer=lambda: 0.0)
+    svc = FFTService(TPU_V5E, devices=jax.devices()[:1], tracer=tracer)
+    payloads = {3: rand_complex((3, 64)), 5: rand_complex((5, 64), KEY + 1)}
+    splits = [[3, 3], [3, 5], [5, 5], [3, 3, 3], [3, 5, 3], [5, 5, 3]]
+    seen, checked = set(), 0
+    for i in rng.permutation(len(splits) * 2) % len(splits):
+        split = [int(v) for v in rng.permutation(splits[i])]
+        for size in split:
+            svc.submit(payloads[size])
+        n_spans = len(tracer.spans)
+        svc.drain()
+        (stack,) = [s for s in tracer.spans[n_spans:] if s.name == "stack"]
+        bucket = 1 << (sum(split) - 1).bit_length()
+        assert stack.attrs["device_stacked"] == len(split)
+        pairs = {(bucket, size) for size in split}
+        if pairs <= seen:
+            assert stack.compiles == 0, (split, bucket)
+            checked += 1
+        seen |= pairs
+    assert checked >= 6
+
+
+# ---------------------------------------------------------------------------
 # multi-device sharding vs the single-device oracle (subprocess, slow)
 # ---------------------------------------------------------------------------
 
@@ -363,3 +473,32 @@ def test_sharded_service_matches_single_device_oracle():
                                    rtol=2e-3, atol=2e-3)
         print("sharded ok")
     """, n_devices=4)
+
+
+def test_batch_on_another_worker_than_its_payloads():
+    """Two workers on two devices: the batch that lands on the worker not
+    holding its payloads is moved there once and still matches numpy."""
+    from test_distributed import run_with_devices
+    out = run_with_devices("""
+        import jax, numpy as np
+        from repro.core.hardware import TPU_V5E
+        from repro.serving import FFTService
+
+        devs = jax.devices()
+        svc = FFTService(TPU_V5E, devices=devs[:2])
+        rng = np.random.default_rng(0)
+        xs = [(rng.standard_normal((3, n))
+               + 1j * rng.standard_normal((3, n))).astype(np.complex64)
+              for n in (64, 128)]
+        reqs = [svc.submit(x) for x in xs]
+        svc.drain()
+        for x, req in zip(xs, reqs):
+            rec = svc.receipt(req)
+            assert req.x.devices() == {devs[0]}
+            assert rec.result.devices() == {devs[rec.worker]}
+            np.testing.assert_allclose(np.asarray(rec.result),
+                                       np.fft.fft(x, axis=-1),
+                                       rtol=2e-3, atol=2e-3)
+        print("workers", sorted(svc.receipt(r).worker for r in reqs))
+    """, n_devices=2)
+    assert "workers [0, 1]" in out
