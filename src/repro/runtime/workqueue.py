@@ -7,10 +7,9 @@ take LIFO from the back, so stolen work is the freshest — and on this
 workload the largest remaining — item).
 
 The queue is cooperative and deterministic: the serving drain loop drives
-workers round-robin on one host, matching how this repository simulates
-multi-device behaviour elsewhere (see repro.runtime.fault's deterministic
-shard reassignment).  The same interface maps onto one consumer thread per
-accelerator in a threaded deployment.
+workers round-robin on one host thread (repro.serving.dispatch), matching
+how this repository simulates multi-device behaviour elsewhere (see
+repro.runtime.fault's deterministic shard reassignment).
 """
 from __future__ import annotations
 

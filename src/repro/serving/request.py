@@ -103,6 +103,9 @@ class FFTRequest:
     # payload (a stream index, an object-store key).  Journaled with the
     # admit record so recovery can re-materialise in-flight payloads.
     payload_ref: Any = None
+    # The worker whose device submit put the payload on; None where it
+    # routed none (one worker, or workers that hold no device).
+    home: int | None = None
 
     def __post_init__(self):
         if self.precision not in COMPLEX_BYTES:

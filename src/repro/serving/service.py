@@ -2,15 +2,18 @@
 
 Request lifecycle (docs/serving.md walks through a full example):
 
-  enqueue      submit() stamps arrival time and parks the request
+  enqueue      submit() stamps arrival time and parks the request, its
+               payload on its home worker's device when there are several
   batch        drain() coalesces pending requests into Eq. 6-sized batches
   plan-cache   each batch's shape hits the plan + sweep cache (one FFT plan
                and one DVFS sweep per distinct shape, ever)
   clock-plan   the batch's operating point is selected from the cached
                sweep under the strictest per-request real-time budget
-  execute      the batch runs with the clock locked (ClockController), on
-               the device the work-stealing dispatcher assigned — or
-               sharded over the whole mesh for oversized batches
+  execute      the batch is launched with the clock locked
+               (ClockController) on the device the work-stealing
+               dispatcher assigned — or sharded over the whole mesh for
+               oversized batches — and waited for once every worker's
+               batch of the round is launched
   account      every request gets a receipt: queue/service latency
                (measured) and energy at the locked vs boost clock
                (modelled, Eqs. 3-4)
@@ -99,18 +102,28 @@ def _complex_from_pairs(v: jax.Array) -> jax.Array:
     return lax.complex(v[..., 0::2], v[..., 1::2])
 
 
-def _to_device(x: Any) -> jax.Array:
-    """``x`` on the device.  A host complex array crosses the link as its
-    real view (the same bytes) and is joined on the device: copied as
-    complex, it is transposed on the host in small chunks, and a profiler
-    records each of them."""
+def _to_device(x: Any, device: Any = None) -> jax.Array:
+    """``x`` on ``device`` (a host array; the default device when None),
+    or where it is (a device array).  A host complex array crosses the
+    link as its real view (the same bytes) and is joined on the device:
+    copied as complex, it is transposed on the host in small chunks, and
+    a profiler records each of them."""
     if isinstance(x, jax.Array):
         return jnp.asarray(x)
     xh = np.asarray(x)
     if xh.ndim == 0 or not np.iscomplexobj(xh):
-        return jnp.asarray(xh)
+        return jnp.asarray(xh, device=device)
     pairs = np.ascontiguousarray(xh).view(np.finfo(xh.dtype).dtype)
-    return _complex_from_pairs(jnp.asarray(pairs))
+    return _complex_from_pairs(jnp.asarray(pairs, device=device))
+
+
+def _stray_bytes(batch: Batch, planes: tuple | None) -> int:
+    """Bytes of the batch's payloads held on another device than its
+    planes: what stacking moves from chip to chip."""
+    if planes is None:
+        return 0
+    home = planes[0].devices()
+    return sum(r.x.nbytes for r in batch.requests if r.x.devices() != home)
 
 
 @functools.partial(jax.jit, donate_argnums=0)
@@ -128,6 +141,24 @@ def _write_rows(planes: tuple, x: jax.Array, offset) -> tuple:
     start = (offset,) + (0,) * (x.ndim - 1)
     return tuple(lax.dynamic_update_slice(p, v.astype(p.dtype), start)
                  for p, v in zip(planes, parts))
+
+
+@dataclasses.dataclass
+class _InFlight:
+    """A batch launched on its worker and not yet waited for."""
+
+    batch: Batch
+    worker: int
+    entry: Any
+    point: Any
+    rung: int
+    reason: str | None
+    rows: int
+    attrs: dict          # the batch span's, for the collect span
+    x: Any               # the batch as placed on the worker's device
+    y: Any               # its executable's result, still being computed
+    d2d_bytes: int       # bytes the placement moved from another device
+    t_start: float
 
 
 @dataclasses.dataclass(frozen=True)
@@ -261,6 +292,8 @@ class FFTService:
         self.dispatcher = Dispatcher(
             devices=[None] if mesh is not None else devices)
         self._pending: list[FFTRequest] = []
+        self._copying: dict[int, jax.Array] = {}   # worker -> last routed copy
+        self._inflight: list[_InFlight] = []
         self._receipts: dict[int, RequestReceipt] = {}
         self._next_batch_id = 0
         # --- robustness state ---------------------------------------------
@@ -353,15 +386,29 @@ class FFTService:
         """
         with self._span("submit") as span:
             on_host = not isinstance(x, jax.Array)
-            req = FFTRequest(x=_to_device(x), precision=precision,
+            if on_host:
+                x = np.asarray(x)
+            req = FFTRequest(x=x, precision=precision,
                              kind=kind, latency_budget=latency_budget,
                              n_harmonics=n_harmonics, transform=transform,
                              ndim=ndim, templates=templates,
                              segment=segment, dm_trials=dm_trials)
+            req.home = self._route(req)
+            copied = on_host and req.home is not None
+            if copied and req.home in self._copying:
+                # One host copy in flight per chip: with many in flight
+                # at once the copies cross several times slower.
+                self._copying.pop(req.home).block_until_ready()
+            req.x = _to_device(x, None if req.home is None
+                               else self.dispatcher.devices[req.home])
+            if copied:
+                self._copying[req.home] = req.x
             if span is not None:
                 span.attrs.update(request_id=req.request_id,
                                   payload_bytes=req.x.nbytes,
                                   h2d_bytes=req.x.nbytes if on_host else 0)
+                if req.home is not None:
+                    span.attrs["worker"] = req.home
             req.t_enqueue = self._timer()
             req.payload_ref = payload_ref
             if self.journal is not None:
@@ -374,6 +421,32 @@ class FFTService:
                 req.jseq = self.journal.append(wal.ADMIT, admit_record(req))
             self._pending.append(req)
         return req
+
+    def _route(self, req: FFTRequest) -> int | None:
+        """The worker whose device a new payload is put on, so that the
+        batch holding it runs where it already is.  A host payload goes
+        to the worker with the fewest queued bytes of its shape key among
+        those not stalled or quarantined (the lowest index on a tie); a
+        device payload stays on its device, homed at the worker there.
+        None with one worker, or with workers that hold no device (a
+        mesh, a simulated fleet): the payload goes to the default device.
+        """
+        devices = self.dispatcher.devices
+        if len(devices) < 2 or any(d is None for d in devices):
+            return None
+        if isinstance(req.x, jax.Array):
+            return next((w for w, d in enumerate(devices)
+                         if req.x.devices() == {d}), None)
+        key = req.shape_key(self.device_spec.name)
+        queued = [0] * len(devices)
+        for r in self._pending:
+            if (r.home is not None
+                    and r.shape_key(self.device_spec.name) == key):
+                queued[r.home] += r.x.nbytes
+        now = self._timer()
+        ready = [w for w in range(len(devices))
+                 if not self._peek_blocked(w, now)]
+        return min(ready or range(len(devices)), key=queued.__getitem__)
 
     def receipt(self, request: FFTRequest) -> RequestReceipt | None:
         return self._receipts.get(request.request_id)
@@ -418,6 +491,7 @@ class FFTService:
         the rest of the wave.
         """
         with self._span("drain"):
+            self._copying.clear()
             pending, self._pending = self._pending, []
             if not pending:
                 return []
@@ -441,11 +515,13 @@ class FFTService:
                         batches = coalesce(
                             serve, device_name=self.device_spec.name,
                             batch_bytes=self.batch_bytes,
-                            start_id=self._next_batch_id)
+                            start_id=self._next_batch_id,
+                            pow2_rows=self.bucket_batches)
                     else:
                         batches = [
                             Batch(self._next_batch_id + i,
-                                  r.shape_key(self.device_spec.name), [r])
+                                  r.shape_key(self.device_spec.name), [r],
+                                  r.home)
                             for i, r in enumerate(serve)
                         ]
                     self._next_batch_id += len(batches)
@@ -456,11 +532,12 @@ class FFTService:
                                 "rseqs": [r.jseq for r in batch.requests]})
                     for batch in batches:
                         self.dispatcher.submit(batch)
-                    self.dispatcher.drain(self._execute,
+                    self.dispatcher.drain(self._execute, self._collect,
                                           timer=self._timer,
                                           deadline_s=deadline)
             except BaseException:
                 self.dispatcher.clear()          # drop stale queued batches
+                self._inflight.clear()
                 unserved = [r for r in serve
                             if r.request_id not in self._receipts]
                 self._pending = unserved + self._pending
@@ -475,8 +552,9 @@ class FFTService:
     def _bucket(self, batch: Batch, target: int) -> tuple | None:
         """The zero-filled (``target``, *shape) planes the batch is written
         into (one for a real batch, real and imaginary for a complex
-        one), on the device holding its first payload (submit put it
-        there); their rows past the requests' are the bucketing pad.
+        one), on the device holding its first payload: its home worker's
+        where submit routed it, else the default device; their rows past
+        the requests' are the bucketing pad.
         None when the batch is one payload that already has the
         executable's shape and dtype: it is handed through as it is.
         """
@@ -495,7 +573,8 @@ class FFTService:
     def _stack(self, batch: Batch, planes: tuple | None) -> jax.Array:
         """Write every payload of the batch into ``planes`` on the device,
         one ``_write_rows`` call per request at its row offset (a payload
-        on another device is moved to the planes' first), and join a
+        on another device, which routing leaves only to payloads submitted
+        as device arrays, is moved to the planes' first), and join a
         complex batch's planes; with no planes, the batch's one payload.
         """
         if planes is None:
@@ -599,13 +678,13 @@ class FFTService:
         return self.tracer.span(name, **attrs)
 
     def _execute(self, batch: Batch, worker: int, device: Any) -> None:
-        """Fault-aware execution wrapper around :meth:`_execute_batch`.
+        """Fault-aware launch of one batch (:meth:`_launch`); the wait for
+        it is :meth:`_collect`'s.
 
         Blocked workers (stalled or breaker-open) hand the batch to a
         healthy peer; an injected stall marks the worker and redistributes;
         a lost device trips the breaker and retries the batch elsewhere
-        under the retry policy, shedding with "fault:retries-exhausted"
-        receipts only when it is spent.
+        under the retry policy (:meth:`_lost`).
         """
         now = self._timer()
         if self._stalled_until.get(worker, 0.0) > now:
@@ -623,8 +702,29 @@ class FFTService:
                 self._reassign(batch, exclude=worker, now=now)
                 return
         try:
-            self._execute_batch(batch, worker, device)
-        except HostLostError as e:
+            self._inflight.append(self._launch(batch, worker, device))
+        except DeviceLostError as e:
+            self._lost(batch, worker, e)
+
+    def _collect(self) -> None:
+        """Wait for every batch launched since the last collect, in launch
+        (worker) order, and account it.  A device lost at the wait takes
+        the path of one lost at launch."""
+        inflight, self._inflight = self._inflight, []
+        for f in inflight:
+            try:
+                self._finish(f)
+            except DeviceLostError as e:
+                self._lost(f.batch, f.worker, e)
+            else:
+                self._breaker(f.worker).record_success()
+
+    def _lost(self, batch: Batch, worker: int, error: DeviceLostError) -> None:
+        """Retry a batch whose device (or host) was lost on another worker,
+        shedding it with "fault:*" receipts once the retry policy is
+        spent."""
+        now = self._timer()
+        if isinstance(error, HostLostError):
             # The whole fault domain died: every worker on the host is
             # quarantined at once (breaker tripped straight to open — no
             # point counting failures towards a threshold when the host
@@ -632,41 +732,30 @@ class FFTService:
             # (the readings lived in that host's memory).  The batch then
             # follows the normal retry/redistribute/shed ladder, with the
             # whole domain excluded.
-            now = self._timer()
             self.host_kills += 1
-            for w in e.workers:
+            for w in error.workers:
                 self._breaker(w).trip(now)
                 if self.telemetry is not None:
                     ring = self.telemetry.rings.get(w)
                     if ring is not None:
                         ring.clear()
-            attempts = self._attempts.get(batch.batch_id, 0) + 1
-            self._attempts[batch.batch_id] = attempts
-            if attempts > self.retry.max_retries:
-                self._attempts.pop(batch.batch_id, None)
-                for req in batch.requests:
-                    self._store(RequestReceipt.make_shed(
-                        req, "fault:host-lost", now))
-                return
-            self._sleep(self.retry.delay(attempts, token=batch.batch_id))
-            self._reassign(batch, exclude=e.workers, now=now)
-        except DeviceLostError:
-            now = self._timer()
-            self._breaker(worker).record_failure(now)
-            attempts = self._attempts.get(batch.batch_id, 0) + 1
-            self._attempts[batch.batch_id] = attempts
-            if attempts > self.retry.max_retries:
-                self._attempts.pop(batch.batch_id, None)
-                for req in batch.requests:
-                    self._store(RequestReceipt.make_shed(
-                        req, "fault:retries-exhausted", now))
-                return
-            self._sleep(self.retry.delay(attempts, token=batch.batch_id))
-            self._reassign(batch, exclude=worker, now=now)
+            exclude, why = error.workers, "fault:host-lost"
         else:
-            self._breaker(worker).record_success()
+            self._breaker(worker).record_failure(now)
+            exclude, why = worker, "fault:retries-exhausted"
+        attempts = self._attempts.get(batch.batch_id, 0) + 1
+        self._attempts[batch.batch_id] = attempts
+        if attempts > self.retry.max_retries:
+            self._attempts.pop(batch.batch_id, None)
+            for req in batch.requests:
+                self._store(RequestReceipt.make_shed(req, why, now))
+            return
+        self._sleep(self.retry.delay(attempts, token=batch.batch_id))
+        self._reassign(batch, exclude=exclude, now=now)
 
-    def _execute_batch(self, batch: Batch, worker: int, device: Any) -> None:
+    def _launch(self, batch: Batch, worker: int, device: Any) -> _InFlight:
+        """Build the batch on the device and enqueue its executable on the
+        worker's device, without waiting for either."""
         rung, reasons = self._batch_rung(batch)
         if (self.faults is not None
                 and self.faults.take(FAIL_PLAN_BUILD, batch_id=batch.batch_id,
@@ -707,11 +796,12 @@ class FFTService:
         # Span attributes (kind/shape/rung/clock) inherit to child spans;
         # the ledger capture rides the execution so a first-trace records
         # the shape's launch signature (repro.obs.ledger).
-        with self._span("batch", batch_id=batch.batch_id, worker=worker,
-                        kind=batch.key.kind,
-                        shape=batch.key.shape or (batch.key.n,),
-                        rung=rung, clock_mhz=point.f, rows=rows,
-                        requests=len(batch.requests)):
+        attrs = dict(batch_id=batch.batch_id, worker=worker,
+                     kind=batch.key.kind,
+                     shape=batch.key.shape or (batch.key.n,),
+                     rung=rung, clock_mhz=point.f, rows=rows,
+                     requests=len(batch.requests))
+        with self._span("batch", **attrs):
             # The batch is built on the device from the payloads submit
             # put there: zero-filled bucket planes, then one in-place write
             # per request at a traced row offset.  The programs are one
@@ -723,7 +813,8 @@ class FFTService:
             with self._span("pad", pad_rows=target - rows):
                 planes = self._bucket(batch, target)
             with self._span("stack", device_stacked=(
-                    0 if planes is None else len(batch.requests))):
+                    0 if planes is None else len(batch.requests)),
+                    d2d_bytes=_stray_bytes(batch, planes)):
                 x = self._stack(batch, planes)
             t_start = self._timer()
             ctx = (self.clock.locked(lock_f) if lock_f is not None
@@ -748,32 +839,46 @@ class FFTService:
                     raise DeviceLostError(worker)
                 with self._span("execute"), \
                         self.ledger.capture(key=batch.key):
-                    y = self._run(batch, entry, rung, x, device)
-                del x      # the stacked batch's memory, before the slices
+                    xd, y = self._run(batch, entry, rung, x, device)
+        return _InFlight(batch=batch, worker=worker, entry=entry,
+                         point=point, rung=rung,
+                         reason="; ".join(reasons) or None, rows=rows,
+                         attrs=attrs, x=xd, y=y,
+                         d2d_bytes=0 if xd is x else xd.nbytes,
+                         t_start=t_start)
+
+    def _finish(self, f: _InFlight) -> None:
+        """Wait for a launched batch (``h2d``: its placement, when traced;
+        ``compute``: its result), slice off the bucket pad and account
+        it, under a ``collect`` span with the batch span's attributes."""
+        with self._span("collect", **f.attrs):
+            with self._span("execute"):
+                with self._span("h2d", h2d_bytes=0, d2d_bytes=f.d2d_bytes):
+                    if self.tracer is not None:
+                        jax.block_until_ready(f.x)
+                f.x = None     # the stacked batch's memory, before the slices
+                with self._span("compute"):
+                    y = jax.block_until_ready(f.y)
+                f.y = None
             with self._span("slice"):
-                y = y[:rows]
+                y = y[:f.rows]
             t_done = self._timer()
             with self._span("account"):
-                self._account(batch, worker, entry, point, y, t_start,
-                              t_done, rung=rung,
-                              reason="; ".join(reasons) or None)
+                self._account(f.batch, f.worker, f.entry, f.point, y,
+                              f.t_start, t_done, rung=f.rung,
+                              reason=f.reason)
 
     def _run(self, batch: Batch, entry, rung: int, x: jax.Array,
-             device: Any) -> jax.Array:
-        """Place the stacked batch on the worker's device (``h2d``) and run
-        its executable to completion (``compute``).  The batch is already
-        on a device, so no host bytes cross the link here: a worker on
-        another device gets one device-to-device copy.  With a tracer
-        attached the batch is waited for inside ``h2d``, so the span times
-        the submit copies and device writes it depends on rather than
-        leaving them to ``compute``."""
+             device: Any) -> tuple[jax.Array, jax.Array]:
+        """Place the stacked batch on the worker's device and enqueue its
+        executable (``compute``); returns the placed batch and the result,
+        neither waited for.  The batch is already on a device, so no host
+        bytes cross the link here: a worker on another device (one that
+        stole or was handed the batch) gets one device-to-device copy."""
         sharded = (self.mesh is not None and batch.key.kind == KIND_FFT
                    and x.shape[0] > 1 and rung < RUNG_PURE_JAX)
-        with self._span("h2d", h2d_bytes=0):
-            xd = (x if sharded or x.devices() == {device}
-                  else jax.device_put(x, device))
-            if self.tracer is not None:
-                jax.block_until_ready(xd)
+        xd = (x if sharded or device is None or x.devices() == {device}
+              else jax.device_put(x, device))
         with self._span("compute"):
             if sharded:
                 from repro.fft.distributed import batch_parallel_fft
@@ -784,7 +889,7 @@ class FFTService:
                     y = self._rung2_fn(batch.key)(xd)
             else:
                 y = entry.fn(xd)
-            return jax.block_until_ready(y)
+        return xd, y
 
     def _store(self, receipt: RequestReceipt, *, key=None) -> None:
         jseq = getattr(receipt.request, "jseq", None)

@@ -10,8 +10,9 @@ import jax.numpy as jnp
 from repro.core.hardware import TPU_V5E
 from repro.runtime.faults import (CLOSED, FAIL_CLOCK_LOCK, FAIL_PLAN_BUILD,
                                   HALF_OPEN, KILL_DEVICE, OPEN, STALL_WORKER,
-                                  CircuitBreaker, DrainDeadlineError,
-                                  FaultEvent, FaultPlan, RetryPolicy)
+                                  CircuitBreaker, DeviceLostError,
+                                  DrainDeadlineError, FaultEvent, FaultPlan,
+                                  RetryPolicy)
 from repro.serving import (RUNG_BOOST_HEURISTIC, RUNG_PURE_JAX, SLO,
                            FFTService, SLOPolicy, max_rung_for_kind)
 
@@ -121,6 +122,29 @@ def test_device_lost_mid_batch_is_retried_no_request_lost():
                                rtol=1e-4, atol=1e-3)
     rep = svc.report()
     assert rep.retried == 3 and rep.availability == 1.0
+
+
+def test_device_lost_at_the_wait_is_retried_like_at_launch():
+    """A device error that surfaces while the launched batch is waited on
+    takes the launch's path: redistribution, then a retry."""
+    svc = service(n_workers=2)
+    finish = svc._finish
+    lost = []
+
+    def lose_first(f):
+        if not lost:
+            lost.append(f.worker)
+            raise DeviceLostError(f.worker)
+        return finish(f)
+
+    svc._finish = lose_first
+    reqs = [svc.submit(rand_complex((2, 256), jax.random.PRNGKey(i)))
+            for i in range(3)]
+    receipts = svc.drain()
+    assert len(receipts) == len(reqs)
+    assert all(r.status == "served" and r.retries == 1 for r in receipts)
+    rep = svc.report()
+    assert rep.redistributions == 1 and rep.retried == 3
 
 
 def test_retries_exhausted_sheds_with_receipts():

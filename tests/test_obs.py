@@ -489,9 +489,11 @@ class TestServingIntegration:
         for r in svc.receipts:
             assert [l.kernel for l in r.launches] == ["fft-c2c"]
             assert all(l.bytes_moved > 0 for l in r.launches)
-        # spans nested batch > execute with inherited attrs
+        # spans nested batch > execute (launch) and collect > execute
+        # (wait) with inherited attrs
         execs = [s for s in tracer.spans if s.name == "execute"]
-        assert execs and all(s.parent == "batch" for s in execs)
+        assert (sorted(s.parent for s in execs)
+                == ["batch"] * 4 + ["collect"] * 4)
         assert all(s.attrs["kind"] == "fft" for s in execs)
         rep = svc.report()
         assert rep.drift is not None and rep.drift["observations"] > 0
@@ -537,10 +539,13 @@ class TestServingIntegration:
 class TestServicePath:
     """The host path of one served batch as spans and byte counters."""
 
-    BATCH_CHILDREN = {"stack": "batch", "pad": "batch",
-                      "execute": "batch", "h2d": "execute",
-                      "compute": "execute", "slice": "batch",
-                      "account": "batch"}
+    #: (span, parent) of one served batch's steps: its launch under
+    #: ``batch``, its wait and accounting under ``collect``.
+    BATCH_CHILDREN = [("pad", "batch"), ("stack", "batch"),
+                      ("execute", "batch"), ("compute", "execute"),
+                      ("execute", "collect"), ("h2d", "execute"),
+                      ("compute", "execute"), ("slice", "collect"),
+                      ("account", "collect")]
 
     def _serve(self, payloads, *, tracer, bucket_batches=True):
         svc = FFTService(TPU_V5E, devices=jax.devices()[:1],
@@ -563,16 +568,50 @@ class TestServicePath:
         assert all(s.parent is None for s in by_name["submit"])
         (drain,) = by_name["drain"]
         (batch,) = by_name["batch"]
+        (collect,) = by_name["collect"]
         assert batch.parent == "drain" and drain.parent is None
+        assert collect.parent == "drain" and collect.attrs == batch.attrs
         assert batch.attrs["rows"] == 5 and batch.attrs["requests"] == 2
-        for name, parent in self.BATCH_CHILDREN.items():
-            (span,) = by_name[name]
-            assert span.parent == parent, name
-            assert span.attrs["batch_id"] == batch.attrs["batch_id"], name
+        steps = [s for s in tracer.spans
+                 if s.name not in ("submit", "drain", "batch", "collect")]
+        assert (sorted((s.name, s.parent) for s in steps)
+                == sorted(self.BATCH_CHILDREN))
+        for span in steps:
+            assert span.attrs["batch_id"] == batch.attrs["batch_id"], span
         assert by_name["pad"][0].attrs["pad_rows"] == 3
         assert by_name["stack"][0].attrs["device_stacked"] == 2
-        assert set(by_name) == {"submit", "drain", "batch",
-                                *self.BATCH_CHILDREN}
+        assert set(by_name) == {"submit", "drain", "batch", "collect",
+                                *(name for name, _ in self.BATCH_CHILDREN)}
+
+    def test_one_worker_device_waits_sit_inside_execute(self, monkeypatch):
+        """With one worker the batch's waits (its placement, traced, then
+        its result) each take one clock second inside ``execute``, in
+        ``h2d`` and ``compute``, and the host's other steps take none:
+        ``execute`` spans sum to the whole of the client's time, as when
+        launch and wait were one step."""
+        clock = [0.0]
+        block = jax.block_until_ready
+
+        def waiting(x):
+            clock[0] += 1.0
+            return block(x)
+
+        monkeypatch.setattr(jax, "block_until_ready", waiting)
+        tracer = Tracer(timer=lambda: clock[0])
+        payloads = [np.asarray(rand_complex((4, 64), jax.random.PRNGKey(i)))
+                    for i in range(2)]
+        t0 = clock[0]
+        self._serve(payloads, tracer=tracer)
+        host = clock[0] - t0
+
+        def total(name):
+            return sum(s.duration for s in tracer.spans if s.name == name)
+
+        assert host == 2.0
+        assert total("execute") == host
+        assert total("h2d") == 1.0 and total("compute") == 1.0
+        (collect,) = [s for s in tracer.spans if s.name == "collect"]
+        assert collect.duration == host
 
     @pytest.mark.parametrize("on_host,copies", [(True, 1), (False, 0)],
                              ids=["host-array", "device-array"])
