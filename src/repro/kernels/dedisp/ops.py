@@ -5,8 +5,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.common import SUBLANES, batch_tile, use_interpret
-from repro.kernels.dedisp.dedisp_kernel import dedisperse_pallas
+from repro.kernels.common import LANES, SUBLANES, batch_tile, use_interpret
+from repro.kernels.dedisp.dedisp_kernel import dedisperse_pallas, row_layout
 from repro.obs.ledger import record_launch
 
 
@@ -61,12 +61,14 @@ def dedisperse_kernel(fb: jax.Array, delays, *,
     for d in lead:
         b *= d
     x = fb.reshape(b, nchan, n)
-    # VMEM holds a (tile, tile_c, N) channel slab plus the (tile, D, N)
-    # output, both double-buffered; the batch tile is a leading block
-    # dimension (any size).  One sublane group of channels per step keeps
-    # the slab small whatever the channel count.
+    # VMEM holds a (tile, tile_c, rows_in, 128) channel slab plus the
+    # (tile, D, rows_out, 128) output, both double-buffered; the batch
+    # tile is a leading block dimension (any size).  One sublane group of
+    # channels per step keeps the slab small whatever the channel count.
+    ndm = len(static)
+    rows_out, rows_in, rows_t = row_layout(n, max(map(max, static)))
     tile_c = SUBLANES if nchan % SUBLANES == 0 else nchan
-    tile = min(batch_tile(n, 4, buffers=2 * (tile_c + len(static)),
+    tile = min(batch_tile(rows_in * LANES, 4, buffers=2 * (tile_c + ndm),
                           align=1), b)
     pad = (-b) % tile
     if pad:
@@ -74,8 +76,11 @@ def dedisperse_kernel(fb: jax.Array, delays, *,
     out = dedisperse_pallas(x, static, tile_b=tile, tile_c=tile_c,
                             interpret=interpret)[:b]
     padded = b + pad
+    # tile: the block (batch, channels, padded samples) and the time
+    # tile's rows of 128 lanes, so the ledger shows which layout ran.
     record_launch("dedisperse", grid=(padded // tile, nchan // tile_c),
-                  tile=(tile, tile_c, n),
-                  bytes_moved=4 * padded * n * (nchan + len(static)),
+                  tile=(tile, tile_c, rows_in * LANES, rows_t),
+                  bytes_moved=4 * padded * LANES * (nchan * rows_in
+                                                    + ndm * rows_out),
                   shape=(b, nchan, n))
-    return out.reshape(*lead, len(static), n)
+    return out.reshape(*lead, ndm, n)

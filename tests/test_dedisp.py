@@ -20,7 +20,8 @@ except ModuleNotFoundError:
 from repro.data.synthetic import (FilterbankSpec, InjectedPulsar,
                                   synthetic_filterbank)
 from repro.kernels.dedisp import dedisperse_kernel, dedisperse_ref
-from repro.kernels.dedisp.dedisp_kernel import dedisperse_pallas
+from repro.kernels.dedisp.dedisp_kernel import dedisperse_pallas, row_layout
+from repro.obs.ledger import LaunchLedger
 
 KEY = jax.random.PRNGKey(7)
 
@@ -115,6 +116,68 @@ class TestDedisperseParity:
         # the k0 bin dominates only on the matched (second) trial
         assert int(jnp.argmax(spec_pow[1])) == 200
         assert float(spec_pow[1, 200]) > 4 * float(spec_pow[0, 200])
+
+
+def _sequential_sum(fb, delays):
+    """The kernel's sum, in numpy: float32, channels 0..C-1 in order, a
+    sample at or past N reading 0."""
+    fb = np.asarray(fb, np.float32)
+    *lead, nchan, n = fb.shape
+    out = np.zeros((*lead, len(delays), n), np.float32)
+    for d, row in enumerate(delays):
+        for c in range(nchan):
+            s = int(row[c])
+            out[..., d, :n - s] += fb[..., c, s:]
+    return out
+
+
+#: Delays that cross the row boundaries of the kernel's (rows, 128) layout;
+#: a case keeps those below its N, and N - 1.
+_ROW_DELAYS = (0, 1, 127, 128, 129, 255, 1000)
+
+
+class TestDedisperseRowLayout:
+    """The kernel lays each channel's time axis as rows of 128 lanes and
+    splits a delay into rows and lanes: every output equals the same
+    float32 sum in the same channel order, bit for bit."""
+
+    @pytest.mark.parametrize("batch", [1, 3])
+    @pytest.mark.parametrize("ndm", [1, 5])
+    @pytest.mark.parametrize("nchan", [4, 8, 32])
+    @pytest.mark.parametrize("n", [64, 200, 1000, 2048])
+    def test_exact_against_sequential_sum(self, n, nchan, ndm, batch):
+        rng = np.random.default_rng(n * 1000 + nchan * 10 + ndm)
+        vals = [d for d in _ROW_DELAYS if d < n] + [n - 1]
+        delays = rng.permutation(np.resize(vals, ndm * nchan))
+        delays = delays.reshape(ndm, nchan)
+        fb = _rand_fb((batch, nchan, n), jax.random.PRNGKey(n + nchan))
+        got = dedisperse_kernel(fb, delays, interpret=True)
+        np.testing.assert_array_equal(np.asarray(got),
+                                      _sequential_sum(fb, delays))
+
+    @pytest.mark.parametrize("n,max_delay,layout", [
+        (2 ** 17, 252, (1024, 1032, 128)),   # the pulsar cell's block
+        (1000, 999, (8, 16, 8)),
+        (2048, 128, (16, 24, 16)),
+        (64, 0, (8, 16, 8)),
+    ])
+    def test_layout_follows_the_shapes(self, n, max_delay, layout):
+        """(output rows, input rows, time-tile rows): whole (8, 128)
+        tiles of output, room for the largest delay's row and its
+        successor, the largest power-of-two tile dividing the output."""
+        assert row_layout(n, max_delay) == layout
+
+    def test_launch_records_padded_length_and_time_tile(self):
+        fb = _rand_fb((2, 8, 1000))
+        delays = np.array([[0, 1, 2, 3, 4, 5, 6, 300]])
+        led = LaunchLedger()
+        with led.capture():
+            dedisperse_kernel(fb, delays, interpret=True)
+        (rec,) = led.records
+        rows_out, rows_in, rows_t = row_layout(1000, 300)
+        assert rec.kernel == "dedisperse"
+        assert rec.shape == (2, 8, 1000)
+        assert rec.tile == (2, 8, rows_in * 128, rows_t) == (2, 8, 2048, 8)
 
 
 class TestDedisperseGuards:

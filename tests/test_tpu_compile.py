@@ -130,12 +130,21 @@ def test_transpose(one_chip):
              ((4, 1024, 3000), C64))
 
 
-def test_dedisperse(one_chip):
+@pytest.mark.parametrize("table,ndm", [("linear", 16), ("plan", 16),
+                                        ("plan", 64)])
+def test_dedisperse(one_chip, table, ndm):
     """The pulsar phase's filterbanks: 1024 channels x 2^17 samples, 16
-    DMs (channel-tiled; the scoped-VMEM limit raised for the resident
-    (16, 2^17) output block)."""
+    DMs and the pulsar cell's own 64 (channel-tiled; the scoped-VMEM
+    limit raised for the resident (D, 2^17) output block).  ``plan`` is
+    the service's table, 4 samples of band delay a trial."""
+    from repro.data.synthetic import FilterbankSpec
     from repro.kernels.dedisp.ops import dedisperse_kernel
-    delays = np.arange(16)[:, None] * np.arange(1024)[None, :] // 64
+    from repro.search.pipeline import DispersionPlan
+    if table == "plan":
+        delays = DispersionPlan.from_spec(
+            FilterbankSpec(nchan=1024, ntime=2**17), n_trials=ndm).delays
+    else:
+        delays = np.arange(ndm)[:, None] * np.arange(1024)[None, :] // 64
     _compile(lambda fb: dedisperse_kernel(fb, delays, interpret=False),
              one_chip, ((2, 1024, 2**17), F32))
 
