@@ -1,5 +1,4 @@
 from repro.analysis.hlo import collective_bytes_from_hlo
-from repro.analysis.roofline import RooflineTerms, roofline_from_artifact
+from repro.analysis.roofline import RooflineTerms
 
-__all__ = ["collective_bytes_from_hlo", "RooflineTerms",
-           "roofline_from_artifact"]
+__all__ = ["collective_bytes_from_hlo", "RooflineTerms"]

@@ -1,4 +1,4 @@
-"""Roofline analysis per (arch x shape x mesh) from dry-run artifacts.
+"""Roofline terms per (arch x shape x mesh) dry-run cell.
 
   compute term    = HLO_FLOPs / (chips * peak_FLOP/s)
   memory term     = HLO_bytes / (chips * HBM_bw)
@@ -16,7 +16,6 @@ like the paper's per-FFT-length optimum.
 from __future__ import annotations
 
 import dataclasses
-import json
 
 from repro.configs.base import ArchConfig, ShapeSpec
 from repro.core.hardware import TPU_V5E, DeviceSpec
@@ -97,100 +96,3 @@ def model_flops_for(cfg: ArchConfig, shape: ShapeSpec) -> float:
     tokens = shape.global_batch                # one token per sequence
     return 2.0 * n * tokens
 
-
-def analytic_memory_bytes(cfg: ArchConfig, shape: ShapeSpec, chips: int
-                          ) -> dict[str, float]:
-    """First-principles HBM traffic per device per step (bytes).
-
-    The HLO-parsed byte count (recorded in the artifact) is a gross UPPER
-    bound: the CPU backend fuses at much finer granularity than TPU and
-    the parser cannot see in-place aliasing of donated cache/state
-    buffers.  This breakdown is the standard napkin-roofline accounting
-    instead; every component is listed so §Perf iterations can attack the
-    dominant one.
-    """
-    n_params = cfg.param_count()
-    tokens = shape.global_batch * (shape.seq_len
-                                   if shape.kind in ("train", "prefill")
-                                   else 1)
-    d, L, V = cfg.d_model, cfg.n_layers, cfg.vocab
-    out: dict[str, float] = {}
-
-    if shape.kind == "train":
-        out["weights_io"] = 3 * n_params * 2          # read fwd+bwd, write
-        out["optimizer_io"] = 24 * n_params           # grads + m/v, f32
-        out["activations_io"] = 3 * L * tokens * d * 2
-        out["logits_io"] = 4 * tokens * V * 4         # chunked CE fwd+bwd
-    elif shape.kind == "prefill":
-        out["weights_io"] = n_params * 2
-        out["activations_io"] = 2 * L * tokens * d * 2
-        out["logits_io"] = shape.global_batch * V * 4
-    else:
-        out["weights_io"] = n_params * 2
-        out["activations_io"] = 2 * L * shape.global_batch * d * 2
-
-    # attention-score traffic (jnp chunked flash materialises score chunks;
-    # the Pallas-flash §Perf optimisation removes this term)
-    s = shape.seq_len
-    if cfg.family in ("ssm",):
-        q = cfg.ssm.chunk
-        h = cfg.ssm.expand * d // cfg.ssm.head_dim
-        if shape.kind in ("train", "prefill"):
-            # L matrices (B, S/Q, H, Q, Q) f32 -> B*S*H*Q elements/pass
-            passes = 4 if shape.kind == "train" else 2
-            out["ssd_chunk_io"] = passes * L * shape.global_batch * s * q * h * 4
-    else:
-        n_attn = L
-        if cfg.family == "hybrid":
-            n_attn = cfg.n_layers // max(cfg.shared_attn_every, 1)
-        kv_len = s
-        if cfg.sliding_window and cfg.local_per_global:
-            # 5 of 6 layers see only the window
-            frac_local = cfg.local_per_global / (cfg.local_per_global + 1)
-            kv_len = (frac_local * cfg.sliding_window
-                      + (1 - frac_local) * s)
-        heads = cfg.n_heads
-        if shape.kind == "train":
-            out["attn_scores_io"] = (4 * n_attn * shape.global_batch
-                                     * heads * s * kv_len / 2 * 4)
-        elif shape.kind == "prefill":
-            out["attn_scores_io"] = (2 * n_attn * shape.global_batch
-                                     * heads * s * kv_len / 2 * 4)
-        else:
-            # decode: read the KV cache once per step
-            if cfg.mla is not None:
-                per_tok = cfg.mla.kv_lora_rank + cfg.mla.qk_rope_head_dim
-                out["kv_cache_io"] = L * shape.global_batch * s * per_tok * 2
-            else:
-                hd = cfg.resolved_head_dim
-                out["kv_cache_io"] = (n_attn * shape.global_batch * s
-                                      * 2 * cfg.n_kv_heads * hd * 2)
-    if cfg.family == "hybrid" and shape.kind == "decode":
-        hd = cfg.resolved_head_dim
-        n_sites = cfg.n_layers // max(cfg.shared_attn_every, 1)
-        out["kv_cache_io"] = (n_sites * shape.global_batch * s
-                              * 2 * cfg.n_kv_heads * hd * 2)
-
-    if cfg.moe is not None and shape.kind in ("train", "prefill"):
-        passes = 4 if shape.kind == "train" else 2
-        out["moe_dispatch_io"] = (passes * (L - cfg.n_dense_layers) * tokens
-                                  * cfg.moe.top_k * 1.25 * d * 2)
-
-    out["total"] = float(sum(out.values()))
-    return {k: v / chips for k, v in out.items()}
-
-
-def roofline_from_artifact(path: str) -> RooflineTerms:
-    from repro.configs import get_arch, get_shape
-    with open(path) as f:
-        a = json.load(f)
-    cfg = get_arch(a["arch"])
-    shape = get_shape(a["shape"])
-    mem = analytic_memory_bytes(cfg, shape, a["chips"])
-    return RooflineTerms(
-        arch=a["arch"], shape=a["shape"], mesh=a["mesh"],
-        chips=a["chips"], hlo_flops=a["flops_per_device"],
-        hbm_bytes=mem["total"],
-        collective_bytes=a["collective_bytes_per_device"],
-        model_flops=a["model_flops"],
-    )

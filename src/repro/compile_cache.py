@@ -1,8 +1,8 @@
 """Persistent compilation cache for the program's entry points.
 
-Entry points (``chip_smoke.py``, ``benchmarks/run.py``, ``examples/``)
-call :func:`enable_compile_cache` before their first compile.  Library
-modules and tests never do.
+Entry points (``chip_smoke.py``, ``examples/``) call
+:func:`enable_compile_cache` before their first compile.  Library modules
+and tests never do.
 
 Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
 module sets no other directory.  Otherwise the cache lives at the fixed

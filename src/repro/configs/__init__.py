@@ -1,4 +1,4 @@
-"""Config registry: ``--arch <id>`` resolution for launch/dryrun/train."""
+"""Config registry: ``--arch <id>`` resolution for launch/dryrun."""
 from repro.configs.base import (ALL_SHAPES, DECODE_32K, LONG_500K,
                                 PREFILL_32K, TRAIN_4K, ArchConfig, MLAConfig,
                                 MoEConfig, ShapeSpec, SSMConfig, shapes_for)

@@ -121,8 +121,7 @@ def energy_per_transform(result: SweepResult, n_transforms: int
 
     The sweep models a memory-budget-sized batch of ``n_transforms``
     transforms (Eq. 6); energy and time are linear in the count, so
-    per-transform figures are exact divisions.  This is the J/transform
-    proxy the ``fft`` benchmark target persists — an R2C sweep at the same
+    per-transform figures are exact divisions.  An R2C sweep at the same
     N carries ~2x the transforms per batch at ~the same batch energy,
     which is exactly the paper's Eq. 5/6 argument for real inputs.
     """

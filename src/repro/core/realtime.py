@@ -48,21 +48,3 @@ def extra_hardware(slowdown: float, margin: float = 0.0) -> float:
 def devices_required(n_devices: int, slowdown: float, margin: float = 0.0) -> int:
     """Integer device count after applying :func:`extra_hardware`."""
     return math.ceil(n_devices * (1.0 + extra_hardware(slowdown, margin)))
-
-
-@dataclasses.dataclass(frozen=True)
-class CostModel:
-    """Operational vs capital cost trade-off (Sec. 6.1, 'language of costs')."""
-
-    device_cost: float              # capital cost per device [currency]
-    energy_cost: float = 0.25       # electricity [currency/kWh]
-    years: float = 5.0              # amortisation horizon
-
-    def operating_cost(self, avg_power_w: float, n_devices: int) -> float:
-        kwh = avg_power_w / 1000.0 * 24 * 365 * self.years * n_devices
-        return kwh * self.energy_cost
-
-    def total_cost(self, avg_power_w: float, n_devices: int) -> float:
-        return self.device_cost * n_devices + self.operating_cost(
-            avg_power_w, n_devices
-        )

@@ -1,10 +1,10 @@
-"""Seeded request arrival-time distributions for serving benchmarks.
+"""Seeded request arrival times for driving the service in waves.
 
 Real edge telescopes do not deliver work on a fixed grid: channelised
 voltage dumps and candidate follow-ups arrive as a point process.  The
-crash-and-recover harness (``benchmarks/run.py recovery``) drives the
-service from one of two classic processes, both fully seeded so any two
-runs of the same schedule see bit-identical arrival times:
+crash-recovery tests (``tests/test_recovery.py``) drive the service from
+one of two classic processes, both fully seeded so any two runs of the
+same schedule see bit-identical arrival times:
 
   poisson   exponential inter-arrival gaps — the memoryless baseline
             (counts per drain window are Poisson-distributed, so wave

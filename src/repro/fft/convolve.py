@@ -29,8 +29,8 @@ Three cost levers, mirroring the rest of the FFT substrate:
   standalone multiply passes (the fallback path pays one XLA multiply).
 
 ``conv_plan`` exposes the pass/traffic accounting (overlap-save vs the
-direct pad-to-full-length plan) that ``core.workloads.conv_workload`` and
-``benchmarks/run.py fdas`` consume.
+direct pad-to-full-length plan) that ``core.workloads.conv_workload``
+consumes.
 """
 from __future__ import annotations
 
@@ -117,7 +117,7 @@ class ConvPlan:
     segment batch: the fused multiply epilogue keeps the forward side at
     ONE pass regardless of T, and each template's plane pays one inverse
     pass.  ``traffic_ratio`` is direct-method bytes over overlap-save
-    bytes — the before/after figure ``BENCH_fdas.json`` persists.
+    bytes.
     """
 
     n: int                      # input points per row
@@ -136,12 +136,6 @@ class ConvPlan:
     @property
     def traffic_ratio(self) -> float:
         return self.direct_bytes / self.os_bytes
-
-    @property
-    def passes_per_template(self) -> float:
-        """Amortised kernel passes each template costs (forward shared)."""
-        return self.inverse_passes / self.templates + (
-            self.forward_passes / self.templates)
 
 
 def conv_plan(n: int, taps: int, templates: int = 1, nfft: int = 0,

@@ -1,1 +1,1 @@
-"""Launch package: production mesh, dry-run, train and serve drivers."""
+"""Launch package: production mesh and the dry-run driver."""

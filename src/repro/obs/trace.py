@@ -3,8 +3,8 @@
 The :class:`Tracer` mirrors the repository's injectable-clock idiom
 (``tune.timing.time_fn``'s ``timer=`` / the tests' FakeTimer): span
 timestamps come from whatever monotonic callable the caller provides, so
-a trace driven by a fake timer is bit-reproducible — the property the
-``obs`` benchmark gates with a blake2b digest over two fresh runs.
+a trace driven by a fake timer is bit-reproducible — the property
+``tests/test_obs.py`` checks with a blake2b digest over two fresh runs.
 
 Spans nest via a context-manager stack and *inherit* their parent's
 attributes (``kind``/``shape``/``rung``/``clock_mhz`` set on a batch

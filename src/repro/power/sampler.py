@@ -18,7 +18,7 @@ This container has no power sensor, so CI runs
 *current* clock and utilisation, plus deterministic seeded measurement
 noise and a bounded thermal-drift term.  Sensor faults (dropout / spike /
 stale) are injected from the same deterministic
-:class:`repro.runtime.faults.FaultPlan` machinery the chaos harness uses,
+:class:`repro.runtime.faults.FaultPlan` machinery the serving faults use,
 so a seeded run reproduces the exact same telemetry stream bit for bit.
 """
 from __future__ import annotations

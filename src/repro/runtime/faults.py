@@ -165,8 +165,8 @@ class HostLostError(DeviceLostError):
 class ProcessCrashError(FaultError):
     """The serving process itself dies (kill -9, OOM, power cut).
 
-    No in-process handler can catch a real one — the chaos harness
-    *simulates* it by abandoning the live service object mid-stream and
+    No in-process handler can catch a real one — tests *simulate* it
+    by abandoning the live service object mid-stream and
     rebuilding from the write-ahead journal
     (``FFTService.recover``, repro.serving.recovery).
     """
@@ -278,30 +278,6 @@ class FaultPlan:
         return sum(1 for ev in self.fired
                    if kind is None or ev.kind == kind)
 
-    def drop_consumed(self, *, batch_before: int | None = None,
-                      arrival_before: int | None = None) -> int:
-        """Discard events a *previous incarnation* already consumed.
-
-        After a process crash the recovering harness regenerates the same
-        seeded plan, then drops every event pinned to a batch id below
-        the journal-restored ``_next_batch_id`` (all earlier batches were
-        polled for every kind, so their pinned events fired before the
-        crash) or to an arrival seq already admitted.  Returns the number
-        dropped.  Dropped events are *not* added to ``fired`` — they
-        fired in another incarnation's plan object; callers that need
-        cross-incarnation fired totals sum per-incarnation counts.
-        """
-        def consumed(ev: FaultEvent) -> bool:
-            if (batch_before is not None and ev.batch_id is not None
-                    and ev.batch_id < batch_before):
-                return True
-            return (arrival_before is not None and ev.arrival is not None
-                    and ev.arrival < arrival_before)
-
-        before = len(self.events)
-        self.events = [ev for ev in self.events if not consumed(ev)]
-        return before - len(self.events)
-
     @classmethod
     def generate(
         cls,
@@ -326,8 +302,7 @@ class FaultPlan:
         ``ensure_one_of_each`` additionally pins one of each execution
         fault (kill, clock-lock failure, stall) — and, when the run is
         long enough, one of each telemetry sensor fault — onto the
-        earliest batch ids so even tiny runs satisfy the chaos harness's
-        non-trivial-plan requirement.
+        earliest batch ids so even tiny runs see every kind fire.
 
         ``crash_arrivals`` / ``host_kill_batches`` pin CRASH_PROCESS
         events on journal arrival seqs and KILL_HOST events on batch ids.
@@ -409,8 +384,8 @@ class CircuitBreaker:
       open       quarantined; no traffic until ``cooldown_s`` elapses
       half-open  one probe admitted; success -> closed, failure -> open
 
-    Timestamps come from the caller's timer so tests and the chaos
-    harness can drive the state machine with fake clocks.
+    Timestamps come from the caller's timer so tests can drive the
+    state machine with fake clocks.
     """
 
     failure_threshold: int = 2

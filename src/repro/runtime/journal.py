@@ -7,8 +7,7 @@ serving layer's in-memory state (pending requests, receipts, breaker and
 watchdog health) evaporates with the process; this module is the durable
 record it is rebuilt from.
 
-Design — a classic write-ahead log, sized for the chaos harness's 10^6
-request streams:
+Design — a classic write-ahead log, sized for streams of 10^6 requests:
 
   records     one JSON object per line.  Every record carries a
               monotonically increasing sequence number ``seq`` (the
@@ -107,12 +106,12 @@ _PROCESS_INCARNATION: str | None = None
 
 
 def process_incarnation() -> str:
-    """A memoised id for THIS process incarnation (benchmark envelopes).
+    """A memoised id for THIS process incarnation.
 
     Journal-attached services stamp receipts with the journal's own
-    deterministic incarnation counter; artifacts emitted by journal-less
-    processes (most ``BENCH_*.json``) carry this process-level id so any
-    two artifacts can be told apart by which incarnation produced them.
+    deterministic incarnation counter; a journal-less process can stamp
+    what it emits with this process-level id, so any two outputs can be
+    told apart by which incarnation produced them.
     """
     global _PROCESS_INCARNATION
     if _PROCESS_INCARNATION is None:
@@ -251,7 +250,7 @@ class RequestJournal:
         self._warn = log if log is not None else get_logger("journal").warning
         # With a ``record_sink`` replay streams each validated record to
         # the callback and retains nothing (O(1) journal memory at any
-        # history length — the 10^6-request harness recovers this way);
+        # history length);
         # without one, validated records collect in ``recovered``.
         self._sink = record_sink
         os.makedirs(path, exist_ok=True)
@@ -344,7 +343,7 @@ class RequestJournal:
         self._close_segment(fsync=True)
 
     def crash(self) -> None:
-        """Simulate the owning process dying (chaos-harness hook).
+        """Simulate the owning process dying (test hook).
 
         The active segment is abandoned WITHOUT a durability barrier —
         no fsync, no rotation seal — exactly the on-disk state a

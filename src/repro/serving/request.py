@@ -266,7 +266,7 @@ class RequestReceipt:
 
     @property
     def outcome(self) -> str:
-        """"served" | "retried" | "shed" — the chaos-harness taxonomy."""
+        """"served" | "retried" | "shed"."""
         if self.status == "shed":
             return "shed"
         return "retried" if self.retries > 0 else "served"

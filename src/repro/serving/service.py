@@ -18,9 +18,9 @@ Request lifecycle (docs/serving.md walks through a full example):
                (measured) and energy at the locked vs boost clock
                (modelled, Eqs. 3-4)
 
-The energy numbers come from the repository's analytic model — the same
-model the benchmarks validate against the paper — because this container
-has no power sensor.  An optional ``telemetry`` bundle
+The energy numbers come from the repository's analytic model
+(repro.core), because a TPU v5e gives JAX neither a power sensor nor a
+clock lock.  An optional ``telemetry`` bundle
 (repro.power.FleetTelemetry) adds a *measured* energy estimate next to
 the modelled one: each executed batch takes one watchdog-classified
 power sample, and receipts carry ``measured_energy_j`` priced at the
@@ -225,8 +225,8 @@ class FFTService:
     batch locks to, what it costs); execution runs on the host's actual
     JAX devices.  ``mesh`` (optional) shards plain-FFT batches over every
     mesh device via repro.fft.distributed instead of placing them whole.
-    ``coalesce_requests=False`` disables batching (every request executes
-    alone) — the naive baseline the benchmarks compare against.
+    ``coalesce_requests=False`` disables batching: every request executes
+    alone, as its own batch.
     """
 
     def __init__(
@@ -247,16 +247,11 @@ class FFTService:
         timer=time.monotonic,
         slo: SLOPolicy | None = None,
         fault_plan: FaultPlan | None = None,
-        retry_policy: RetryPolicy | None = None,
         breaker_threshold: int = 2,
         breaker_cooldown_s: float = 0.05,
-        drain_deadline_s: float | None = None,
         sleep_fn: Callable[[float], None] | None = None,
         telemetry=None,
         tracer=None,
-        metrics: MetricsRegistry | None = None,
-        ledger: LaunchLedger | None = None,
-        drift: DriftDetector | None = None,
         journal=None,
         topology: HostTopology | None = None,
     ):
@@ -301,8 +296,7 @@ class FFTService:
         self.admission = (AdmissionController(slo, device_spec)
                           if slo is not None else None)
         self.faults = fault_plan
-        self.retry = retry_policy if retry_policy is not None else RetryPolicy()
-        self.drain_deadline_s = drain_deadline_s
+        self.retry = RetryPolicy()
         # Backoff sleeps are computed deterministically but not actually
         # slept by default — the cooperative drain loop would only be
         # blocking itself.  Threaded deployments pass time.sleep.
@@ -328,9 +322,9 @@ class FFTService:
         # reproducible traces).  The drift detector only accumulates when
         # telemetry hands back watchdog-fresh power samples.
         self.tracer = tracer
-        self.metrics = metrics if metrics is not None else MetricsRegistry()
-        self.ledger = ledger if ledger is not None else LaunchLedger()
-        self.drift = drift if drift is not None else DriftDetector()
+        self.metrics = MetricsRegistry()
+        self.ledger = LaunchLedger()
+        self.drift = DriftDetector()
         # --- crash consistency (repro.runtime.journal, optional) ----------
         # With a journal attached every admit/assign/terminal transition is
         # logged write-ahead; the journal seq of the admit record is the
@@ -480,10 +474,10 @@ class FFTService:
         With an ``slo`` policy the admission controller runs first: shed
         requests terminate immediately in a ``status="shed"`` receipt
         (with the reason), pressure-degraded ones carry their forced
-        rung into execution.  ``deadline_s`` (default: the service's
-        ``drain_deadline_s``) bounds the drain loop on the service timer
-        so a wedged worker surfaces a DrainDeadlineError naming the
-        stuck shapes instead of looping forever.
+        rung into execution.  ``deadline_s`` (default: none) bounds the
+        drain loop on the service timer so a wedged worker surfaces a
+        DrainDeadlineError naming the stuck shapes instead of looping
+        forever.
 
         If a batch fails mid-cycle, already-served requests keep their
         receipts and every unserved request is re-queued for the next
@@ -495,8 +489,6 @@ class FFTService:
             pending, self._pending = self._pending, []
             if not pending:
                 return []
-            deadline = (deadline_s if deadline_s is not None
-                        else self.drain_deadline_s)
             serve = pending
             if self.admission is not None:
                 serve = []
@@ -534,7 +526,7 @@ class FFTService:
                         self.dispatcher.submit(batch)
                     self.dispatcher.drain(self._execute, self._collect,
                                           timer=self._timer,
-                                          deadline_s=deadline)
+                                          deadline_s=deadline_s)
             except BaseException:
                 self.dispatcher.clear()          # drop stale queued batches
                 self._inflight.clear()

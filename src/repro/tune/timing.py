@@ -1,10 +1,10 @@
 """Shared warm-up/repeat wall-clock timing — one methodology everywhere.
 
-The benchmark harness (``benchmarks/run.py``) and the autotuner must time
-kernels *identically*, or "speedup vs heuristic" claims compare apples to
-oranges.  Both call :func:`time_fn`: warm-up calls first (JIT compilation
-and cache priming are not the steady state), then ``repeats`` timed calls
-reduced with ``reduce`` (default ``min`` — best-of-n is robust to
+The autotuner times the heuristic and every candidate *identically*, or
+"speedup vs heuristic" compares apples to oranges: each goes through
+:func:`time_fn`, warm-up calls first (JIT compilation and cache priming
+are not the steady state), then ``repeats`` timed calls reduced with
+``reduce`` (default ``min`` — best-of-n is robust to
 scheduler noise on shared CPUs; pass ``statistics.median``/``mean`` for
 other conventions).
 

@@ -19,8 +19,8 @@ The search is staged so measurement stays cheap:
      model prices neither the tile nor the split, so today every FFT
      candidate ties and the sort keeps generation order.
   3. **Measure survivors** with the shared warm-up/repeat methodology
-     (:func:`repro.tune.timing.time_fn` — identical to the benchmark
-     harness), always including the heuristic config.
+     (:func:`repro.tune.timing.time_fn`), always including the
+     heuristic config.
   4. **Score**: ``time`` = measured wall; ``energy`` = model power at the
      workload's DVFS-optimal clock x measured wall (J/call).  Whatever
      the objective, a config that measures *slower* than the heuristic is

@@ -349,3 +349,43 @@ def _chaos_outcomes(seed):
 
 def test_same_fault_seed_reproduces_outcomes():
     assert _chaos_outcomes(5) == _chaos_outcomes(5)
+
+
+# ---------------------------------------------------------------------------
+# a mixed stream under a generated fault plan and an admission cap
+# ---------------------------------------------------------------------------
+
+def _mixed_payload(i):
+    """Request ``i`` of a mixed c2c / r2c / 2-D stream: payload, kwargs."""
+    key = jax.random.PRNGKey(1000 + i)
+    if i % 11 == 5:
+        return rand_complex((2, 32, 32), key), {"ndim": 2}
+    if i % 7 == 3:
+        return (jax.random.normal(key, (1 + i % 2, 512)),
+                {"transform": "r2c"})
+    return rand_complex((1 + i % 4, (256, 512)[i % 2]), key), {}
+
+
+def test_mixed_fault_stream_keeps_availability():
+    """Every pinned fault kind fires on a mixed stream; every request ends
+    in exactly one receipt, and availability (admission sheds excluded)
+    stays at or above 0.99 while the admission cap does shed."""
+    plan = FaultPlan.generate(0, n_batches=64, stall_duration_s=0.0)
+    policy = SLOPolicy(default=SLO(max_queue_transforms=16))
+    svc = service(n_workers=2, fault_plan=plan, slo=policy,
+                  timer=FakeTimer(dt=1e-4))
+    reqs, receipts = [], []
+    for wave in range(6):
+        for i in range(8 * wave, 8 * wave + 8):
+            x, kwargs = _mixed_payload(i)
+            reqs.append(svc.submit(x, **kwargs))
+        receipts.extend(svc.drain())
+
+    assert sorted(r.request.request_id for r in receipts) == sorted(
+        q.request_id for q in reqs)
+    assert all(svc.receipt(q) is not None for q in reqs)
+    for kind in (KILL_DEVICE, FAIL_CLOCK_LOCK, STALL_WORKER):
+        assert plan.fired_count(kind) >= 1, kind
+    rep = svc.report()
+    assert rep.shed > rep.fault_shed          # the admission cap did shed
+    assert rep.availability >= 0.99, rep.availability

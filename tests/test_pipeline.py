@@ -234,7 +234,7 @@ class TestStagePlanning:
         assert all(c in grid for c in sp.locked.values())
         assert len(sp.report.stages) == 4
         assert all(s.energy > 0 and s.time > 0 for s in sp.report.stages)
-        assert sp.realtime_margin > 0
+        assert sp.realtime_margin >= 1.0        # real time at the locks
         assert sp.t_acquire == pytest.approx(SPEC.t_acquire)
 
     def test_total_profile_covers_stage_sum(self):

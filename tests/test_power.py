@@ -301,9 +301,9 @@ class TestSite:
         b.run(40, dt=0.1)
         assert a.digest() == b.digest()
 
-    def test_sensor_storm_engages_exact_fallback_then_rearms(self):
-        plan = FaultPlan(events=[FaultEvent(SENSOR_SPIKE, batch_id=k,
-                                            worker=0)
+    @staticmethod
+    def _storm_engages_exact_fallback_then_rearms(kind):
+        plan = FaultPlan(events=[FaultEvent(kind, batch_id=k, worker=0)
                                  for k in range(10, 14)])
         site = make_site(fault_plan=plan)
         ticks = site.run(30, dt=0.1)
@@ -311,6 +311,13 @@ class TestSite:
         assert fb, "governor never fell back under the sensor storm"
         assert all(ticks[k].clocks_mhz[0] == FALLBACK for k in fb)
         assert ticks[-1].health[0] == HEALTHY    # re-armed after recovery
+
+    def test_sensor_storm_engages_exact_fallback_then_rearms(self):
+        self._storm_engages_exact_fallback_then_rearms(SENSOR_SPIKE)
+
+    @pytest.mark.parametrize("kind", [SENSOR_DROPOUT, SENSOR_STALE])
+    def test_other_sensor_storms_engage_exact_fallback(self, kind):
+        self._storm_engages_exact_fallback_then_rearms(kind)
 
     def test_shed_order_is_lowest_priority_first(self):
         # A cap whose budget (headroom * cap = 368 W) cannot hold all

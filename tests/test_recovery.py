@@ -6,11 +6,12 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core.hardware import TPU_V5E
+from repro.data.arrivals import arrival_times, wave_slices
 from repro.obs.ledger import launches_digest
 from repro.power import FleetTelemetry
 from repro.power.governor import PowerGovernor
-from repro.runtime.faults import (KILL_HOST, OPEN, FaultEvent, FaultPlan,
-                                  HostTopology)
+from repro.runtime.faults import (CRASH_PROCESS, KILL_HOST, OPEN,
+                                  FaultEvent, FaultPlan, HostTopology)
 from repro.runtime.journal import (ADMIT, SERVED, SHED, JournalRecord,
                                    RequestJournal, read_journal)
 from repro.runtime.journal import OPEN as J_OPEN
@@ -187,6 +188,77 @@ def test_recovered_service_matches_uncrashed_launches_digest(tmp_path):
     assert d_clean == d_crash
     for a, b in zip(r_clean, r_crash):
         assert (a.status, a.rung, a.reason) == (b.status, b.rung, b.reason)
+
+
+# ---------------------------------------------------------------------------
+# crashes mid-wave under seeded arrivals leave the outcomes unchanged
+# ---------------------------------------------------------------------------
+
+def _arrival_run(jdir, waves, crash_arrivals):
+    """Serve ``waves`` of payload refs under a generated fault plan,
+    recovering from the journal at each crash arrival.  Returns
+    ({ref: (status, outcome, rung, reason)}, counts of replays,
+    mismatched replays and re-executions, crashes)."""
+    plan = FaultPlan.generate(0, n_batches=64, stall_duration_s=0.0,
+                              crash_arrivals=crash_arrivals)
+    svc = service(RequestJournal(jdir), fault_plan=plan)
+    outcomes, counts = {}, {"replays": 0, "mismatches": 0, "reexecuted": 0}
+
+    def collect(receipts):
+        for r in receipts:
+            t = (r.status, r.outcome, r.rung, r.reason)
+            prev = outcomes.setdefault(r.request.payload_ref, t)
+            if prev is t:
+                continue
+            if not r.recovered:
+                counts["reexecuted"] += 1
+            elif prev == t:
+                counts["replays"] += 1
+            else:
+                counts["mismatches"] += 1
+
+    crashes = 0
+    for start, stop in waves:
+        for i in range(start, stop):
+            if plan.take(CRASH_PROCESS, arrival=i) is not None:
+                svc.journal.crash()          # kill -9 mid-wave
+                crashes += 1
+                svc = recover(jdir, fault_plan=plan,
+                              payload_fn=lambda ref, meta: PAYLOADS[ref % 8])
+                collect(svc.recovered_receipts)
+            svc.submit(PAYLOADS[i % 8], payload_ref=i)
+        collect(svc.drain())
+        svc.snapshot()
+    svc.journal.close()
+    return outcomes, counts, crashes
+
+
+@pytest.mark.parametrize("process", ["poisson", "gamma"])
+def test_crashes_under_arrivals_keep_outcomes(tmp_path, process):
+    """Two crashes in a Poisson or a bursty Gamma arrival stream: no
+    receipt lost or re-executed, every replay equal to the live receipt, the
+    journal closed with one terminal per admit, availability at or above
+    0.99, and each request's outcome equal to an uncrashed run's."""
+    n = 24
+    waves = list(wave_slices(
+        arrival_times(n, seed=1, process=process, rate_hz=1e3), 4e-3))
+    crashed, counts, crashes = _arrival_run(
+        str(tmp_path / "crash"), waves, crash_arrivals=(n // 3, 2 * n // 3))
+    clean, _, _ = _arrival_run(str(tmp_path / "clean"), waves, ())
+
+    assert crashes == 2 and len(waves) > 2
+    assert sorted(crashed) == list(range(n))
+    assert counts["reexecuted"] == counts["mismatches"] == 0
+    assert counts["replays"] > 0
+    audit = ReplayResult(retain=0)
+    read_journal(str(tmp_path / "crash"), sink=audit.feed)
+    assert audit.admits_total == audit.terminals_total == n
+    assert audit.open_admits == [] and audit.duplicate_terminals == 0
+    served = sum(t[0] == "served" for t in crashed.values())
+    fault_shed = sum(t[0] == "shed" and str(t[3]).startswith("fault:")
+                     for t in crashed.values())
+    assert served / (served + fault_shed) >= 0.99
+    assert crashed == clean
 
 
 # ---------------------------------------------------------------------------
