@@ -67,7 +67,8 @@ class CacheEntry:
 
     key: ShapeKey
     plan: FFTPlan | Any | None  # NDPlan for N-D; DispersionPlan for pulsar
-    fn: Callable                # jitted executable for the shape
+    fn: Callable                # jitted executable for the shape (of a
+    #                             c2c batch's Planes)
     profile: WorkloadProfile    # analytic workload model of one full batch
     sweep: dvfs.SweepResult     # full clock-grid sweep for ``profile``
     n_fft_model: int            # transforms the modelled batch contains
@@ -244,7 +245,13 @@ class PlanSweepCache:
             plan = self._plan_fn(key.n)
         else:
             plan = self._plan_fn(key.n, key.transform)
-        fn = jax.jit(plan.fn)
+        if key.transform == "c2c":
+            # The service hands a complex batch over as its planes: joined
+            # inside the executable, where the kernel's split of the same
+            # array cancels the join.
+            fn = jax.jit(lambda planes, _fn=plan.fn: _fn(planes.join()))
+        else:
+            fn = jax.jit(plan.fn)
         case = FFTCase(n=0 if key.shape else key.n, precision=key.precision,
                        batch_bytes=self.batch_bytes,
                        transform=key.transform,

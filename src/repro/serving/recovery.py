@@ -496,10 +496,8 @@ def recover_service(
     passed, else unbounded.  Older terminals stay durable in the journal
     either way — only eager reconstruction is windowed.
     """
-    import jax.numpy as jnp
-
     from repro.serving.request import FFTRequest
-    from repro.serving.service import FFTService
+    from repro.serving.service import FFTService, _to_device
 
     if retain_receipts is None:
         retain_receipts = service_kwargs.get("max_retained_receipts")
@@ -543,7 +541,7 @@ def recover_service(
                 stub, "recovery:payload-unresolvable", now))
             continue
         req = FFTRequest(
-            x=jnp.asarray(payload_fn(meta.get("payload_ref"), meta)),
+            x=_to_device(payload_fn(meta.get("payload_ref"), meta)),
             precision=meta["precision"], kind=meta["kind"],
             latency_budget=meta["latency_budget"],
             n_harmonics=meta["n_harmonics"],

@@ -11,6 +11,10 @@ import dataclasses
 import itertools
 from typing import Any
 
+import jax
+import numpy as np
+from jax import lax
+
 from repro.core.workloads import COMPLEX_BYTES, is_pow2
 
 _REQUEST_IDS = itertools.count()
@@ -73,6 +77,57 @@ class ShapeKey:
         return full
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class Planes:
+    """A complex array held on the device as its real and imaginary
+    float planes.  The TPU keeps a complex element as one 64-bit word, so
+    every split or join of a complex array is a pass over it; a complex
+    payload stays in planes from the link to the executable, which joins
+    them where the FFT kernel splits them again, and XLA cancels the two.
+
+    It reads as the complex array it holds: ``shape``, ``dtype`` and
+    ``nbytes`` are the complex array's, ``np.asarray`` joins it on the
+    host and ``jnp.asarray`` on the device.  A pytree, so ``jax.jit``,
+    ``jax.device_put`` and ``jax.block_until_ready`` take it whole."""
+
+    re: jax.Array
+    im: jax.Array
+
+    @property
+    def shape(self) -> tuple[int, ...]:
+        return self.re.shape
+
+    @property
+    def ndim(self) -> int:
+        return self.re.ndim
+
+    @property
+    def dtype(self) -> np.dtype:
+        return np.result_type(self.re.dtype, np.complex64)
+
+    @property
+    def nbytes(self) -> int:
+        return self.re.nbytes + self.im.nbytes
+
+    def devices(self) -> set:
+        return self.re.devices()
+
+    def block_until_ready(self) -> "Planes":
+        return jax.block_until_ready(self)
+
+    def join(self) -> jax.Array:
+        """The complex array, formed on the planes' device."""
+        return lax.complex(self.re, self.im)
+
+    __jax_array__ = join
+
+    def __array__(self, dtype=None, copy=None) -> np.ndarray:
+        out = np.empty(self.shape, self.dtype)
+        out.real, out.imag = np.asarray(self.re), np.asarray(self.im)
+        return out if dtype is None else out.astype(dtype)
+
+
 @dataclasses.dataclass
 class FFTRequest:
     """One client submission: ``x`` rows are independent transforms.
@@ -83,7 +138,9 @@ class FFTRequest:
     engine (one fused pass per pow2 axis).
     """
 
-    x: Any                               # (batch, *shape) or (*shape,) array
+    # (batch, *shape) or (*shape,) array; Planes once submit put a
+    # complex payload on the device
+    x: Any
     precision: str = "fp32"
     kind: str = KIND_FFT
     latency_budget: float | None = None  # max tolerable slowdown vs boost

@@ -68,7 +68,8 @@ from repro.serving.batcher import Batch, coalesce
 from repro.serving.cache import CacheStats, PlanSweepCache
 from repro.serving.dispatch import Dispatcher
 from repro.serving.request import (KIND_FFT, KIND_PULSAR, FFTRequest,
-                                   RequestReceipt, ShapeKey, StageReceipt)
+                                   Planes, RequestReceipt, ShapeKey,
+                                   StageReceipt)
 from repro.serving.slo import (RUNG_BOOST_HEURISTIC, RUNG_PURE_JAX,
                                RUNG_TUNED_DVFS, SHED, SLOPolicy,
                                AdmissionController, max_rung_for_kind)
@@ -95,26 +96,32 @@ def _exec_dtype(key: ShapeKey) -> np.dtype:
 
 
 @jax.jit
-def _complex_from_pairs(v: jax.Array) -> jax.Array:
-    """The complex array whose (real, imaginary) pairs ``v`` holds along
-    its last axis: a host complex array's real view, joined on the
-    device."""
-    return lax.complex(v[..., 0::2], v[..., 1::2])
+def _deinterleave(v: jax.Array) -> Planes:
+    """The planes of the complex array whose (real, imaginary) pairs
+    ``v`` holds along its last axis: a host complex array's real view."""
+    return Planes(v[..., 0::2], v[..., 1::2])
 
 
-def _to_device(x: Any, device: Any = None) -> jax.Array:
+@jax.jit
+def _split(x: jax.Array) -> Planes:
+    return Planes(jnp.real(x), jnp.imag(x))
+
+
+def _to_device(x: Any, device: Any = None) -> jax.Array | Planes:
     """``x`` on ``device`` (a host array; the default device when None),
-    or where it is (a device array).  A host complex array crosses the
-    link as its real view (the same bytes) and is joined on the device:
-    copied as complex, it is transposed on the host in small chunks, and
-    a profiler records each of them."""
+    or where it is (a device array); a complex ``x`` as its planes.  A
+    host complex array crosses the link as its real view (the same bytes)
+    and is split into planes on the device: copied as complex, it is
+    transposed on the host in small chunks, and a profiler records each
+    of them."""
     if isinstance(x, jax.Array):
-        return jnp.asarray(x)
+        x = jnp.asarray(x)
+        return _split(x) if jnp.iscomplexobj(x) else x
     xh = np.asarray(x)
-    if xh.ndim == 0 or not np.iscomplexobj(xh):
+    if not np.iscomplexobj(xh):
         return jnp.asarray(xh, device=device)
     pairs = np.ascontiguousarray(xh).view(np.finfo(xh.dtype).dtype)
-    return _complex_from_pairs(jnp.asarray(pairs, device=device))
+    return _deinterleave(jnp.asarray(pairs, device=device))
 
 
 def _stray_bytes(batch: Batch, planes: tuple | None) -> int:
@@ -127,20 +134,18 @@ def _stray_bytes(batch: Batch, planes: tuple | None) -> int:
 
 
 @functools.partial(jax.jit, donate_argnums=0)
-def _write_rows(planes: tuple, x: jax.Array, offset) -> tuple:
+def _write_rows(planes: tuple, x: jax.Array | Planes, offset) -> tuple:
     """``planes`` with request ``x`` written in place from row ``offset``
     on, as (rows, *shape): its real part into ``planes[0]`` and, for a
-    complex batch, its imaginary part into ``planes[1]``.  A complex
-    batch is built as two real planes because the TPU keeps complex
-    elements as 64-bit words that every update splits and joins again:
-    on planes, each payload is split once and the batch joined once.
-    The offset is traced, so the programs are one per (bucket, payload)
-    shape and dtype pair, whatever the batch's row split."""
-    x = x.reshape((-1, *planes[0].shape[1:]))
-    parts = (jnp.real(x), jnp.imag(x))
-    start = (offset,) + (0,) * (x.ndim - 1)
-    return tuple(lax.dynamic_update_slice(p, v.astype(p.dtype), start)
-                 for p, v in zip(planes, parts))
+    complex batch and payload, its imaginary part into ``planes[1]`` (a
+    real payload's is the zero the plane holds).  The offset is traced,
+    so the programs are one per (bucket, payload) shape and dtype pair,
+    whatever the batch's row split."""
+    parts = (x.re, x.im) if isinstance(x, Planes) else (x,)
+    start = (offset,) + (0,) * (planes[0].ndim - 1)
+    return tuple(lax.dynamic_update_slice(
+        p, v.reshape((-1, *p.shape[1:])).astype(p.dtype), start)
+        for p, v in zip(planes, parts))
 
 
 @dataclasses.dataclass
@@ -548,7 +553,8 @@ class FFTService:
         where submit routed it, else the default device; their rows past
         the requests' are the bucketing pad.
         None when the batch is one payload that already has the
-        executable's shape and dtype: it is handed through as it is.
+        executable's shape and dtype: it is handed through as it is (a
+        complex one in its planes).
         """
         shape = (target, *(batch.key.shape or (batch.key.n,)))
         dtype = _exec_dtype(batch.key)
@@ -562,24 +568,29 @@ class FFTService:
             return (plane,)
         return plane, jnp.zeros_like(plane)
 
-    def _stack(self, batch: Batch, planes: tuple | None) -> jax.Array:
+    def _stack(self, batch: Batch, planes: tuple | None, *,
+               join: bool = False) -> jax.Array | Planes:
         """Write every payload of the batch into ``planes`` on the device,
         one ``_write_rows`` call per request at its row offset (a payload
         on another device, which routing leaves only to payloads submitted
-        as device arrays, is moved to the planes' first), and join a
-        complex batch's planes; with no planes, the batch's one payload.
+        as device arrays, is moved to the planes' first); with no planes,
+        the batch's one payload.  A complex batch stays in its planes,
+        which its executable takes, unless ``join``: then it is joined
+        into the complex array the mesh and the pure-JAX rung take.
         """
         if planes is None:
-            return batch.requests[0].x
-        home = planes[0].sharding
-        offset = 0
-        for r in batch.requests:
-            x = r.x
-            if x.devices() != home.device_set:
-                x = jax.device_put(x, home)
-            planes = _write_rows(planes, x, offset)
-            offset += r.batch
-        return planes[0] if len(planes) == 1 else lax.complex(*planes)
+            x = batch.requests[0].x
+        else:
+            home = planes[0].sharding
+            offset = 0
+            for r in batch.requests:
+                x = r.x
+                if x.devices() != home.device_set:
+                    x = jax.device_put(x, home)
+                planes = _write_rows(planes, x, offset)
+                offset += r.batch
+            x = planes[0] if len(planes) == 1 else Planes(*planes)
+        return x.join() if join and isinstance(x, Planes) else x
 
     def _effective_budget(self, batch: Batch) -> float | None:
         """Strictest real-time budget across the batch's requests.
@@ -785,6 +796,13 @@ class FFTService:
         rows = sum(r.batch for r in batch.requests)
         target = (1 << (rows - 1).bit_length() if self.bucket_batches
                   else rows)
+        fft = batch.key.kind == KIND_FFT
+        sharded = (self.mesh is not None and fft and target > 1
+                   and rung < RUNG_PURE_JAX)
+        # The mesh and the pure-JAX rung take a complex array; every other
+        # complex executable takes the batch's planes.
+        join = (jnp.issubdtype(_exec_dtype(batch.key), jnp.complexfloating)
+                and (sharded or (fft and rung >= RUNG_PURE_JAX)))
         # Span attributes (kind/shape/rung/clock) inherit to child spans;
         # the ledger capture rides the execution so a first-trace records
         # the shape's launch signature (repro.obs.ledger).
@@ -797,7 +815,7 @@ class FFTService:
             # The batch is built on the device from the payloads submit
             # put there: zero-filled bucket planes, then one in-place write
             # per request at a traced row offset.  The programs are one
-            # zero fill (and join) per bucket shape and one write per
+            # zero fill per bucket shape and one write per
             # (bucket, payload) shape and dtype pair, so a stream of new
             # row splits compiles nothing.  Shape bucketing pads the row
             # count to the next power of two, so streaming drains reuse a
@@ -806,8 +824,9 @@ class FFTService:
                 planes = self._bucket(batch, target)
             with self._span("stack", device_stacked=(
                     0 if planes is None else len(batch.requests)),
-                    d2d_bytes=_stray_bytes(batch, planes)):
-                x = self._stack(batch, planes)
+                    d2d_bytes=_stray_bytes(batch, planes),
+                    joined=int(join)):
+                x = self._stack(batch, planes, join=join)
             t_start = self._timer()
             ctx = (self.clock.locked(lock_f) if lock_f is not None
                    else contextlib.nullcontext())
@@ -831,7 +850,8 @@ class FFTService:
                     raise DeviceLostError(worker)
                 with self._span("execute"), \
                         self.ledger.capture(key=batch.key):
-                    xd, y = self._run(batch, entry, rung, x, device)
+                    xd, y = self._run(batch, entry, rung, x, device,
+                                      sharded=sharded)
         return _InFlight(batch=batch, worker=worker, entry=entry,
                          point=point, rung=rung,
                          reason="; ".join(reasons) or None, rows=rows,
@@ -860,15 +880,15 @@ class FFTService:
                               f.t_start, t_done, rung=f.rung,
                               reason=f.reason)
 
-    def _run(self, batch: Batch, entry, rung: int, x: jax.Array,
-             device: Any) -> tuple[jax.Array, jax.Array]:
+    def _run(self, batch: Batch, entry, rung: int, x: jax.Array | Planes,
+             device: Any, *, sharded: bool = False
+             ) -> tuple[jax.Array | Planes, jax.Array]:
         """Place the stacked batch on the worker's device and enqueue its
-        executable (``compute``); returns the placed batch and the result,
-        neither waited for.  The batch is already on a device, so no host
-        bytes cross the link here: a worker on another device (one that
-        stole or was handed the batch) gets one device-to-device copy."""
-        sharded = (self.mesh is not None and batch.key.kind == KIND_FFT
-                   and x.shape[0] > 1 and rung < RUNG_PURE_JAX)
+        executable (``compute``), or shard it over the mesh; returns the
+        placed batch and the result, neither waited for.  The batch is
+        already on a device, so no host bytes cross the link here: a
+        worker on another device (one that stole or was handed the batch)
+        gets one device-to-device copy."""
         xd = (x if sharded or device is None or x.devices() == {device}
               else jax.device_put(x, device))
         with self._span("compute"):

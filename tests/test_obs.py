@@ -580,6 +580,8 @@ class TestServicePath:
             assert span.attrs["batch_id"] == batch.attrs["batch_id"], span
         assert by_name["pad"][0].attrs["pad_rows"] == 3
         assert by_name["stack"][0].attrs["device_stacked"] == 2
+        # the c2c batch reaches its executable in planes, never joined
+        assert by_name["stack"][0].attrs["joined"] == 0
         assert set(by_name) == {"submit", "drain", "batch", "collect",
                                 *(name for name, _ in self.BATCH_CHILDREN)}
 

@@ -13,6 +13,7 @@ from repro.core.workloads import COMPLEX_BYTES
 from repro.fft.plan import plan_for_length
 from repro.runtime.workqueue import WorkStealingQueue
 from repro.serving import FFTService, FFTRequest, coalesce
+from repro.serving.request import Planes
 
 KEY = jax.random.PRNGKey(0)
 
@@ -349,7 +350,8 @@ def host_stack(batch, target):
             for r in batch.requests]
     x = np.concatenate(rows, axis=0)
     if key.kind == "fft" and key.transform == "c2c":
-        x = x.astype(np.complex64)
+        x = x.astype(jax.dtypes.canonicalize_dtype(
+            np.complex128 if key.precision == "fp64" else np.complex64))
     else:
         x = x.real.astype(np.float32)
     pad = np.zeros((target - len(x), *x.shape[1:]), x.dtype)
@@ -377,15 +379,16 @@ PULSAR_KW = dict(kind="pulsar", n_harmonics=4, templates=5, dm_trials=4)
 def test_device_stacked_batch_matches_host_stacking(monkeypatch, payloads,
                                                     kw, target):
     """The executable gets the bits the host stacking gave it: the same
-    rows, cast and zero pad, built on the device from the payloads."""
+    rows, cast and zero pad, built on the device from the payloads; a
+    complex batch as its real and imaginary float32 planes."""
     svc = FFTService(TPU_V5E, devices=jax.devices()[:1])
     reqs = [svc.submit(p, **kw) for p in payloads]
     seen = []
     run = svc._run
 
-    def capture(batch, entry, rung, x, device):
+    def capture(batch, entry, rung, x, device, **kw):
         seen.append((batch, x, np.asarray(x)))
-        return run(batch, entry, rung, x, device)
+        return run(batch, entry, rung, x, device, **kw)
 
     monkeypatch.setattr(svc, "_run", capture)
     svc.drain()
@@ -393,7 +396,12 @@ def test_device_stacked_batch_matches_host_stacking(monkeypatch, payloads,
     want = host_stack(batch, target)
     assert got.dtype == want.dtype and got.shape == want.shape
     assert got.tobytes() == want.tobytes()
-    assert isinstance(x, jax.Array)
+    if want.dtype == np.complex64:
+        assert isinstance(x, Planes)
+        assert x.re.dtype == x.im.dtype == np.float32
+        assert x.re.shape == x.im.shape == want.shape
+    else:
+        assert isinstance(x, jax.Array)
     if len(reqs) == 1:
         assert x is reqs[0].x                     # handed through
     assert all(svc.receipt(r).status == "served" for r in reqs)
@@ -408,14 +416,135 @@ def test_device_stacked_batch_matches_host_stacking(monkeypatch, payloads,
 ], ids=["c64", "n-d", "1-d", "c128", "strided"])
 def test_host_complex_payload_is_copied_bit_for_bit(make):
     """A host complex payload crosses the link as its real view and is
-    joined on the device into the array a complex copy gives."""
+    split on the device into the real and imaginary planes of the array
+    a complex copy gives."""
     x = make()
     svc = FFTService(TPU_V5E, devices=jax.devices()[:1])
     want = np.asarray(jnp.asarray(x))
     req = svc.submit(x, ndim=2 if x.ndim == 3 else 1)
-    got = np.asarray(req.x)
-    assert got.dtype == want.dtype and got.shape == want.shape
-    assert got.tobytes() == want.tobytes()
+    assert isinstance(req.x, Planes)
+    assert req.x.shape == want.shape and req.x.dtype == want.dtype
+    for plane, part in ((req.x.re, want.real), (req.x.im, want.imag)):
+        got = np.asarray(plane)
+        assert got.dtype == part.dtype and got.shape == part.shape
+        assert got.tobytes() == np.ascontiguousarray(part).tobytes()
+    assert req.x.nbytes == want.nbytes
+    assert np.asarray(req.x).tobytes() == want.tobytes()
+
+
+def _pure_jax_service(**kw):
+    from repro.serving import SLO, SLOPolicy
+    return FFTService(TPU_V5E, slo=SLOPolicy(default=SLO(
+        deadline_s=1.0, degrade_at=0.0, degrade_hard_at=0.0,
+        shed_at=None)), **kw)
+
+
+def _one_device_mesh_service(**kw):
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
+    return FFTService(TPU_V5E, mesh=mesh, **kw)
+
+
+def _complex_path(svc, batch, entry, rung, x):
+    """The result the service gave before it kept complex batches in
+    planes: the batch's executable, ``jax.jit(plan.fn)`` (the pure-JAX
+    twin at rung 2, the mesh's sharded FFT on a mesh), on the complex
+    batch stacked on the host."""
+    if svc.mesh is not None:
+        from repro.fft.distributed import batch_parallel_fft
+        return batch_parallel_fft(x, svc.mesh, fft_fn=entry.plan)
+    if rung >= 2:
+        from repro.fft.plan import pallas_disabled
+        with pallas_disabled():
+            return svc._rung2_fn(batch.key)(x)
+    return jax.jit(entry.plan.fn)(x)
+
+
+def _c128(shape, seed):
+    return np.asarray(rand_complex(shape, jax.random.PRNGKey(seed)),
+                      np.complex128)
+
+
+@pytest.mark.parametrize("make_svc,make_payloads,kw,joined", [
+    (None, lambda: [np.asarray(rand_complex((2, 64), jax.random.PRNGKey(i)))
+                    for i in range(4)], {}, 0),
+    (None, lambda: [rand_complex((3, 64)),
+                    np.asarray(rand_complex((2, 64), KEY + 1))], {}, 0),
+    (None, lambda: [np.asarray(rand_complex((8, 64)))], {}, 0),
+    (None, lambda: [_c128((3, 64), 0), _c128((2, 64), 1)],
+     dict(precision="fp64"), 0),
+    (_pure_jax_service, lambda: [rand_complex((3, 64)),
+                                 np.asarray(rand_complex((2, 64), KEY + 1))],
+     {}, 1),
+    (_one_device_mesh_service,
+     lambda: [np.asarray(rand_complex((3, 64))), rand_complex((2, 64))],
+     {}, 1),
+], ids=["c2c-several", "padded", "pass-through", "c128-x64", "rung2",
+        "mesh"])
+def test_served_results_equal_the_complex_path_bit_for_bit(
+        monkeypatch, make_svc, make_payloads, kw, joined):
+    """Each request's result is, bit for bit, what the complex path gave:
+    the executable joins the planes where the kernel splits them, and
+    real(complex(a, b)) is a.  The mesh and the pure-JAX rung take the
+    batch joined once, as a complex array; every other batch reaches its
+    executable in its planes, with no complex array formed before it."""
+    from repro.obs import Tracer
+    with jax.enable_x64(kw.get("precision") == "fp64"):
+        tracer = Tracer(timer=lambda: 0.0)
+        svc = (make_svc or FFTService)(devices=jax.devices()[:1],
+                                       tracer=tracer)
+        reqs = [svc.submit(p, **kw) for p in make_payloads()]
+        seen = []
+        run = svc._run
+
+        def capture(batch, entry, rung, x, device, **kw):
+            seen.append((batch, entry, rung, x))
+            return run(batch, entry, rung, x, device, **kw)
+
+        monkeypatch.setattr(svc, "_run", capture)
+        svc.drain()
+        ((batch, entry, rung, x),) = seen
+        (stack,) = [s for s in tracer.spans if s.name == "stack"]
+        assert stack.attrs["joined"] == joined
+        assert isinstance(x, jax.Array if joined else Planes)
+        stacked = host_stack(batch, 1 << (batch.n_transforms - 1)
+                             .bit_length())
+        want = np.asarray(_complex_path(svc, batch, entry, rung,
+                                        jnp.asarray(stacked)))
+        offset = 0
+        for req in reqs:
+            got = np.asarray(svc.receipt(req).result)
+            part = want[offset:offset + req.batch]
+            offset += req.batch
+            assert got.dtype == part.dtype and got.shape == part.shape
+            assert got.tobytes() == part.tobytes()
+        if len(reqs) == 1:
+            assert x is reqs[0].x                 # handed through
+
+
+def _hlo_ops(text, *names):
+    import re
+    return {name: len(re.findall(rf"\s{name}\(", text)) for name in names}
+
+
+def test_c2c_executable_splits_no_complex_array_before_the_kernel():
+    """The service's 4096-point C2C executable, compiled on the CPU,
+    takes float32 planes, and its optimized HLO holds no real or imag
+    (the join of the planes and the kernel's split of the same array
+    cancel) and at most one complex: the executable the service built
+    for the complex batch held both."""
+    svc = FFTService(TPU_V5E, devices=jax.devices()[:1])
+    key = FFTRequest(x=np.zeros((8, 4096), np.complex64)).shape_key(
+        TPU_V5E.name)
+    entry = svc.cache.entry(key)
+    plane = jax.ShapeDtypeStruct((8, 4096), jnp.float32)
+    hlo = entry.fn.lower(Planes(plane, plane)).compile().as_text()
+    assert "c64[8,4096]" not in hlo.split("ENTRY")[1].split("->")[0]
+    ops = _hlo_ops(hlo, "real", "imag", "complex")
+    assert ops["real"] == ops["imag"] == 0 and ops["complex"] <= 1, ops
+    complex_in = jax.ShapeDtypeStruct((8, 4096), jnp.complex64)
+    before = jax.jit(entry.plan.fn).lower(complex_in).compile().as_text()
+    assert _hlo_ops(before, "real", "imag") == {"real": 1, "imag": 1}
 
 
 def test_stack_compiles_once_per_bucket_and_request_size():

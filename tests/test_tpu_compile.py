@@ -66,6 +66,37 @@ def test_c2c(one_chip, n, rows):
              ((rows, n), C64))
 
 
+def test_served_c2c_executable_takes_the_planes_whole(one_chip,
+                                                     monkeypatch):
+    """The service's 4096-point C2C executable, at the benchmark's 32768
+    rows, compiled for the chip: the planes go straight into the kernel,
+    with no X64SplitLow / X64SplitHigh (the TPU's split of a complex
+    array) before it and one X64Combine (its join) for the result.  The
+    executable on the complex batch splits it twice."""
+    import repro.kernels.fft.ops as ops
+    from repro.core.hardware import TPU_V5E
+    from repro.serving import FFTRequest, FFTService
+    from repro.serving.request import Planes
+    monkeypatch.setattr(ops, "use_interpret", lambda: False)
+    key = FFTRequest(x=np.zeros((1, 4096), C64)).shape_key(TPU_V5E.name)
+    entry = FFTService(TPU_V5E).cache.entry(key)
+    plane = jax.ShapeDtypeStruct((32768, 4096), F32, sharding=one_chip)
+    batch = jax.ShapeDtypeStruct((32768, 4096), C64, sharding=one_chip)
+
+    def calls(compiled):
+        text = compiled.as_text()
+        return {t: text.count(f'custom_call_target="{t}"')
+                for t in ("X64SplitLow", "X64SplitHigh", "X64Combine",
+                          "tpu_custom_call")}
+
+    planar = entry.fn.lower(Planes(plane, plane)).compile()
+    assert calls(planar) == {"X64SplitLow": 0, "X64SplitHigh": 0,
+                             "X64Combine": 1, "tpu_custom_call": 1}
+    joined = jax.jit(entry.plan.fn).lower(batch).compile()
+    assert calls(joined) == {"X64SplitLow": 1, "X64SplitHigh": 1,
+                             "X64Combine": 1, "tpu_custom_call": 1}
+
+
 @pytest.mark.parametrize("which", ["largest_candidate", "stale_cache_tile"])
 def test_c2c_at_tuned_tile(one_chip, which):
     """The longest fused pass at the largest tile the autotuner can
