@@ -811,6 +811,11 @@ class FFTService:
                      shape=batch.key.shape or (batch.key.n,),
                      rung=rung, clock_mhz=point.f, rows=rows,
                      requests=len(batch.requests))
+        if fft:
+            # Which transform and which plan route a batch ran, so a
+            # reader can tell an R2C four-step from a C2C single pass.
+            attrs.update(transform=batch.key.transform,
+                         plan=entry.plan.algorithm)
         with self._span("batch", **attrs):
             # The batch is built on the device from the payloads submit
             # put there: zero-filled bucket planes, then one in-place write

@@ -585,6 +585,27 @@ class TestServicePath:
         assert set(by_name) == {"submit", "drain", "batch", "collect",
                                 *(name for name, _ in self.BATCH_CHILDREN)}
 
+    @pytest.mark.parametrize("transform,n,plan", [
+        ("c2c", 64, "single-pass"), ("r2c", 2**15, "four-step")])
+    def test_batch_span_names_its_transform_and_plan(self, transform, n,
+                                                     plan):
+        """An R2C batch past twice the kernel length runs the packed
+        four-step; a short C2C batch one fused pass.  The batch span says
+        which, and its children inherit both."""
+        tracer = Tracer(timer=FakeTimer(dt=1e-4))
+        svc = FFTService(TPU_V5E, devices=jax.devices()[:1], tracer=tracer)
+        x = np.random.default_rng(0).standard_normal((2, n),
+                                                     dtype=np.float32)
+        svc.submit(x if transform == "r2c" else x.astype(np.complex64),
+                   transform=transform)
+        svc.drain()
+        for span in tracer.spans:
+            if span.name in ("submit", "drain"):
+                assert "transform" not in span.attrs, span
+            else:
+                assert span.attrs["transform"] == transform, span
+                assert span.attrs["plan"] == plan, span
+
     def test_one_worker_device_waits_sit_inside_execute(self, monkeypatch):
         """With one worker the batch's waits (its placement, traced, then
         its result) each take one clock second inside ``execute``, in

@@ -125,6 +125,24 @@ def test_r2c(one_chip, n):
              ((256, n), F32))
 
 
+def test_served_r2c_four_step_at_a_gib(one_chip, monkeypatch):
+    """The R2C executable the service builds for 256 rows of 2^20 real
+    samples (1 GiB, the fft_r2c_1m_1gib cell's batch): packed into 2^19
+    complex points, the four-step's two kernel passes at 512 x 1024, the
+    Hermitian split.  It fits HBM with room for the cell's payloads and
+    retained receipts."""
+    import repro.kernels.fft.ops as ops
+    from repro.fft.plan import plan_for_length
+    monkeypatch.setattr(ops, "use_interpret", lambda: False)
+    plan = plan_for_length(2**20, "r2c")
+    assert plan.algorithm == "four-step"
+    compiled = _compile(plan.fn, one_chip, ((256, 2**20), F32))
+    text = compiled.as_text()
+    assert "fft_axis1_twiddle_pallas" in text and "fft_t_pallas" in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes <= 4 * 2**30
+
+
 def test_c2r(one_chip):
     from repro.kernels.fft.ops import fft_kernel_c2r
     _compile(lambda x: fft_kernel_c2r(x, interpret=False), one_chip,
